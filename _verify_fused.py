@@ -25,10 +25,10 @@ Phases:
       with the maglev plane (submit_pick at zero extra launches,
       status fused:true).
   [5] knobs + the Pallas tier — VPROXY_TPU_FUSED=0 regenerates WITHOUT
-      packed tables and falls back identically; the fused-fn cache
-      re-keys on a kernel-knob flip (the PR-6 stale-program family);
-      pallas_supported() honestly refuses on CPU and bit-verifies the
-      kernel in interpret mode.
+      packed tables and falls back identically; the fused tier
+      follows a kernel-knob flip (the PR-6 stale-program family);
+      the Pallas kernel bit-verifies in interpret mode and an explicit
+      kernel=pallas without it raises on CPU.
 """
 import json
 import os
@@ -244,27 +244,33 @@ def main():
             os.environ.pop("VPROXY_TPU_FUSED", None)
         hm.set_rules(list(rules2))
         assert hm.fused_stat()["available"]
+        v5c, _p, _h, _m = classify_and_pick(hm, mm, probe, pips)
         from vproxy_tpu.ops import fused_pallas as FP
-        FP.reset_probe()
         fn0 = E._fused_fn()
         os.environ["VPROXY_TPU_FUSED_KERNEL"] = "pallas"
         os.environ["VPROXY_TPU_PALLAS_INTERPRET"] = "1"
         try:
-            FP.reset_probe()
-            ok, why = FP.pallas_supported()
-            assert ok, why
-            assert E._fused_fn() is not fn0, "stale compiled program"
+            assert E._fused_fn() is FP.fused_classify_pick_pallas, \
+                "stale compiled program"
             assert E.fused_kernel_name() == "pallas"
+            v6, p6, _h, _m = classify_and_pick(hm, mm, probe, pips)
+            assert [int(x) for x in v6] == [int(x) for x in v5c] and \
+                [int(x) for x in p6] == want_picks, "pallas != jit"
+            os.environ.pop("VPROXY_TPU_PALLAS_INTERPRET")
+            try:  # explicit pallas that cannot compile RAISES
+                np.asarray(E.fused_dispatch(
+                    hm, hm.snapshot(), mm, mm.snapshot(), probe, pips))
+                raise AssertionError("kernel=pallas on CPU did not raise")
+            except ValueError as e:
+                why = str(e)
         finally:
             os.environ.pop("VPROXY_TPU_FUSED_KERNEL", None)
             os.environ.pop("VPROXY_TPU_PALLAS_INTERPRET", None)
-            FP.reset_probe()
-        ok, why = FP.pallas_supported()
-        assert not ok and "cpu" in why, (ok, why)
+        assert E._fused_fn() is fn0 and E.fused_kernel_name() == "jit"
         say(f"[5] VPROXY_TPU_FUSED=0 falls back identically (no packed "
-            f"tables); kernel-knob flip re-keys the fused-fn cache and "
-            f"interpret-mode bit-verifies the Pallas kernel; the CPU "
-            f"probe honestly refuses ('{why[:42]}...')")
+            f"tables); the fused tier follows a kernel-knob flip and "
+            f"interpret-mode bit-verifies the Pallas kernel; explicit "
+            f"kernel=pallas on CPU raises ('{why[:42]}...')")
 
         say("FUSED VERIFY OK")
     finally:

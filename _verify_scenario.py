@@ -151,7 +151,7 @@ _m7 = _HM7(_rules7)
 _m7.match([_Hint.of_host("warm.example")] * 16)
 _real7 = _m7.dispatch_snap
 def _slow7(snap, hints):
-    time.sleep(0.05)  # tunnel-like 50ms device RTT
+    time.sleep(0.05)  # a slow (50ms) device round trip
     return _real7(snap, hints)
 _m7.dispatch_snap = _slow7
 _svc7._ewma["device"] = 50_000.0  # measured-over-budget device

@@ -1335,8 +1335,10 @@ def main():
     # SIGTERM (bench.py's stage timeout) must run the finally block —
     # otherwise the native server processes are orphaned forever
     signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
+    from vproxy_tpu.utils.jaxenv import force_cpu
 
     if "--storm" in sys.argv[1:]:
+        force_cpu(8)  # a host bench: the tools it imports expect the CPU
         return run_storm()
 
     if "--maglev" in sys.argv[1:]:
@@ -1347,8 +1349,10 @@ def main():
     if "--analytics" in sys.argv[1:]:
         return run_analytics()
     if "--replay" in sys.argv[1:]:
+        force_cpu(8)  # a host bench: the tools it imports expect the CPU
         return run_replay()
     if "--policing" in sys.argv[1:]:
+        force_cpu(8)  # a host bench: the tools it imports expect the CPU
         return run_policing()
 
     # --lanes: run ONLY the accept-lane stage (direct ceiling +

@@ -163,6 +163,7 @@ def test_daemon_restart_and_reload_logic(tmp_path, monkeypatch):
 
     class FakeProc:
         n = 0
+        platform = "tpu"  # what the child's boot line reported
 
         def __init__(self):
             FakeProc.n += 1
@@ -192,3 +193,17 @@ def test_daemon_restart_and_reload_logic(tmp_path, monkeypatch):
     assert d.child is not first          # new child took over
     assert first.signals                 # old child got SIGTERM
     assert first.poll() is not None
+
+    # a reload whose new child lands on the cpu after an accelerator
+    # served is a FAILED reload: new child killed, old one kept
+    d.accelerator = "tpu"
+    kept = d.child
+
+    def cpu_child():
+        p = FakeProc()
+        p.platform = "cpu"
+        return p
+
+    monkeypatch.setattr(d, "_spawn", cpu_child)
+    d._do_reload()
+    assert d.child is kept and kept.poll() is None

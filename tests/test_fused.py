@@ -306,89 +306,63 @@ def test_maglev_install_swaps_pick_atomically():
     assert set(np.asarray(p).tolist()) <= {0, 1}
 
 
-# --------------------------------------------- fused-fn cache (knobs)
+# ------------------------------------------------ fused tier (knobs)
 
 
-def test_fused_fn_cache_keyed_on_kernel_knobs(monkeypatch):
+def test_fused_tier_follows_the_kernel_knob(monkeypatch):
     """The PR-6 stale-mesh family: a VPROXY_TPU_* knob change
-    mid-process must select a fresh compiled program, never serve the
-    cached one for the old knob state."""
+    mid-process must select the other program, never keep serving the
+    one for the old knob state. The tier is chosen BY NAME: the default
+    is the jit tier, and no capability probe runs."""
     from vproxy_tpu.ops import fused as F
     from vproxy_tpu.ops import fused_pallas as FP
     monkeypatch.delenv("VPROXY_TPU_FUSED_KERNEL", raising=False)
     monkeypatch.delenv("VPROXY_TPU_PALLAS_INTERPRET", raising=False)
-    FP.reset_probe()
     fn0 = engine._fused_fn()
+    assert fn0 is F.fused_jit
     assert engine._fused_fn() is fn0  # stable under a stable key
-    assert engine.fused_kernel_name() == "jit"  # cpu probe refuses
+    assert engine.fused_kernel_name() == "jit"
     monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "pallas")
     monkeypatch.setenv("VPROXY_TPU_PALLAS_INTERPRET", "1")
-    FP.reset_probe()
     fn1 = engine._fused_fn()
-    assert fn1 is not fn0, "knob change served a stale compiled program"
+    assert fn1 is FP.fused_classify_pick_pallas, \
+        "knob change served a stale compiled program"
     assert engine.fused_kernel_name() == "pallas"
     monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "jit")
     assert engine._fused_fn() is fn0
-    FP.reset_probe()
-
-
-def test_auto_mode_never_serves_interpret_pallas(monkeypatch):
-    """VPROXY_TPU_PALLAS_INTERPRET=1 is the bit-verify lane (~100x
-    slower per batch); in kernel mode "auto" it must NOT flip
-    production serving onto the interpreter — only an explicit
-    kernel=pallas serves it."""
-    from vproxy_tpu.ops import fused as F
-    from vproxy_tpu.ops import fused_pallas as FP
+    # interpret mode alone never moves serving off the jit tier
     monkeypatch.delenv("VPROXY_TPU_FUSED_KERNEL", raising=False)
-    monkeypatch.setenv("VPROXY_TPU_PALLAS_INTERPRET", "1")
-    FP.reset_probe()
-    assert FP.pallas_supported()[0]  # the probe itself passes
     assert engine._fused_fn() is F.fused_jit
-    assert engine.fused_kernel_name() == "jit"
-    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "pallas")
-    assert engine._fused_fn() is FP.fused_classify_pick_pallas
-    FP.reset_probe()
-
-
-def test_fused_kernel_name_is_probe_free(monkeypatch):
-    """The stat surfaces (list-detail / HTTP detail) read the serving
-    tier on the control thread: fused_kernel_name must report from
-    CACHED state only, never trigger the capability probe (whose first
-    pass compiles and dispatches a kernel)."""
-    from vproxy_tpu.ops import fused as F
-    from vproxy_tpu.ops import fused_pallas as FP
-    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "auto")
-    monkeypatch.setenv("VPROXY_TPU_PALLAS_INTERPRET", "1")
-    FP.reset_probe()
-    engine._FUSED_FN.pop(F.layout_key(), None)
-    assert engine.fused_kernel_name() == "jit"  # cold: the jit default
-    assert FP.probe_cached() is None, "stat read ran the probe"
-    FP.reset_probe()
+    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "auto")  # retired value
+    with pytest.raises(ValueError, match="expected 'jit' or 'pallas'"):
+        engine._fused_fn()
 
 
 # ------------------------------------------------------- pallas tier
 
 
-def test_pallas_probe_honest_on_cpu(monkeypatch):
-    from vproxy_tpu.ops import fused_pallas as FP
+def test_explicit_pallas_on_cpu_raises(monkeypatch):
+    """VPROXY_TPU_FUSED_KERNEL=pallas where the kernel cannot compile
+    (the CPU platform, no interpret mode) RAISES at the first fused
+    dispatch — it does not warn and serve the jit tier."""
+    from vproxy_tpu.rules.maglev import MaglevMatcher
+    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "pallas")
     monkeypatch.delenv("VPROXY_TPU_PALLAS_INTERPRET", raising=False)
-    FP.reset_probe()
-    ok, why = FP.pallas_supported()
-    assert not ok and "cpu" in why
-    FP.reset_probe()
+    hm = HintMatcher(mk_rules(64), backend="jax")
+    mm = MaglevMatcher([("a:1", 1), ("b:2", 1)], m=251)
+    with pytest.raises(ValueError, match="interpret mode"):
+        np.asarray(engine.fused_dispatch(
+            hm, hm.snapshot(), mm, mm.snapshot(),
+            mk_queries(hm.rules, 8), mk_ips(8)))
 
 
-def test_pallas_interpret_bit_verify(monkeypatch):
-    """The real-hardware flip-on guard, exercised in interpret mode:
-    the Pallas kernel's (verdict, pick) is bit-identical to the fused
-    jit on a randomized table."""
+def test_pallas_interpret_bit_verify():
+    """The Pallas kernel's statement of the contract, in interpret
+    mode: (verdict, pick) bit-identical to the fused jit on a
+    randomized table."""
     from vproxy_tpu.ops import fused as F
     from vproxy_tpu.ops import fused_pallas as FP
     from vproxy_tpu.ops import hashmatch as H
-    monkeypatch.setenv("VPROXY_TPU_PALLAS_INTERPRET", "1")
-    FP.reset_probe()
-    ok, why = FP.pallas_supported()
-    assert ok, why
     rules = mk_rules(400)
     tab = H.compile_hint_hash(rules)
     hints = mk_queries(rules, 24)
@@ -402,7 +376,6 @@ def test_pallas_interpret_bit_verify(monkeypatch):
     got = np.asarray(FP.fused_classify_pick_pallas(ht, q, mtab, slots,
                                                    interpret=True))
     assert np.array_equal(ref, got)
-    FP.reset_probe()
 
 
 # ------------------------------------------------- service + step loop

@@ -112,7 +112,7 @@ def test_device_failure_degrades_to_oracle_and_recovers():
 
     def flaky(snap, hints, **kw):
         if boom["on"]:
-            raise RuntimeError("tunnel dropped")
+            raise RuntimeError("device dropped")
         return real_dispatch(snap, hints, **kw)
 
     m.dispatch_snap = flaky
@@ -309,7 +309,7 @@ def test_latency_budget_reroutes_lone_big_table_queries():
     svc.inline_lone = False  # exercise the budget policy, not the lane
     svc.budget_us = 1000.0  # 1ms budget
     m = HintMatcher(mk_rules(300))  # > SMALL_TABLE
-    # make the device path artificially slow (tunnel-like: 50ms)
+    # make the device path artificially slow (50ms)
     real = m.dispatch_snap
 
     def slow(snap, hints, **kw):

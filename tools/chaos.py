@@ -42,10 +42,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from vproxy_tpu.utils.jaxenv import force_cpu  # noqa: E402
-
-force_cpu(8)
-
 import _fleetlib  # noqa: E402  (tools/_fleetlib.py — shared fleet helpers)
 
 from vproxy_tpu.components import servergroup as SG                # noqa: E402
@@ -407,6 +403,11 @@ def run_cluster(n_rules: int = 24, queries_per_node: int = 120,
 
 
 def main(argv=None) -> int:
+    # a host-side tool: pin ITS process to the CPU (importing this
+    # module leaves the platform alone — the benchmark imports it next
+    # to a chip)
+    from vproxy_tpu.utils.jaxenv import force_cpu
+    force_cpu(8)
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--requests", type=int, default=120,

@@ -41,10 +41,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from vproxy_tpu.utils.jaxenv import force_cpu  # noqa: E402
-
-force_cpu(8)
-
 import _fleetlib  # noqa: E402  (tools/_fleetlib.py — shared fleet helpers)
 
 # schedule caps: a replay is a bounded experiment, not a soak
@@ -461,6 +457,11 @@ def drive_zipf_mix(port: int, seed: int, n: int = 200, clients: int = 8,
 # ------------------------------------------------------------------- main
 
 def main(argv=None) -> int:
+    # a host-side tool: pin ITS process to the CPU (importing this
+    # module leaves the platform alone — the benchmark imports it next
+    # to a chip)
+    from vproxy_tpu.utils.jaxenv import force_cpu
+    force_cpu(8)
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     src = ap.add_mutually_exclusive_group(required=True)
     src.add_argument("--model", help="workload model JSON file")

@@ -65,10 +65,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from vproxy_tpu.utils.jaxenv import force_cpu  # noqa: E402
-
-force_cpu(8)
-
 import _fleetlib  # noqa: E402  (tools/_fleetlib.py — shared fleet helpers)
 
 ROUND = "r10"
@@ -1191,6 +1187,11 @@ def run_all(seed: int = 0, scale: float = 1.0, only: str = None,
 
 
 def main(argv=None) -> int:
+    # a host-side tool: pin ITS process to the CPU (importing this
+    # module leaves the platform alone — the benchmark imports it next
+    # to a chip)
+    from vproxy_tpu.utils.jaxenv import force_cpu
+    force_cpu(8)
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0,
                     help="pin failpoint RNGs + payloads; echoed into "

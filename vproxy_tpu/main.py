@@ -78,6 +78,25 @@ def _addr(s: str):
     return h or "127.0.0.1", int(p)
 
 
+DEVICE_LINE = "device: platform="  # apps/daemon.py parses this line
+
+
+def boot_device_line() -> str:
+    """Claim the device at boot and say what was claimed. Every pad
+    bucket compiles on the serving path, so the persistent compile cache
+    is placed first (utils/jaxenv). A process that cannot have the chip
+    (another one holds it) dies HERE with the runtime's own error, not
+    at the first rule install; one that lands on another platform than
+    the operator expects says so on its first line."""
+    from .utils.jaxenv import compile_cache_dir
+    cache = compile_cache_dir()
+    import jax
+    devs = jax.devices()
+    return (f"{DEVICE_LINE}{devs[0].platform} "
+            f"kind={devs[0].device_kind!r} count={len(devs)} "
+            f"jax={jax.__version__} compile-cache={cache}")
+
+
 def main(argv: list[str] | None = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
 
@@ -85,6 +104,13 @@ def main(argv: list[str] | None = None) -> int:
     # comes first, covering the deployable apps below too
     from .utils.oom import install as install_oom
     install_oom()
+
+    # the supervisor must stay off JAX: one process per chip, and the
+    # child it spawns is the one that needs the devices — so `daemon`
+    # dispatches before the distributed bring-up below claims them
+    if argv and argv[0].lower() == "daemon":
+        from .apps import daemon
+        return daemon.run(argv[1:])
 
     # multi-host bring-up BEFORE any device touch: when
     # VPROXY_TPU_DIST_COORD/_NPROC/_PROCID are set, join the
@@ -99,8 +125,8 @@ def main(argv: list[str] | None = None) -> int:
               f"{len(jax.devices())} global devices")
 
     # deployable apps (reference -Deploy=...): first arg selects the app
-    if argv and argv[0].lower() in ("simple", "helloworld", "daemon",
-                                    "kcptun", "websocks"):
+    if argv and argv[0].lower() in ("simple", "helloworld", "kcptun",
+                                    "websocks"):
         name = argv.pop(0).lower()
         import importlib
         mod = importlib.import_module(f".apps.{name}", __package__)
@@ -144,6 +170,8 @@ def main(argv: list[str] | None = None) -> int:
         else:
             print(f"unknown argument {a!r}", file=sys.stderr)
             return 1
+
+    print(boot_device_line(), flush=True)
 
     app = Application.create(workers=opts["workers"])
     try:

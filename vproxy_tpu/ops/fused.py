@@ -38,10 +38,10 @@ Two layers live here:
   tables.
 
 A Pallas implementation of the same contract lives in
-ops/fused_pallas.py behind a capability probe; `layout_key()` is the
-cache key every compiled-fused-fn cache must carry so a
-`VPROXY_TPU_*` knob change mid-process can never serve a stale
-compiled program (the PR-6 stale-mesh family of bug).
+ops/fused_pallas.py, served only under an explicit
+VPROXY_TPU_FUSED_KERNEL=pallas (rules/engine._fused_fn re-reads the
+knob per dispatch, so a change mid-process never serves a stale
+program — the PR-6 stale-mesh family of bug).
 """
 from __future__ import annotations
 
@@ -55,28 +55,14 @@ import numpy as np
 from . import cuckoo as CK
 from .hashmatch import DOT, HOST_SHIFT, _fnv32_device
 
-# Packed-table layout version: bump on ANY change to the pk_* array
-# shapes/column meanings. Folded into layout_key() so compiled-fn
-# caches (engine._fused_fn) and cross-process consumers can detect a
-# mismatch instead of gathering garbage.
-PACK_LAYOUT_V = 1
-
-
 def kernel_mode() -> str:
-    """VPROXY_TPU_FUSED_KERNEL: "auto" (pallas on capable real devices,
-    jit elsewhere), "jit" (force the CPU-valid fused jit), "pallas"
-    (force the Pallas tier — interpret-mode on CPU when
-    VPROXY_TPU_PALLAS_INTERPRET=1, else refused by the probe).
-    Re-read per call: jit statics must honor mid-process changes."""
-    return os.environ.get("VPROXY_TPU_FUSED_KERNEL", "auto")
-
-
-def layout_key() -> tuple:
-    """The key every fused-fn cache must use: packed layout version +
-    the env knobs that select a different compiled program. A knob
-    change mid-process produces a NEW key, never a stale hit."""
-    return (PACK_LAYOUT_V, kernel_mode(),
-            os.environ.get("VPROXY_TPU_PALLAS_INTERPRET", "0"))
+    """VPROXY_TPU_FUSED_KERNEL: "jit" (default — the fused XLA program,
+    the tier that serves on every platform) or "pallas" (the
+    ops/fused_pallas.py kernel: interpret-mode on CPU when
+    VPROXY_TPU_PALLAS_INTERPRET=1; anywhere the kernel cannot compile,
+    the first dispatch raises). Re-read per call: jit statics must
+    honor mid-process changes."""
+    return os.environ.get("VPROXY_TPU_FUSED_KERNEL", "jit")
 
 
 # ------------------------------------------------------------- packing

@@ -1,31 +1,30 @@
-"""Pallas tier of the fused classify+pick contract (real devices).
+"""Pallas tier of the fused classify+pick contract — NOT a serving tier.
 
-`ops/fused.py`'s jitted program is CPU-valid and is what this sandbox
-serves with; on a real accelerator the same contract — packed tables
-in, (verdict, pick) out, one launch — wants a hand-scheduled kernel:
-the probe/resolve/pick chain is gather-bound, and a Pallas kernel can
-keep the per-query working set (one packed slot row, one packed meta
-row, one packed byte row) streaming through VMEM instead of paying
-XLA's general-gather lowering.
+Same contract as `ops/fused.py`'s jitted program (packed tables in,
+(verdict, pick) out, one launch), written as a scalar kernel: grid over
+the batch, one query row per step, the packed tables left in `pl.ANY`
+and read one row per candidate.
 
-Capability-gated, never assumed: `pallas_supported()` compiles AND
-bit-verifies a tiny fused case against the jit path before anyone
-serves from this tier — on a platform where Mosaic rejects the kernel
-(or on this CPU sandbox, where there is no Mosaic at all) the probe
-fails closed and the engine keeps the fused jit. That is the
-"flip it on without rework" contract for the real-hardware campaign:
-`VPROXY_TPU_FUSED_KERNEL=auto` starts serving Pallas the moment the
-probe passes, and `VPROXY_TPU_PALLAS_INTERPRET=1` lets this sandbox
-bit-verify the kernel logic in interpret mode (tests/test_fused.py).
-
-Kernel shape: grid over the batch, one query row per step. The query
-row blocks (hostb/urib windows, probe slots) ride VMEM; the packed
-tables are left in `pl.ANY` — at million-rule scale they are
-HBM-resident and the row gathers become DMAs, which is exactly the
-access pattern the packed layout was chosen for (one slot row + one
-meta row + one byte row per touch; see ops/fused.py). Memory-space
-tuning beyond that is real-hardware work by design (ROADMAP
-real-hardware campaign) — the probe keeps it safe to defer.
+Status (TPU v5 lite, jax 0.9.0, PR 21): Mosaic refuses the kernel at
+lowering, first on the `(1, w)` / `(1, 1)` query blocks ("the last two
+dimensions of your block shape are divisible by 8 and 128 respectively,
+or be equal to the respective dimensions of the overall array"). The
+body behind that check does not lower either — each construct tried
+alone in a micro-kernel with legal blocks: `pk_meta[ci, :]` on a
+`pl.ANY` ref: "Loads are only allowed on VMEM and SMEM references. ANY
+memory space can only be accessed using async_copy"; the uint8 row
+compare + `jnp.all`: "Mosaic failed to compile TPU kernel: Invalid
+relayout ... 'vector<32xi1>'"; `qhost[clip(hl)]`: "Unimplemented
+primitive in Pallas TPU lowering: dynamic_slice" (scalar reads like
+`hlen[0, 0]` from VMEM do compile). That is a rewrite (per-candidate
+DMAs, i32 lanes, SMEM copies of the query row), not a repair, so the
+engine serves the fused jit tier by name (`fused.kernel_mode()`
+defaults to "jit"). This kernel runs only under an explicit
+`VPROXY_TPU_FUSED_KERNEL=pallas`, which raises at the first dispatch
+wherever it cannot compile, and in interpret mode
+(`VPROXY_TPU_PALLAS_INTERPRET=1`) as the bit-verified statement of the
+contract (tests/test_fused.py). Whether a Mosaic kernel is worth
+writing is ROADMAP D3's question.
 """
 from __future__ import annotations
 
@@ -201,8 +200,7 @@ def fused_classify_pick_pallas(ht: dict, q: dict, mtab, slots,
 
     row = lambda w: pl.BlockSpec((1, w), lambda i: (i, 0))
     one = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    # packed tables: whole-array refs, compiler-placed — HBM-resident
-    # at million-rule scale, row gathers become DMAs (module doc)
+    # packed tables: whole-array refs, compiler-placed
     full = pl.BlockSpec(memory_space=pl.ANY)
 
     kernel = functools.partial(_fused_kernel, hw=hw, r_cap=r_cap,
@@ -229,78 +227,3 @@ def fused_classify_pick_pallas(ht: dict, q: dict, mtab, slots,
       ht["pk_meta"], ht["pk_bytes"], ht["pk_hslot"], ht["pk_hkey"],
       uslot, ukey, ht["hb_items"], ub_items,
       ht["wh_idx"], wu_idx, mtab)
-
-
-# ----------------------------------------------------- capability probe
-
-_PROBE: dict = {}  # interpret flag -> (ok, why)
-
-
-def pallas_supported() -> tuple:
-    """(ok, why): can THIS process serve the Pallas tier? ok only when
-    the kernel compiles AND bit-matches the fused jit on a tiny fused
-    case — a probe failure (no accelerator, Mosaic rejection, numeric
-    mismatch) keeps the engine on the jit tier with the reason
-    surfaced in the HTTP engine object. Cached PER KNOB STATE, not per
-    process: a VPROXY_TPU_PALLAS_INTERPRET flip mid-process re-probes
-    under the new mode instead of serving a verdict measured under the
-    old one (the same stale-program family engine._fused_fn re-keys
-    for). Interpret mode counts as capable so CPU environments can
-    bit-verify the kernel logic."""
-    interp = interpret_forced()
-    hit = _PROBE.get(interp)
-    if hit is not None:
-        return hit
-    try:
-        platform = jax.devices()[0].platform
-    except Exception as e:  # noqa: BLE001 — no backend at all
-        return _PROBE.setdefault(interp, (False, f"no jax backend: {e!r}"))
-    if platform == "cpu" and not interp:
-        return _PROBE.setdefault(
-            interp, (False, "cpu platform (no Mosaic); "
-                            "VPROXY_TPU_PALLAS_INTERPRET=1 bit-verifies "
-                            "the kernel in interpret mode"))
-    try:
-        res = _probe_verify(interp)
-    except MemoryError:
-        raise
-    except Exception as e:  # noqa: BLE001 — probe must fail closed
-        res = (False, f"pallas probe failed: {e!r}"[:300])
-    return _PROBE.setdefault(interp, res)
-
-
-def _probe_verify(interpret: bool) -> tuple:
-    """Compile + run the tiny fused case on both tiers; bit-compare."""
-    from ..rules.ir import Hint, HintRule
-    from . import fused as F
-    from . import hashmatch as H
-    rules = [HintRule(host=f"p{i}.probe.example.com") for i in range(8)]
-    rules.append(HintRule(host="*", uri="/probe"))
-    tab = H.compile_hint_hash(rules)
-    hints = [Hint.of_host("p3.probe.example.com"),
-             Hint(host="x.example.org", uri="/probe/deep"), Hint()]
-    q = H.encode_hint_queries(hints, tab)
-    ht = F.pack_hint_table(tab.arrays)
-    mtab = np.arange(11, dtype=np.int32) % 3
-    slots = np.array([1, 4, 7], np.int64)
-    ref = np.asarray(F.fused_jit(ht, q, mtab, slots))
-    got = np.asarray(fused_classify_pick_pallas(ht, q, mtab, slots,
-                                                interpret=interpret))
-    if not np.array_equal(ref, got):
-        return (False, f"pallas/jit mismatch: {got.tolist()} != "
-                       f"{ref.tolist()}")
-    return (True, "interpret" if interpret else "compiled")
-
-
-def probe_cached() -> Optional[tuple]:
-    """The cached probe verdict for the CURRENT knob state, or None if
-    that probe hasn't run — NEVER triggers one (the control-thread-safe
-    read the stat surfaces use; a probe's first pass compiles and
-    dispatches a kernel)."""
-    return _PROBE.get(interpret_forced())
-
-
-def reset_probe() -> None:
-    """Test hook: force a full re-probe (e.g. after a monkeypatched
-    backend); plain env flips re-key automatically."""
-    _PROBE.clear()

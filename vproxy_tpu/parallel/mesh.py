@@ -70,7 +70,6 @@ def init_distributed(coordinator: Optional[str] = None,
     if process_id > 0:
         _preflight_coordinator(coordinator, num_processes, process_id,
                                timeout_s)
-    enable_cpu_collectives()
     try:
         jax.distributed.initialize(
             coordinator, num_processes=num_processes,
@@ -90,44 +89,6 @@ def init_distributed(coordinator: Optional[str] = None,
             "VPROXY_TPU_DIST_TIMEOUT_S for genuinely slow fleets."
         ) from e
     return True
-
-
-def cpu_collectives_available() -> bool:
-    """Can THIS jaxlib run multiprocess collectives on the CPU backend?
-    Without a cross-process CPU collectives implementation (gloo/mpi)
-    the CPU client fails any multiprocess computation with
-    "Multiprocess computations aren't implemented on the CPU backend" —
-    the capability probe tests gate on (tests/test_multihost.py) instead
-    of failing in environments that cannot comply."""
-    try:
-        from jax._src.lib import xla_extension as _xe
-        if not hasattr(_xe, "make_gloo_tcp_collectives"):
-            return False
-        # the config option wires gloo into the CPU client at creation;
-        # a jax too old to register the option cannot enable it (the
-        # option is holder-registered, not an attribute on jax.config)
-        holders = getattr(jax.config, "_value_holders", {})
-        return "jax_cpu_collectives_implementation" in holders
-    except Exception:
-        return False
-
-
-def enable_cpu_collectives() -> None:
-    """Select the gloo CPU collectives implementation (when this jaxlib
-    ships it) BEFORE the backend initializes — multiprocess CPU fleets
-    (and the 2-process tests) need it; accelerator backends ignore it.
-    Must run before the first device use; init_distributed() calls it
-    ahead of jax.distributed.initialize."""
-    if not cpu_collectives_available():
-        return
-    try:
-        holders = getattr(jax.config, "_value_holders", {})
-        cur = holders["jax_cpu_collectives_implementation"].value
-        if cur in (None, "", "none"):
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-    except Exception:
-        pass  # backend already initialized: leave the config alone
 
 
 def _preflight_coordinator(coordinator: str, num_processes: int,
@@ -339,15 +300,6 @@ def shard_hint_queries_sharded(q: dict, mesh: Mesh) -> dict:
     return put_many(mesh, specs, q)
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs)
-
-
 def _donate_queries(mesh: Mesh, argnums: tuple) -> dict:
     """jit kwargs donating the per-dispatch QUERY buffers (tables are
     reused across dispatches and must never be donated). Donation lets
@@ -399,7 +351,8 @@ def make_sharded_hint_fn(mesh: Mesh, table_keys_ndim: dict,
          for k, nd in query_keys_ndim.items()},
         P(),
     )
-    return jax.jit(_shard_map(body, mesh, in_specs, P(ba)),
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(ba)),
                    **_donate_queries(mesh, (1,)))
 
 
@@ -441,7 +394,8 @@ def make_sharded_cidr_fn(mesh: Mesh, table_keys_ndim: dict,
         {k: P("rules", *([None] * (nd - 1)))  # stacked ndims
          for k, nd in table_keys_ndim.items()},
     ) + q_specs
-    return jax.jit(_shard_map(body, mesh, in_specs, P(ba)),
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(ba)),
                    **_donate_queries(mesh, (1, 2, 3) if with_port
                                      else (1, 2)))
 
@@ -453,10 +407,6 @@ def make_sharded_classify(mesh: Mesh, hint_stab, route_stab, acl_stab,
     classify SPMD over the (batch, rules) mesh. example_hq: one output
     of encode_hint_queries_sharded (shapes fix the query specs)."""
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     from ..ops.hashmatch import cidr_hash_match, hint_hash_match
 
@@ -497,6 +447,5 @@ def make_sharded_classify(mesh: Mesh, hint_stab, route_stab, acl_stab,
          for k, v in example_hq.items()},
         P(ba, None), P(ba), P(ba),
     )
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(ba, None))
-    return jax.jit(fn)
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(ba, None)))
