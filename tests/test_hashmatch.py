@@ -9,6 +9,7 @@ probe positions, rebuild-on-update with cap reuse, wildcard keys.
 import random
 
 import numpy as np
+import pytest
 
 from vproxy_tpu.ops import hashmatch as H
 from vproxy_tpu.ops import tables as T
@@ -223,3 +224,79 @@ def test_hash_vs_dense_vs_host_cross_check():
         got[be] = HintMatcher(rules, backend=be).match(hints)
     np.testing.assert_array_equal(got["jax"], got["host"])
     np.testing.assert_array_equal(got["jax-dense"], got["host"])
+
+
+# ------------------------------------------------ stage names (metadata)
+
+def _scope_case(kernel):
+    """-> (function, args, stage names its program has to carry)."""
+    from vproxy_tpu.ops import fused as F
+    from vproxy_tpu.rules.maglev import MaglevMatcher, flow_slots
+    hm = HintMatcher([HintRule(host=f"s{i}.example.com",
+                               uri=f"/a{i}" if i % 3 == 0 else None)
+                      for i in range(96)], backend="jax")
+    hsnap = hm.snapshot()
+    q = H.encode_hint_queries(
+        [Hint(host=f"s{i}.example.com", uri="/a3/x") for i in range(16)],
+        hsnap[0], pad_to=16)
+    cm = CidrMatcher([Network(bytes([10, i, 0, 0]), mask_bytes(16))
+                      for i in range(32)], backend="jax")
+    a16, fam = T.encode_ips([bytes([10, i, 1, 1]) for i in range(16)])
+    port = np.arange(16, dtype=np.int32)
+    hint_stages = ("hint_probe", "hint_candidates", "hint_score",
+                   "hint_reduce")
+    cidr_stages = ("cidr_hash", "cidr_probe", "cidr_candidates",
+                   "cidr_gate", "cidr_reduce")
+    if kernel == "hint_hash_match":
+        return H.hint_hash_match, (hsnap[1], q), hint_stages
+    if kernel == "cidr_hash_match":
+        return H.cidr_hash_match, (cm.snapshot()[0], a16, fam, port), \
+            cidr_stages
+    if kernel == "classify_hash_all":
+        cdev = cm.snapshot()[0]
+        return H.classify_hash_all, (hsnap[1], cdev, cdev, q, a16, fam,
+                                     port), \
+            hint_stages + cidr_stages + ("route", "acl")
+    mm = MaglevMatcher([(f"b{i}", 1) for i in range(5)], m=251)
+    msnap = mm.snapshot()
+    slots = flow_slots(len(msnap[0]),
+                       [bytes([1, 2, 3, i]) for i in range(16)], None)
+    return F.fused_classify_pick, (hsnap[5], q, msnap[1], slots), \
+        hint_stages + ("maglev_pick",)
+
+
+def _compiled_text(fn, args) -> tuple:
+    """(the compiled program's text, the same without its metadata: the
+    per-instruction `metadata={...}` and the file / function / location
+    / stack-frame tables in the module's head)."""
+    import re
+    import jax
+    # a fresh function object: jit's trace cache is keyed by it, and the
+    # second compile of a test has to trace again
+    full = jax.jit(lambda *a: fn(*a)).lower(*args).compile().as_text()
+    bare = re.sub(r",? ?metadata=\{[^}]*\}", "", full)
+    bare = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n.*?\n\n", "", bare, flags=re.S | re.M)
+    return full, bare
+
+
+@pytest.mark.parametrize("kernel", ["hint_hash_match", "cidr_hash_match",
+                                    "classify_hash_all",
+                                    "fused_classify_pick"])
+def test_stage_scopes_are_metadata_only(kernel, monkeypatch):
+    """jax.named_scope names the stages inside the kernels so a profile
+    can say which stage a device op belongs to. The names must reach the
+    compiled program, and it must otherwise be the very program it is
+    without them (no recompile of a warm cache entry, no other op)."""
+    import contextlib
+    import jax
+    fn, args, stages = _scope_case(kernel)
+    full, bare = _compiled_text(fn, args)
+    stages = [f"/{stage}/" for stage in stages]     # as in an op_name
+    for stage in stages:
+        assert stage in full, f"{kernel}: no op carries {stage!r}"
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    unnamed, bare_unnamed = _compiled_text(fn, args)
+    assert not any(stage in unnamed for stage in stages)
+    assert bare == bare_unnamed

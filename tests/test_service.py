@@ -480,3 +480,72 @@ def test_inline_fast_lane_default_and_parity_vs_oracle():
     assert svc.stats.dispatches == 0
     assert svc.stats.device_queries == 0
     assert svc.stats.inline_fast >= len(queries)
+
+
+# ------------------------------------------------ batch-cycle span totals
+
+@pytest.mark.parametrize("kind", ["hint", "cidr", "cpick"])
+def test_batch_cycle_spans_for_each_kind(kind):
+    """Tracing on: whichever matcher kind a batch rides, its cycle lands
+    in utils/trace's totals — one launch (named by kind, fused for the
+    classify+pick program) per device batch, every real query counted
+    once at encode and once at deliver."""
+    from vproxy_tpu.rules.maglev import FusedPair, MaglevMatcher
+    from vproxy_tpu.utils import trace
+    n = 24
+    hm = HintMatcher(mk_rules(64))
+    if kind == "cidr":
+        m = CidrMatcher([Network(bytes([10, i, 0, 0]), mask_bytes(16))
+                         for i in range(32)])
+        m.match([b"\x0a\x00\x00\x01"] * 16)     # warm the jit
+    else:
+        m = hm if kind == "hint" else FusedPair(
+            hm, MaglevMatcher([(f"b{i}", 1) for i in range(5)], m=251))
+    got, done = [], threading.Event()
+
+    def cb(*verdict):
+        got.append(verdict[0])
+        if len(got) == n:
+            done.set()
+
+    svc = ClassifyService(mode="device")
+    trace.configure(1)
+    try:
+        tid = trace.new_trace_id()
+        with trace.bind(tid):   # every query sampled: one trace
+            # the first submit may ride alone (and compile): totals are
+            # read as a whole, whatever the batches came out as
+            before = trace.span_totals()
+            for i in range(n):
+                h = Hint.of_host(f"svc{i}.example.com")
+                if kind == "hint":
+                    svc.submit_hint(m, h, cb)
+                elif kind == "cidr":
+                    svc.submit_cidr(m, bytes([10, i, 1, 2]), None, cb)
+                else:
+                    svc.submit_classify_pick(m, h, bytes([172, 16, 0, i]),
+                                             80 + i, cb)
+        assert done.wait(60)
+        assert sorted(got) == list(range(n))
+        after = trace.span_totals()
+        spans = trace.get_trace(tid)
+    finally:
+        trace.configure(0)
+        trace.reset()
+        svc.close()
+
+    def moved(span, field):
+        return after[f"engine/{span}"][field] \
+            - before.get(f"engine/{span}", {}).get(field, 0)
+
+    batches = svc.stats.dispatches
+    assert batches >= 1 and svc.stats.device_queries == n
+    for span in ("dispatch", "launch", "d2h_sync", "deliver"):
+        assert moved(span, "n") == batches, span
+    assert moved("encode", "sum_items") == moved("deliver", "sum_items") == n
+    assert moved("queue_wait", "n") == moved("submit_lock_wait", "n") == n
+    launches = [s for s in spans if s["span"] == "launch"]
+    assert len(launches) == batches
+    assert all(s["kind"] == kind and s["fused"] is (kind == "cpick")
+               and s["parent"] == "dispatch" for s in launches)
+    assert sum(s["batch"] for s in spans if s["span"] == "dispatch") == n

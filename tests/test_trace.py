@@ -562,3 +562,230 @@ def test_step_loop_queue_shape():
     spans = trace.get_trace(tid)
     assert any(s["span"] == "host_index" and s["plane"] == "cluster"
                for s in spans)
+
+
+# ------------------------------------------- the dispatcher's batch cycle
+
+CYCLE = ("dispatch", "encode", "launch", "d2h_sync", "deliver")
+
+
+def _totals_delta(before: dict) -> dict:
+    """span_totals() now minus `before`, numeric fields only (totals are
+    process-lifetime: other tests of the session have added to them)."""
+    out = {}
+    for key, tot in trace.span_totals().items():
+        was = before.get(key, {})
+        out[key] = {k: tot[k] - was.get(k, 0)
+                    for k in ("n", "sum_ns", "sum_cpu_ns", "sum_items")}
+    return out
+
+
+def _serve_hints(n, sampled_at=None):
+    """n hint queries through a fresh device-mode service on a small
+    table -> (stats, trace id bound to query `sampled_at` or 0, what
+    serving them added to the span totals)."""
+    from vproxy_tpu.rules.engine import HintMatcher
+    from vproxy_tpu.rules.ir import Hint, HintRule
+    from vproxy_tpu.rules.service import ClassifyService
+    m = HintMatcher([HintRule(host=f"s{i}.example.com") for i in range(64)],
+                    backend="jax")
+    m.match([Hint.of_host("warm.example.com")] * 16)    # compile outside
+    svc = ClassifyService(mode="device")
+    before = trace.span_totals()
+    got, done = [], threading.Event()
+
+    def cb(idx, _pl):
+        got.append(idx)
+        if len(got) == n:
+            done.set()
+
+    tid = 0
+    try:
+        for i in range(n):
+            h = Hint.of_host(f"s{i % 64}.example.com")
+            if i == sampled_at:
+                tid = trace.new_trace_id()
+                with trace.bind(tid):
+                    svc.submit_hint(m, h, cb)
+            else:
+                svc.submit_hint(m, h, cb)
+        assert done.wait(30)
+        assert sorted(got) == sorted(i % 64 for i in range(n))
+    finally:
+        svc.close()
+        if svc._thread is not None:
+            svc._thread.join(10)    # leaving the last wait records it
+            assert not svc._thread.is_alive()
+    return svc.stats, tid, _totals_delta(before)
+
+
+def test_batch_cycle_totals_every_batch():
+    """Tracing on: every batch adds one dispatch / launch / d2h_sync /
+    deliver and its encode to the totals, with items and CPU time."""
+    trace.configure(1)
+    stats, _tid, d = _serve_hints(40)
+    batches = stats.dispatches
+    assert batches >= 1
+    for span in CYCLE:
+        assert d[f"engine/{span}"]["n"] == batches, (span, d)
+        assert d[f"engine/{span}"]["sum_ns"] > 0
+    assert d["engine/wait"]["n"] >= 1 and d["engine/wait"]["sum_ns"] > 0
+    for span in ("encode", "deliver"):
+        tot = d[f"engine/{span}"]
+        assert tot["sum_items"] == 40
+        assert 0 < tot["sum_cpu_ns"] <= tot["sum_ns"]
+    tot = trace.span_totals()["engine/launch"]
+    assert sum(tot["buckets"]) == tot["n"] and len(tot["buckets"]) == 28
+    assert 0 < tot["first_ns"] <= tot["last_ns"]
+
+
+def test_sampled_request_trace_holds_the_cycle_nested():
+    """The batch's spans are buffered on its first sampled request:
+    encode + launch lie inside dispatch and name it as parent; the
+    request's own submit_lock_wait and queue_wait are there too."""
+    trace.configure(1)
+    _stats, tid, _d = _serve_hints(12, sampled_at=0)
+    by = {}
+    for s in trace.get_trace(tid):
+        assert s["plane"] == "engine"
+        by.setdefault(s["span"], []).append(s)
+    assert set(CYCLE) | {"submit_lock_wait", "queue_wait"} <= set(by), by
+    disp = by["dispatch"][0]
+    for name in ("encode", "launch"):
+        s = by[name][0]
+        assert s["parent"] == "dispatch"
+        assert disp["t_ns"] <= s["t_ns"] and \
+            s["t_ns"] + s["dur_ns"] <= disp["t_ns"] + disp["dur_ns"]
+    assert by["launch"][0]["fused"] is False
+    assert by["launch"][0]["bucket"] >= disp["batch"] >= 1
+    assert by["queue_wait"][0]["batch"] == disp["batch"]
+    assert by["encode"][0]["items"] == disp["batch"]
+    assert by["encode"][0]["cpu_ns"] <= by["encode"][0]["dur_ns"]
+    assert by["deliver"][0]["items"] == by["d2h_sync"][0]["batch"]
+    ends = [by[n][0]["t_ns"] for n in
+            ("submit_lock_wait", "queue_wait", "dispatch", "d2h_sync",
+             "deliver")]
+    assert ends == sorted(ends)     # one clock, the order of the cycle
+
+
+def test_span_totals_survive_reset():
+    trace.configure(1)
+    _serve_hints(4)
+    before = trace.span_totals()
+    assert before["engine/launch"]["n"] >= 1
+    trace.reset()
+    assert trace.span_totals() == before and not trace.trace_ids()
+
+
+def test_unsampled_batch_adds_totals_and_no_trace():
+    """No batch-only traces: they would evict every request trace."""
+    trace.configure(64)
+    stats, _tid, d = _serve_hints(10)
+    assert d["engine/launch"]["n"] == stats.dispatches >= 1
+    assert d["engine/deliver"]["sum_items"] == 10
+    assert trace.trace_ids() == []
+    assert d.get("engine/queue_wait", {"n": 0})["n"] == 0
+
+
+def test_tracing_off_leaves_no_totals_no_spans_no_gc_hook():
+    import gc
+    assert not any(getattr(cb, "__module__", "") == trace.__name__
+                   for cb in gc.callbacks)
+    _stats, tid, d = _serve_hints(10, sampled_at=3)  # a bound id, knob off
+    assert all(v["n"] == 0 for v in d.values())
+    assert trace.get_trace(tid) == [] and trace.trace_ids() == []
+
+
+def test_gc_hook_lives_while_tracing_is_on():
+    import gc
+    trace.configure(8)
+    assert gc.callbacks.count(trace._gc_hook) == 1
+    trace.configure(16)     # no second copy
+    assert gc.callbacks.count(trace._gc_hook) == 1
+    before = trace.span_totals().get("runtime/gc_pause", {"n": 0})
+    gc.collect()
+    after = trace.span_totals()["runtime/gc_pause"]
+    assert after["n"] == before["n"] + 1 and after["sum_ns"] > 0
+    trace.configure(0)
+    assert trace._gc_hook not in gc.callbacks
+    gc.collect()
+    assert trace.span_totals()["runtime/gc_pause"]["n"] == after["n"]
+
+
+def test_span_metrics_family_preregistered_at_zero_and_env_knob():
+    """A fresh process: the whole vproxy_trace_span_us{plane,span}
+    vocabulary is on /metrics at zero with the knob unset and nothing
+    is hooked into the collector; VPROXY_TPU_TRACE_SAMPLE=N at import
+    installs the gc hook."""
+    prog = (
+        "import gc;"
+        "from vproxy_tpu.utils import trace;"
+        "import jax;"   # collections run inside this import, hook or not
+        "from vproxy_tpu.utils.metrics import GlobalInspection;"
+        "t = GlobalInspection.get().prometheus_string();"
+        "print(int(trace._gc_hook in gc.callbacks), trace.span_totals());"
+        "print('\\n'.join(l for l in t.splitlines()"
+        "      if l.startswith('vproxy_trace_span_us_count')))")
+    import os
+    env = {k: v for k, v in os.environ.items()
+           if k != "VPROXY_TPU_TRACE_SAMPLE"}
+    out = subprocess.run([sys.executable, "-c", prog], env=env,
+                         capture_output=True, text=True, timeout=60)
+    lines = out.stdout.strip().splitlines()
+    assert lines[0] == "0 {}", (out.stdout, out.stderr)
+    assert sorted(lines[1:]) == sorted(
+        f'vproxy_trace_span_us_count{{plane="{p}",span="{s}"}} 0'
+        for p, s in trace.SPANS)
+    out = subprocess.run([sys.executable, "-c", prog],
+                         env=dict(env, VPROXY_TPU_TRACE_SAMPLE="8"),
+                         capture_output=True, text=True, timeout=60)
+    assert out.stdout.startswith("1 ") and not out.stderr, \
+        (out.stdout, out.stderr)
+
+
+def test_span_metrics_family_follows_the_totals():
+    from vproxy_tpu.utils.metrics import GlobalInspection
+    trace.configure(1)
+    _serve_hints(6)
+    tot = trace.span_totals()["engine/launch"]
+    text = GlobalInspection.get().prometheus_string()
+    lbl = '{plane="engine",span="launch"}'
+    assert f"vproxy_trace_span_us_count{lbl} {tot['n']}" in text
+    assert f'vproxy_trace_span_us_bucket{{le="+Inf",plane="engine",' \
+           f'span="launch"}} {tot["n"]}' in text
+    got = float(next(l for l in text.splitlines() if l.startswith(
+        f"vproxy_trace_span_us_sum{lbl}")).split()[-1])
+    assert got == pytest.approx(tot["sum_ns"] / 1000.0)
+
+
+def test_spans_enter_the_profiler_trace(tmp_path):
+    """While tracing is on the batch cycle's spans are TraceAnnotations
+    named vproxy/<plane>/<span>: a jax.profiler trace of a served batch
+    holds them on its own clock, encode + launch inside dispatch."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    trace.configure(1)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _serve_hints(8)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                         "*.xplane.pb"))[0]
+    evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+           for plane in ProfileData.from_file(path).planes
+           if not plane.name.startswith("/device:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith("vproxy/")]
+    names = {n for n, _s, _e in evs}
+    assert {f"vproxy/engine/{s}" for s in CYCLE + ("wait",)} <= names
+    disp = [(s, e) for n, s, e in evs if n == "vproxy/engine/dispatch"]
+    assert disp
+    for ds, de in disp:     # (the warm-up's encode + launch have none)
+        for inner in ("vproxy/engine/encode", "vproxy/engine/launch"):
+            assert any(n == inner and ds <= s and e <= de
+                       for n, s, e in evs), (inner, ds, de)

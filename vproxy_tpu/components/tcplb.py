@@ -950,7 +950,7 @@ class TcpLB:
 
         try:
             # the submit rides the trace context so the classify plane
-            # (queue wait / dispatch / launch markers) attaches its
+            # (queue wait / dispatch / launch spans) attaches its
             # spans to THIS request's trace
             with trace.bind(tid):
                 self.security_group.allow_async(Proto.TCP, parse_ip(ip),

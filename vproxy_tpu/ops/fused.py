@@ -186,72 +186,85 @@ def _hint_verdict_packed(t: dict, q: dict):
     has_uri = "pk_uslot" in t  # static: uri-free tables compile a
     #                            program with NO uri work (pack doc)
 
-    ch1 = _packed_probe(q["hp_slot1"], q["hp_len"], t["pk_hslot"],
-                        t["pk_hkey"], q["hostb"], t["bh_iota"])
-    ch2 = _packed_probe(q["hp_slot2"], q["hp_len"], t["pk_hslot"],
-                        t["pk_hkey"], q["hostb"], t["bh_iota"])
-    host_cand = jnp.where(ch1 >= 0, t["hb_items"][jnp.maximum(ch1, 0)], -1)
-    host_cand2 = jnp.where(ch2 >= 0, t["hb_items"][jnp.maximum(ch2, 0)], -1)
-    parts = [host_cand.reshape(b, -1), host_cand2.reshape(b, -1)]
+    # stage names as in hashmatch.hint_hash_match (jax.named_scope:
+    # metadata only, the compiled program is the same)
+    with jax.named_scope("hint_probe"):
+        ch1 = _packed_probe(q["hp_slot1"], q["hp_len"], t["pk_hslot"],
+                            t["pk_hkey"], q["hostb"], t["bh_iota"])
+        ch2 = _packed_probe(q["hp_slot2"], q["hp_len"], t["pk_hslot"],
+                            t["pk_hkey"], q["hostb"], t["bh_iota"])
+    with jax.named_scope("hint_candidates"):
+        host_cand = jnp.where(ch1 >= 0,
+                              t["hb_items"][jnp.maximum(ch1, 0)], -1)
+        host_cand2 = jnp.where(ch2 >= 0,
+                               t["hb_items"][jnp.maximum(ch2, 0)], -1)
+        parts = [host_cand.reshape(b, -1), host_cand2.reshape(b, -1)]
     if has_uri:
-        cu1 = _packed_probe(q["up_slot1"], q["up_len"], t["pk_uslot"],
-                            t["pk_ukey"], q["urib"], t["bu_iota"])
-        cu2 = _packed_probe(q["up_slot2"], q["up_len"], t["pk_uslot"],
-                            t["pk_ukey"], q["urib"], t["bu_iota"])
-        parts.append(jnp.where(
-            cu1 >= 0, t["ub_items"][jnp.maximum(cu1, 0)], -1)
-            .reshape(b, -1))
-        parts.append(jnp.where(
-            cu2 >= 0, t["ub_items"][jnp.maximum(cu2, 0)], -1)
-            .reshape(b, -1))
-    parts.append(jnp.broadcast_to(t["wh_idx"][None],
-                                  (b, t["wh_idx"].shape[0])))
-    if has_uri:
-        parts.append(jnp.broadcast_to(t["wu_idx"][None],
-                                      (b, t["wu_idx"].shape[0])))
-    cand = jnp.concatenate(parts, axis=1)  # [B, NC]
+        with jax.named_scope("hint_probe"):
+            cu1 = _packed_probe(q["up_slot1"], q["up_len"], t["pk_uslot"],
+                                t["pk_ukey"], q["urib"], t["bu_iota"])
+            cu2 = _packed_probe(q["up_slot2"], q["up_len"], t["pk_uslot"],
+                                t["pk_ukey"], q["urib"], t["bu_iota"])
+        with jax.named_scope("hint_candidates"):
+            parts.append(jnp.where(
+                cu1 >= 0, t["ub_items"][jnp.maximum(cu1, 0)], -1)
+                .reshape(b, -1))
+            parts.append(jnp.where(
+                cu2 >= 0, t["ub_items"][jnp.maximum(cu2, 0)], -1)
+                .reshape(b, -1))
+    with jax.named_scope("hint_candidates"):
+        parts.append(jnp.broadcast_to(t["wh_idx"][None],
+                                      (b, t["wh_idx"].shape[0])))
+        if has_uri:
+            parts.append(jnp.broadcast_to(t["wu_idx"][None],
+                                          (b, t["wu_idx"].shape[0])))
+        cand = jnp.concatenate(parts, axis=1)  # [B, NC]
 
-    c = jnp.maximum(cand, 0)
-    meta = t["pk_meta"][c]   # [B, NC, 8] — one sweep over the records
-    by = t["pk_bytes"][c]    # [B, NC, hw+uw] — one sweep over the bytes
-    valid = (cand >= 0) & (meta[..., 0] > 0)
+    with jax.named_scope("hint_score"):
+        c = jnp.maximum(cand, 0)
+        meta = t["pk_meta"][c]   # [B, NC, 8] — one sweep over the records
+        by = t["pk_bytes"][c]    # [B, NC, hw+uw] — one sweep over the bytes
+        valid = (cand >= 0) & (meta[..., 0] > 0)
 
-    rp = meta[..., 1]
-    pg = (q["port"][:, None] == 0) | (rp == 0) | (q["port"][:, None] == rp)
+        rp = meta[..., 1]
+        pg = (q["port"][:, None] == 0) | (rp == 0) | \
+            (q["port"][:, None] == rp)
 
-    hk, hl_ = meta[..., 2], meta[..., 3]
-    rb = by[..., :hw]
-    span = jnp.arange(hw, dtype=jnp.int32)
-    heq = jnp.all((rb == q["hostb"][:, None, :hw]) |
-                  (span[None, None, :] >= hl_[:, :, None]), axis=-1)
-    exact = heq & (hl_ == q["hlen"][:, None])
-    boundary = jnp.take_along_axis(
-        q["hostb"], jnp.clip(hl_, 0, hw - 1), axis=1)
-    suffix = heq & (hl_ < q["hlen"][:, None]) & (boundary == DOT)
-    host_level = jnp.maximum(
-        jnp.maximum(jnp.where(exact, 3, 0), jnp.where(suffix, 2, 0)),
-        jnp.where(hk == 2, 1, 0))
-    host_level = jnp.where((hk > 0) & q["has_host"][:, None], host_level, 0)
+        hk, hl_ = meta[..., 2], meta[..., 3]
+        rb = by[..., :hw]
+        span = jnp.arange(hw, dtype=jnp.int32)
+        heq = jnp.all((rb == q["hostb"][:, None, :hw]) |
+                      (span[None, None, :] >= hl_[:, :, None]), axis=-1)
+        exact = heq & (hl_ == q["hlen"][:, None])
+        boundary = jnp.take_along_axis(
+            q["hostb"], jnp.clip(hl_, 0, hw - 1), axis=1)
+        suffix = heq & (hl_ < q["hlen"][:, None]) & (boundary == DOT)
+        host_level = jnp.maximum(
+            jnp.maximum(jnp.where(exact, 3, 0), jnp.where(suffix, 2, 0)),
+            jnp.where(hk == 2, 1, 0))
+        host_level = jnp.where((hk > 0) & q["has_host"][:, None],
+                               host_level, 0)
 
-    if has_uri:
-        uw = by.shape[-1] - hw
-        uk, ul = meta[..., 4], meta[..., 5]
-        ub = by[..., hw:]
-        uspan = jnp.arange(uw, dtype=jnp.int32)
-        ueq = jnp.all((ub == q["urib"][:, None, :uw]) |
-                      (uspan[None, None, :] >= ul[:, :, None]), axis=-1)
-        prefix = ueq & (ul <= q["ulen"][:, None])
-        uri_level = jnp.maximum(jnp.where(prefix, meta[..., 6], 0),
-                                jnp.where(uk == 2, 1, 0))
-        uri_level = jnp.where((uk > 0) & q["has_uri"][:, None],
-                              uri_level, 0)
-    else:
-        uri_level = 0  # no uri rules exist: nothing can score by uri
+        if has_uri:
+            uw = by.shape[-1] - hw
+            uk, ul = meta[..., 4], meta[..., 5]
+            ub = by[..., hw:]
+            uspan = jnp.arange(uw, dtype=jnp.int32)
+            ueq = jnp.all((ub == q["urib"][:, None, :uw]) |
+                          (uspan[None, None, :] >= ul[:, :, None]), axis=-1)
+            prefix = ueq & (ul <= q["ulen"][:, None])
+            uri_level = jnp.maximum(jnp.where(prefix, meta[..., 6], 0),
+                                    jnp.where(uk == 2, 1, 0))
+            uri_level = jnp.where((uk > 0) & q["has_uri"][:, None],
+                                  uri_level, 0)
+        else:
+            uri_level = 0  # no uri rules exist: nothing can score by uri
 
-    level = (host_level << HOST_SHIFT) + uri_level
-    level = jnp.where(valid & pg, level, 0)
+        level = (host_level << HOST_SHIFT) + uri_level
+        level = jnp.where(valid & pg, level, 0)
     from .hashmatch import _reduce_best
-    return _reduce_best(level, c, r_cap)
+    with jax.named_scope("hint_reduce"):
+        return _reduce_best(level, c, r_cap)
 
 
 def _cidr_first_packed(t: dict, addr16, fam, port):
@@ -259,32 +272,38 @@ def _cidr_first_packed(t: dict, addr16, fam, port):
     [B, G, 4] gather + the key row; rule gate one pk_cmeta row."""
     r_cap = t["pk_cmeta"].shape[0]
     b = addr16.shape[0]
-    masked = addr16[:, None, :] & t["g_mask"][None]  # [B, G, 16]
-    gok = (t["g_fam"][None] >= 0) & (fam[:, None] == t["g_fam"][None])
+    with jax.named_scope("cidr_mask"):
+        masked = addr16[:, None, :] & t["g_mask"][None]  # [B, G, 16]
+        gok = (t["g_fam"][None] >= 0) & (fam[:, None] == t["g_fam"][None])
 
     cands = []
     for salt in (t["g_salt1"], t["g_salt2"]):
-        h = _fnv32_device(masked, salt)
-        slot = t["g_off"][None] + (
-            h.astype(jnp.int32) & t["g_capmask"][None])
-        srec = t["pk_cslot"][slot]  # [B, G, 4]
-        key = t["s_key"][slot]      # [B, G, 16]
-        ok = gok & (srec[..., 0] > 0) & jnp.all(key == masked, axis=-1)
-        start, cnt = srec[..., 1], srec[..., 2]
-        j = t["bk_iota"][None, None, :]
-        cands.append(jnp.where(ok[:, :, None] & (j < cnt[:, :, None]),
-                               start[:, :, None] + j, -1))
-    slot_cand = jnp.concatenate(cands, axis=1).reshape(b, -1)
-    cand = jnp.where(slot_cand >= 0,
-                     t["cb_items"][jnp.maximum(slot_cand, 0)], -1)
-    c = jnp.maximum(cand, 0)
-    meta = t["pk_cmeta"][c]  # [B, NC, 4]
-    valid = (cand >= 0) & (meta[..., 0] > 0)
-    if port is not None:
-        valid = valid & (meta[..., 1] <= port[:, None]) & \
-            (port[:, None] <= meta[..., 2])
-    first = jnp.min(jnp.where(valid, c, r_cap), axis=1).astype(jnp.int32)
-    return jnp.where(first < r_cap, first, -1)
+        with jax.named_scope("cidr_hash"):
+            h = _fnv32_device(masked, salt)
+            slot = t["g_off"][None] + (
+                h.astype(jnp.int32) & t["g_capmask"][None])
+        with jax.named_scope("cidr_probe"):
+            srec = t["pk_cslot"][slot]  # [B, G, 4]
+            key = t["s_key"][slot]      # [B, G, 16]
+            ok = gok & (srec[..., 0] > 0) & jnp.all(key == masked, axis=-1)
+            start, cnt = srec[..., 1], srec[..., 2]
+            j = t["bk_iota"][None, None, :]
+            cands.append(jnp.where(ok[:, :, None] & (j < cnt[:, :, None]),
+                                   start[:, :, None] + j, -1))
+    with jax.named_scope("cidr_candidates"):
+        slot_cand = jnp.concatenate(cands, axis=1).reshape(b, -1)
+        cand = jnp.where(slot_cand >= 0,
+                         t["cb_items"][jnp.maximum(slot_cand, 0)], -1)
+        c = jnp.maximum(cand, 0)
+    with jax.named_scope("cidr_gate"):
+        meta = t["pk_cmeta"][c]  # [B, NC, 4]
+        valid = (cand >= 0) & (meta[..., 0] > 0)
+        if port is not None:
+            valid = valid & (meta[..., 1] <= port[:, None]) & \
+                (port[:, None] <= meta[..., 2])
+    with jax.named_scope("cidr_reduce"):
+        first = jnp.min(jnp.where(valid, c, r_cap), axis=1).astype(jnp.int32)
+        return jnp.where(first < r_cap, first, -1)
 
 
 def fused_classify_pick(ht: dict, q: dict, mtab, slots,
@@ -297,7 +316,8 @@ def fused_classify_pick(ht: dict, q: dict, mtab, slots,
     slots (the shared hash contract of rules/maglev.py) so the pick
     column is bit-identical with every other pick plane."""
     v, _level = _hint_verdict_packed(ht, q)
-    p = jnp.take(mtab, slots, mode="clip").astype(jnp.int32)
+    with jax.named_scope("maglev_pick"):
+        p = jnp.take(mtab, slots, mode="clip").astype(jnp.int32)
     cols = [v, p]
     if ct is not None:
         cols.append(_cidr_first_packed(ct, a16, fam, port))

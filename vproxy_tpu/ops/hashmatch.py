@@ -522,68 +522,73 @@ def hint_hash_match(t: dict, q: dict):
     r_cap = t["r_active"].shape[0]
     b = q["hostb"].shape[0]
 
-    ch1 = _probe_buckets(q["hp_slot1"], q["hp_len"], t["hk_used"], t["hk_len"],
-                         t["hk_bytes"], t["hk_bs"], t["hk_bc"], q["hostb"],
-                         t["bh_iota"])
-    ch2 = _probe_buckets(q["hp_slot2"], q["hp_len"], t["hk_used"], t["hk_len"],
-                         t["hk_bytes"], t["hk_bs"], t["hk_bc"], q["hostb"],
-                         t["bh_iota"])
-    cu1 = _probe_buckets(q["up_slot1"], q["up_len"], t["uk_used"], t["uk_len"],
-                         t["uk_bytes"], t["uk_bs"], t["uk_bc"], q["urib"],
-                         t["bu_iota"])
-    cu2 = _probe_buckets(q["up_slot2"], q["up_len"], t["uk_used"], t["uk_len"],
-                         t["uk_bytes"], t["uk_bs"], t["uk_bc"], q["urib"],
-                         t["bu_iota"])
-    host_cand = jnp.where(ch1 >= 0, t["hb_items"][jnp.maximum(ch1, 0)], -1)
-    host_cand2 = jnp.where(ch2 >= 0, t["hb_items"][jnp.maximum(ch2, 0)], -1)
-    uri_cand = jnp.where(cu1 >= 0, t["ub_items"][jnp.maximum(cu1, 0)], -1)
-    uri_cand2 = jnp.where(cu2 >= 0, t["ub_items"][jnp.maximum(cu2, 0)], -1)
+    # the stages carry jax.named_scope names (metadata only: the compiled
+    # program is the same) so a profile can say which stage an op is of
+    with jax.named_scope("hint_probe"):       # slot gathers + byte-verify
+        htab = (t["hk_used"], t["hk_len"], t["hk_bytes"], t["hk_bs"],
+                t["hk_bc"], q["hostb"], t["bh_iota"])
+        utab = (t["uk_used"], t["uk_len"], t["uk_bytes"], t["uk_bs"],
+                t["uk_bc"], q["urib"], t["bu_iota"])
+        ch1 = _probe_buckets(q["hp_slot1"], q["hp_len"], *htab)
+        ch2 = _probe_buckets(q["hp_slot2"], q["hp_len"], *htab)
+        cu1 = _probe_buckets(q["up_slot1"], q["up_len"], *utab)
+        cu2 = _probe_buckets(q["up_slot2"], q["up_len"], *utab)
+    with jax.named_scope("hint_candidates"):  # bucket items -> rule ids
+        def items(of, ch):
+            return jnp.where(ch >= 0, t[of][jnp.maximum(ch, 0)], -1)
+        host_cand, host_cand2 = items("hb_items", ch1), items("hb_items", ch2)
+        uri_cand, uri_cand2 = items("ub_items", cu1), items("ub_items", cu2)
 
-    cand = jnp.concatenate([
-        host_cand.reshape(b, -1), host_cand2.reshape(b, -1),
-        uri_cand.reshape(b, -1), uri_cand2.reshape(b, -1),
-        jnp.broadcast_to(t["wh_idx"][None], (b, t["wh_idx"].shape[0])),
-        jnp.broadcast_to(t["wu_idx"][None], (b, t["wu_idx"].shape[0])),
-    ], axis=1)  # [B, NC]
+        cand = jnp.concatenate([
+            host_cand.reshape(b, -1), host_cand2.reshape(b, -1),
+            uri_cand.reshape(b, -1), uri_cand2.reshape(b, -1),
+            jnp.broadcast_to(t["wh_idx"][None], (b, t["wh_idx"].shape[0])),
+            jnp.broadcast_to(t["wu_idx"][None], (b, t["wu_idx"].shape[0])),
+        ], axis=1)  # [B, NC]
 
-    c = jnp.maximum(cand, 0)
-    valid = (cand >= 0) & t["r_active"][c]
+        c = jnp.maximum(cand, 0)
+        valid = (cand >= 0) & t["r_active"][c]
 
-    # port gate (Hint.java: ports both set and different -> no match)
-    rp = t["r_port"][c]
-    pg = (q["port"][:, None] == 0) | (rp == 0) | (q["port"][:, None] == rp)
+    with jax.named_scope("hint_score"):       # rule-record gathers + compare
+        # port gate (Hint.java: ports both set and different -> no match)
+        rp = t["r_port"][c]
+        pg = (q["port"][:, None] == 0) | (rp == 0) | \
+            (q["port"][:, None] == rp)
 
-    # host level: exact=3 / dot-suffix=2 / wildcard=1 (max of applicable)
-    hw = t["r_host"].shape[1]
-    hk, hl_ = t["r_host_kind"][c], t["r_host_len"][c]
-    rb = t["r_host"][c]  # [B, NC, hw]
-    span = jnp.arange(hw, dtype=jnp.int32)
-    heq = jnp.all((rb == q["hostb"][:, None, :hw]) |
-                  (span[None, None, :] >= hl_[:, :, None]), axis=-1)
-    exact = heq & (hl_ == q["hlen"][:, None])
-    boundary = jnp.take_along_axis(
-        q["hostb"], jnp.clip(hl_, 0, hw - 1), axis=1)
-    suffix = heq & (hl_ < q["hlen"][:, None]) & (boundary == DOT)
-    host_level = jnp.maximum(
-        jnp.maximum(jnp.where(exact, 3, 0), jnp.where(suffix, 2, 0)),
-        jnp.where(hk == 2, 1, 0))
-    host_level = jnp.where((hk > 0) & q["has_host"][:, None], host_level, 0)
+        # host level: exact=3 / dot-suffix=2 / wildcard=1 (max of applicable)
+        hw = t["r_host"].shape[1]
+        hk, hl_ = t["r_host_kind"][c], t["r_host_len"][c]
+        rb = t["r_host"][c]  # [B, NC, hw]
+        span = jnp.arange(hw, dtype=jnp.int32)
+        heq = jnp.all((rb == q["hostb"][:, None, :hw]) |
+                      (span[None, None, :] >= hl_[:, :, None]), axis=-1)
+        exact = heq & (hl_ == q["hlen"][:, None])
+        boundary = jnp.take_along_axis(
+            q["hostb"], jnp.clip(hl_, 0, hw - 1), axis=1)
+        suffix = heq & (hl_ < q["hlen"][:, None]) & (boundary == DOT)
+        host_level = jnp.maximum(
+            jnp.maximum(jnp.where(exact, 3, 0), jnp.where(suffix, 2, 0)),
+            jnp.where(hk == 2, 1, 0))
+        host_level = jnp.where((hk > 0) & q["has_host"][:, None],
+                               host_level, 0)
 
-    # uri level: exact/prefix -> min(len(rule.uri)+1, 1023), wildcard -> 1
-    uw = t["r_uri"].shape[1]
-    uk, ul = t["r_uri_kind"][c], t["r_uri_len"][c]
-    ub = t["r_uri"][c]  # [B, NC, uw]
-    uspan = jnp.arange(uw, dtype=jnp.int32)
-    ueq = jnp.all((ub == q["urib"][:, None, :]) |
-                  (uspan[None, None, :] >= ul[:, :, None]), axis=-1)
-    prefix = ueq & (ul <= q["ulen"][:, None])
-    uri_level = jnp.maximum(jnp.where(prefix, t["r_uri_score"][c], 0),
-                            jnp.where(uk == 2, 1, 0))
-    uri_level = jnp.where((uk > 0) & q["has_uri"][:, None], uri_level, 0)
+        # uri level: exact/prefix -> min(len(rule.uri)+1, 1023), wildcard -> 1
+        uw = t["r_uri"].shape[1]
+        uk, ul = t["r_uri_kind"][c], t["r_uri_len"][c]
+        ub = t["r_uri"][c]  # [B, NC, uw]
+        uspan = jnp.arange(uw, dtype=jnp.int32)
+        ueq = jnp.all((ub == q["urib"][:, None, :]) |
+                      (uspan[None, None, :] >= ul[:, :, None]), axis=-1)
+        prefix = ueq & (ul <= q["ulen"][:, None])
+        uri_level = jnp.maximum(jnp.where(prefix, t["r_uri_score"][c], 0),
+                                jnp.where(uk == 2, 1, 0))
+        uri_level = jnp.where((uk > 0) & q["has_uri"][:, None],
+                              uri_level, 0)
 
-    level = (host_level << HOST_SHIFT) + uri_level
-    level = jnp.where(valid & pg, level, 0)
-    return _reduce_best(level, c, r_cap)
+        level = (host_level << HOST_SHIFT) + uri_level
+        level = jnp.where(valid & pg, level, 0)
+    with jax.named_scope("hint_reduce"):      # best level, first index
+        return _reduce_best(level, c, r_cap)
 
 
 # --------------------------------------------------------------- cidr side
@@ -726,30 +731,36 @@ def cidr_hash_match(t: dict, addr16: jnp.ndarray, fam: jnp.ndarray,
     if none. addr16 [B,16] u8, fam [B] i32, port [B] i32 (ACL only)."""
     r_cap = t["r_valid"].shape[0]
     b = addr16.shape[0]
-    masked = addr16[:, None, :] & t["g_mask"][None]  # [B, G, 16]
-    gok = (t["g_fam"][None] >= 0) & (fam[:, None] == t["g_fam"][None])
+    with jax.named_scope("cidr_mask"):      # per-group masked address
+        masked = addr16[:, None, :] & t["g_mask"][None]  # [B, G, 16]
+        gok = (t["g_fam"][None] >= 0) & (fam[:, None] == t["g_fam"][None])
 
     cands = []
     for salt in (t["g_salt1"], t["g_salt2"]):
-        h = _fnv32_device(masked, salt)
-        slot = t["g_off"][None] + (
-            h.astype(jnp.int32) & t["g_capmask"][None])
-        key = t["s_key"][slot]  # [B, G, 16]
-        ok = gok & t["s_used"][slot] & jnp.all(key == masked, axis=-1)
-        start, cnt = t["s_bs"][slot], t["s_bc"][slot]
-        j = t["bk_iota"][None, None, :]
-        cands.append(jnp.where(ok[:, :, None] & (j < cnt[:, :, None]),
-                               start[:, :, None] + j, -1))
-    slot_cand = jnp.concatenate(cands, axis=1).reshape(b, -1)
-    cand = jnp.where(slot_cand >= 0,
-                     t["cb_items"][jnp.maximum(slot_cand, 0)], -1)
-    c = jnp.maximum(cand, 0)
-    valid = (cand >= 0) & t["r_valid"][c]
-    if port is not None:
-        valid = valid & (t["min_port"][c] <= port[:, None]) & \
-            (port[:, None] <= t["max_port"][c])
-    first = jnp.min(jnp.where(valid, c, r_cap), axis=1).astype(jnp.int32)
-    return jnp.where(first < r_cap, first, -1)
+        with jax.named_scope("cidr_hash"):  # FNV over the 16 masked bytes
+            h = _fnv32_device(masked, salt)
+            slot = t["g_off"][None] + (
+                h.astype(jnp.int32) & t["g_capmask"][None])
+        with jax.named_scope("cidr_probe"):  # slot gathers + key verify
+            key = t["s_key"][slot]  # [B, G, 16]
+            ok = gok & t["s_used"][slot] & jnp.all(key == masked, axis=-1)
+            start, cnt = t["s_bs"][slot], t["s_bc"][slot]
+            j = t["bk_iota"][None, None, :]
+            cands.append(jnp.where(ok[:, :, None] & (j < cnt[:, :, None]),
+                                   start[:, :, None] + j, -1))
+    with jax.named_scope("cidr_candidates"):    # bucket items -> rule ids
+        slot_cand = jnp.concatenate(cands, axis=1).reshape(b, -1)
+        cand = jnp.where(slot_cand >= 0,
+                         t["cb_items"][jnp.maximum(slot_cand, 0)], -1)
+        c = jnp.maximum(cand, 0)
+    with jax.named_scope("cidr_gate"):      # rule validity + port range
+        valid = (cand >= 0) & t["r_valid"][c]
+        if port is not None:
+            valid = valid & (t["min_port"][c] <= port[:, None]) & \
+                (port[:, None] <= t["max_port"][c])
+    with jax.named_scope("cidr_reduce"):    # first match = least index
+        first = jnp.min(jnp.where(valid, c, r_cap), axis=1).astype(jnp.int32)
+        return jnp.where(first < r_cap, first, -1)
 
 
 def classify_hash_all(hint_t: dict, route_t: dict, acl_t: dict,
@@ -758,9 +769,12 @@ def classify_hash_all(hint_t: dict, route_t: dict, acl_t: dict,
     """The fused flagship step: one dispatch classifies a micro-batch of
     LB/DNS hints + route LPM + ACL checks; one packed [B, 3] i32 result
     so the host pays a single d2h per step."""
-    h_idx, _ = hint_hash_match(hint_t, hint_q)
-    r_idx = cidr_hash_match(route_t, addr16, fam, None)
-    a_idx = cidr_hash_match(acl_t, addr16, fam, port)
+    with jax.named_scope("hint"):
+        h_idx, _ = hint_hash_match(hint_t, hint_q)
+    with jax.named_scope("route"):
+        r_idx = cidr_hash_match(route_t, addr16, fam, None)
+    with jax.named_scope("acl"):
+        a_idx = cidr_hash_match(acl_t, addr16, fam, port)
     return jnp.stack([h_idx, r_idx, a_idx], axis=1)
 
 

@@ -63,6 +63,7 @@ from ..ops import hashmatch as H
 from ..ops import tables as T
 from ..ops.bitmatch import unpack_bits
 from ..ops.matchers import cidr_match_jit, hint_match_jit, table_arrays
+from ..utils import trace
 from ..utils.log import Logger
 from . import oracle
 from .ir import AclRule, Hint, HintRule, Proto
@@ -177,7 +178,6 @@ def _install_phase(tid: int, span: str, t0_ns: int, **fields) -> None:
     installer's trace (utils/trace) — tid 0 (constructor compiles, or
     tracing off) records nothing."""
     if tid:
-        from ..utils import trace
         trace.record_span(tid, "install", span, t0_ns,
                           time.monotonic_ns() - t0_ns, **fields)
 
@@ -238,26 +238,36 @@ def generation_total() -> int:
     return _GENERATION[0]
 
 
-def note_launch(n: int = 1, kind: str = "", fused: bool = False) -> None:
+def note_launch(n: int = 1) -> None:
     """Count one device launch on the dispatch path (a lock-free int
     store race can only lose a count, never corrupt — same contract as
     the C-side counters). This is what makes the fused path's
     one-launch-per-batch claim SCRAPE-verifiable
     (vproxy_engine_dispatch_launches_total) instead of bench-asserted:
     every jitted submit site increments it, so fused batches move the
-    counter by exactly 1 and the unfused chain by one per chained op.
-
-    Tracing (utils/trace): when the calling thread carries a sampled
-    request's trace context, every launch site also drops a `launch`
-    marker span — fused vs unfused distinguishable per launch, so a
-    trace shows exactly how many programs a batch really cost. One
-    branch when no context is bound."""
+    counter by exactly 1 and the unfused chain by one per chained op."""
     _LAUNCHES[0] += n
-    from ..utils import trace
-    tid = trace.current_id()
-    if tid:
-        trace.record_span(tid, "engine", "launch", time.monotonic_ns(),
-                          0, kind=kind, fused=fused)
+
+
+def launch_span(kind: str, bucket: int, fused: bool = False):
+    """Count one device launch (note_launch) and time it: the `launch`
+    span (utils/trace) around the jitted call itself. That call
+    enqueues the program AND uploads its numpy arguments — the served
+    path makes no explicit device_put, so the two are one number here.
+    fused vs unfused is distinguishable per launch, so a sampled
+    request's trace shows how many programs its batch really cost.
+    bucket: the padded batch the program was compiled for."""
+    note_launch()
+    return trace.span("engine", "launch", kind=kind, fused=fused,
+                      bucket=bucket, parent="dispatch")
+
+
+def encode_span(items: int):
+    """The `encode` span around the host encode + padding of one batch.
+    items: real queries encoded (0 where a second encoder handles the
+    same queries, so a batch's queries count once)."""
+    return trace.span("engine", "encode", cpu=True, items=items,
+                      parent="dispatch")
 
 
 def dispatch_launches_total() -> int:
@@ -425,7 +435,6 @@ class TableInstaller:
                 # (compile / upload / swap) attach through the bound
                 # context, so an install-under-load trace shows the
                 # standby build bracketing unstalled dispatches
-                from ..utils import trace
                 itid = trace.new_trace_id() if trace.enabled() else 0
                 with trace.bind(itid):
                     matcher._install(args)
@@ -538,11 +547,12 @@ def fused_dispatch(hm, hsnap: tuple, mm, msnap: tuple, hints,
         return None
     note_serving()
     q = _fused_hint_q(hsnap[0], hints, pad_to)
-    slots = _fused_slots(mtab, ips, ports, q["hostb"].shape[0])
+    cap = q["hostb"].shape[0]
+    slots = _fused_slots(mtab, ips, ports, cap)
     fn = _fused_fn()
-    note_launch(kind="cpick", fused=True)
     _FUSED_DISP[0] += 1
-    return fn(fd, q, mdev, slots)
+    with launch_span("cpick", cap, fused=True):
+        return fn(fd, q, mdev, slots)
 
 
 def fused_dispatch_all(hm, hsnap: tuple, cm, csnap: tuple, mm,
@@ -570,23 +580,36 @@ def fused_dispatch_all(hm, hsnap: tuple, cm, csnap: tuple, mm,
     q = _fused_hint_q(hsnap[0], hints, pad_to)
     cap = q["hostb"].shape[0]
     slots = _fused_slots(mtab, ips, ports, cap)
-    a16, fam = T.encode_ips(addrs)
-    if cap > a16.shape[0]:
-        k = cap - a16.shape[0]
-        a16 = np.concatenate([a16, np.zeros((k,) + a16.shape[1:],
-                                            a16.dtype)])
-        fam = np.concatenate([fam, np.full(k, -1, fam.dtype)])
+    a16, fam, _p = _encode_addrs(addrs, None, cap, items=0)
     from ..ops import fused as F
-    note_launch(kind="all", fused=True)
     _FUSED_DISP[0] += 1
-    return F.fused_jit(fd, q, mdev, slots, cfd, a16, fam, None)
+    with launch_span("all", cap, fused=True):
+        return F.fused_jit(fd, q, mdev, slots, cfd, a16, fam, None)
 
 
 def _fused_hint_q(tab, hints, pad_to: Optional[int]) -> dict:
-    q = H.encode_hint_queries(hints, tab, pad_to=pad_to or 0)
-    if pad_to and q["hostb"].shape[0] < pad_to:
-        q = _pad_hint_q(q, pad_to, _PAD_CUCKOO)
+    with encode_span(len(hints)):
+        q = H.encode_hint_queries(hints, tab, pad_to=pad_to or 0)
+        if pad_to and q["hostb"].shape[0] < pad_to:
+            q = _pad_hint_q(q, pad_to, _PAD_CUCKOO)
     return q
+
+
+def _encode_addrs(addrs, ports, pad_to: Optional[int],
+                  items: int) -> tuple:
+    """(a16, fam, p) of one cidr batch padded to the bucket: family -1
+    marks pad rows — they match no group and walk no trie."""
+    with encode_span(items):
+        a16, fam = T.encode_ips(addrs)
+        p = None if ports is None else np.asarray(ports, np.int32)
+        if pad_to and pad_to > a16.shape[0]:
+            k = pad_to - a16.shape[0]
+            a16 = np.concatenate([a16, np.zeros((k,) + a16.shape[1:],
+                                                a16.dtype)])
+            fam = np.concatenate([fam, np.full(k, -1, fam.dtype)])
+            if p is not None:
+                p = np.concatenate([p, np.zeros(k, p.dtype)])
+    return a16, fam, p
 
 
 def _fused_slots(mtab, ips, ports, cap: int) -> np.ndarray:
@@ -595,10 +618,11 @@ def _fused_slots(mtab, ips, ports, cap: int) -> np.ndarray:
     other pick plane); pad rows ride slot 0 and are sliced off by the
     caller."""
     from .maglev import flow_slots
-    slots = flow_slots(len(mtab), ips, ports)
-    if cap > len(slots):
-        slots = np.concatenate([slots, np.zeros(cap - len(slots),
-                                                np.int64)])
+    with encode_span(0):    # the batch's queries count at the hint encode
+        slots = flow_slots(len(mtab), ips, ports)
+        if cap > len(slots):
+            slots = np.concatenate([slots, np.zeros(cap - len(slots),
+                                                    np.int64)])
     return slots
 
 
@@ -677,7 +701,6 @@ class HintMatcher:
         return int(sum(getattr(v, "nbytes", 0) for v in dev.values()))
 
     def _recompile(self) -> None:
-        from ..utils import trace
         itid = trace.current_id()  # nonzero only under a traced install
         t_ph = time.monotonic_ns() if itid else 0
         if self.backend == "jax":
@@ -768,8 +791,8 @@ class HintMatcher:
 
     def submit(self, q: dict):
         """Dispatch an encoded batch; returns the device array (async)."""
-        note_launch(kind="hint")
-        idx, _ = H.hint_hash_jit(self._dev, q)
+        with launch_span("hint", q["hostb"].shape[0]):
+            idx, _ = H.hint_hash_jit(self._dev, q)
         return idx
 
     def fused_stat(self) -> dict:
@@ -863,48 +886,57 @@ class HintMatcher:
         tab, dev, rules = snap[0], snap[1], snap[2]
         if not rules or not hints:
             return np.full(len(hints), -1, np.int32)
-        note_launch(kind="hint")  # every branch below is one dispatch
+        # every branch below is one dispatch: one encode span (host
+        # encode + padding) and one launch span (the jitted call)
+        n = len(hints)
         if self.backend == "jax":
             # ONE copy of the encode+pad idiom, shared with the fused
             # entry: small batches encode straight into the padded
             # bucket (the per-hint python path); big ones encode the
             # real rows then array-pad with invalid probes
-            idx, _ = H.hint_hash_jit(dev,
-                                     _fused_hint_q(tab, hints, pad_to))
+            q = _fused_hint_q(tab, hints, pad_to)
+            with launch_span("hint", q["hostb"].shape[0]):
+                idx, _ = H.hint_hash_jit(dev, q)
             return idx
         if self.backend == "jax-fp":
             from ..ops import fphash as F
-            q = F.encode_hint_queries_fp(hints, tab)
-            if pad_to and pad_to > len(hints):
-                q = _pad_hint_q(q, pad_to, {})
+            with encode_span(n):
+                q = F.encode_hint_queries_fp(hints, tab)
+                if pad_to and pad_to > n:
+                    q = _pad_hint_q(q, pad_to, {})
             # resolve the member-mode env knob HERE, per dispatch: jit
             # keys on the static mode arg, so passing None would bake
             # the first dispatch's VPROXY_TPU_FP_MEMBER into the cache
             # and silently ignore later changes (stale lowering)
-            idx, _ = F.hint_fp_jit(dev, q, mode=F.default_member_mode())
+            with launch_span("hint", max(n, pad_to or 0)):
+                idx, _ = F.hint_fp_jit(dev, q,
+                                       mode=F.default_member_mode())
             return idx
         if self.backend in ("jax-sharded", "jax-fp-sharded"):
             from ..parallel import mesh as M
             from ..parallel.mesh import query_shards
-            n = len(hints)
             cap = pad_batch(max(n, pad_to or 0), query_shards(self._mesh))
-            if self.backend == "jax-fp-sharded":
-                from ..ops import fphash as F
-                padded = list(hints) + [Hint()] * (cap - n)
-                q = F.encode_hint_queries_fp_sharded(padded, tab)
-                kernel = F.hint_fp_match
-            else:
-                # single-pass multi-salt encode: one rolling-hash pass
-                # serves every shard (the old path re-encoded per shard
-                # — 8x the host cost of the whole dispatch)
-                q = H.encode_hint_queries_sharded(hints, tab, pad_to=cap)
-                kernel = None
+            with encode_span(n):
+                if self.backend == "jax-fp-sharded":
+                    from ..ops import fphash as F
+                    padded = list(hints) + [Hint()] * (cap - n)
+                    q = F.encode_hint_queries_fp_sharded(padded, tab)
+                    kernel = F.hint_fp_match
+                else:
+                    # single-pass multi-salt encode: one rolling-hash
+                    # pass serves every shard (the old path re-encoded
+                    # per shard — 8x the host cost of the whole
+                    # dispatch)
+                    q = H.encode_hint_queries_sharded(hints, tab,
+                                                      pad_to=cap)
+                    kernel = None
             qd = M.shard_hint_queries_sharded(q, self._mesh)
             if self._fn is None:
                 self._fn = M.make_sharded_hint_fn(
                     self._mesh, {k: v.ndim for k, v in tab.arrays.items()},
                     {k: v.ndim for k, v in q.items()}, kernel=kernel)
-            out = self._fn(dev, qd, np.int32(tab.shard_size))
+            with launch_span("hint", cap):
+                out = self._fn(dev, qd, np.int32(tab.shard_size))
             if not sync:
                 import jax
                 if jax.process_count() <= 1:
@@ -912,12 +944,14 @@ class HintMatcher:
             # to_local: this process's slice on a multi-process mesh,
             # plain np.asarray single-process
             return M.to_local(out)[:n]
-        if pad_to and pad_to > len(hints):
-            hints = list(hints) + [Hint()] * (pad_to - len(hints))
-        q = T.encode_hints(hints)
-        idx, _ = hint_match_jit(
-            dev, q["host"], q["has_host"], unpack_bits(q["uri"]),
-            q["has_uri"], q["port"])
+        with encode_span(n):
+            if pad_to and pad_to > n:
+                hints = list(hints) + [Hint()] * (pad_to - n)
+            q = T.encode_hints(hints)
+        with launch_span("hint", len(hints)):
+            idx, _ = hint_match_jit(
+                dev, q["host"], q["has_host"], unpack_bits(q["uri"]),
+                q["has_uri"], q["port"])
         return idx
 
 
@@ -981,7 +1015,6 @@ class CidrMatcher:
         return int(sum(getattr(v, "nbytes", 0) for v in dev.values()))
 
     def _recompile(self) -> None:
-        from ..utils import trace
         itid = trace.current_id()  # nonzero only under a traced install
         t_ph = time.monotonic_ns() if itid else 0
         hash_arrays = None  # "jax" backend: source for the packed build
@@ -1143,27 +1176,21 @@ class CidrMatcher:
         dev, nets, acl = snap[0], snap[1], snap[2]
         if not nets or not addrs:
             return np.full(len(addrs), -1, np.int32)
-        note_launch(kind="cidr")  # every branch below is one dispatch
-        a16, fam = T.encode_ips(addrs)
         # route tables (acl=None) have zeroed port-range columns: the port
         # gate must be skipped entirely or every port>0 query misses
-        p = None if (ports is None or acl is None) \
-            else np.asarray(ports, np.int32)
-        if pad_to and pad_to > a16.shape[0]:
-            k = pad_to - a16.shape[0]
-            a16 = np.concatenate([a16, np.zeros((k,) + a16.shape[1:],
-                                                a16.dtype)])
-            fam = np.concatenate([fam, np.full(k, -1, fam.dtype)])
-            if p is not None:
-                p = np.concatenate([p, np.zeros(k, p.dtype)])
-        if self.backend == "jax":
-            return H.cidr_hash_jit(dev, a16, fam, p)
-        if self.backend == "jax-fp":
-            from ..ops import fphash as F
-            return F.cidr_fp_jit(dev, a16, fam, p)
+        a16, fam, p = _encode_addrs(
+            addrs, None if acl is None else ports, pad_to,
+            items=len(addrs))
         if self.backend in ("jax-sharded", "jax-fp-sharded"):
             return self._dispatch_sharded(snap, a16, fam, p, sync=sync)
-        return cidr_match_jit(dev, a16, fam, p)
+        # every branch is one dispatch
+        with launch_span("cidr", a16.shape[0]):
+            if self.backend == "jax":
+                return H.cidr_hash_jit(dev, a16, fam, p)
+            if self.backend == "jax-fp":
+                from ..ops import fphash as F
+                return F.cidr_fp_jit(dev, a16, fam, p)
+            return cidr_match_jit(dev, a16, fam, p)
 
     def _dispatch_sharded(self, snap: tuple, a16: np.ndarray,
                           fam: np.ndarray, p: Optional[np.ndarray],
@@ -1191,8 +1218,9 @@ class CidrMatcher:
                 self._mesh, {k: v.ndim for k, v in tab.arrays.items()},
                 with_port, kernel=kernel)
         size = np.int32(tab.shard_size)
-        out = fn(dev, a16d, famd, pd, size) if with_port \
-            else fn(dev, a16d, famd, size)
+        with launch_span("cidr", cap):
+            out = fn(dev, a16d, famd, pd, size) if with_port \
+                else fn(dev, a16d, famd, size)
         if not sync:
             import jax
             if jax.process_count() <= 1:

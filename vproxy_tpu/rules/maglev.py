@@ -358,8 +358,8 @@ class MaglevMatcher:
             return np.full(len(ips), -1, np.int32)
         slots = flow_slots(len(tab), ips, ports)
         from . import engine as E
-        E.note_launch()
-        return _device_take(dev, slots)
+        with E.launch_span("pick", len(slots)):
+            return _device_take(dev, slots)
 
     def match(self, ips: Sequence[bytes],
               ports: Optional[Sequence[int]] = None) -> np.ndarray:

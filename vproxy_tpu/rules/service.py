@@ -134,15 +134,15 @@ def _apply_gil_slice() -> None:
 class _Req:
     __slots__ = ("payload", "cb", "loop", "t0", "tid")
 
-    def __init__(self, payload, cb, loop):
+    def __init__(self, payload, cb, loop, tid=0):
         self.payload = payload
         self.cb = cb
         self.loop = loop
         self.t0 = time.monotonic()
         # the submitter's trace context rides the request so the
         # dispatcher thread can attach its spans (queue wait, dispatch,
-        # d2h sync) to the sampled request that triggered them
-        self.tid = trace.current_id()
+        # d2h sync, deliver) to the sampled request that triggered them
+        self.tid = tid
 
 
 class _Inflight:
@@ -150,9 +150,9 @@ class _Inflight:
     (the dispatcher's double buffer slot)."""
 
     __slots__ = ("kind", "matcher", "reqs", "snap", "arr", "t0",
-                 "lone_big")
+                 "lone_big", "tid")
 
-    def __init__(self, kind, matcher, reqs, snap, arr, t0, lone_big):
+    def __init__(self, kind, matcher, reqs, snap, arr, t0, lone_big, tid):
         self.kind = kind
         self.matcher = matcher
         self.reqs = reqs
@@ -160,6 +160,7 @@ class _Inflight:
         self.arr = arr
         self.t0 = t0
         self.lone_big = lone_big
+        self.tid = tid    # the batch's first sampled request, 0 = none
 
 
 class ClassifyStats:
@@ -298,7 +299,15 @@ class ClassifyService:
 
     def _submit(self, kind: str, matcher, payload, cb, loop) -> None:
         inline = False
+        # tracing on: how long a sampled submit waits for the lock. The
+        # trace id is read INSIDE the lock, where _Req used to read it:
+        # a Python call between a submitter's waking and its taking the
+        # lock cost the 8-submitter cell 4 % of its rate (PERF.md §6)
+        t_lock = time.monotonic_ns() if trace.SAMPLE else 0
         with self._cv:
+            tid = trace.current_id()
+            if t_lock:
+                t_in = time.monotonic_ns()
             if self._closed:
                 raise OSError("ClassifyService is closed")
             self.stats.queries += 1
@@ -307,9 +316,10 @@ class ClassifyService:
             if ent is None and self._inline_host(matcher):
                 inline = True  # answered below, outside the lock
             elif ent is None:
-                self._pending[key] = (kind, matcher, [_Req(payload, cb, loop)])
+                self._pending[key] = (kind, matcher,
+                                      [_Req(payload, cb, loop, tid)])
             else:
-                ent[2].append(_Req(payload, cb, loop))
+                ent[2].append(_Req(payload, cb, loop, tid))
             if not inline:
                 if self._thread is None:
                     self._thread = threading.Thread(
@@ -317,6 +327,9 @@ class ClassifyService:
                         daemon=True)
                     self._thread.start()
                 self._cv.notify()
+        if t_lock and tid:
+            trace.note_span(tid, "engine", "submit_lock_wait", t_lock,
+                            t_in - t_lock, kind=kind)
         if inline:
             self._answer_inline(kind, matcher, payload, cb, loop)
 
@@ -490,9 +503,11 @@ class ClassifyService:
         inflight: Optional[_Inflight] = None
         while True:
             with self._cv:
-                while not self._pending and not self._closed \
+                if not self._pending and not self._closed \
                         and inflight is None:
-                    self._cv.wait()
+                    with trace.span("engine", "wait", tid=0):
+                        while not self._pending and not self._closed:
+                            self._cv.wait()
                 batches = list(self._pending.values())
                 self._pending.clear()
                 closed = self._closed
@@ -596,49 +611,48 @@ class ClassifyService:
             if own:
                 sketch.update("routes", f"upstream:{own}", n,
                               plane="engine")
-        # sampled requests in the batch: batch-shared phases (dispatch,
-        # d2h sync, host_index) attach to the FIRST one — one span, not
-        # one per request; per-request queue wait is recorded for every
-        # sampled request on BOTH serving branches
-        tids = [r.tid for r in reqs if r.tid]
-        if tids:
-            t_q = time.monotonic()
+        # sampled requests in the batch: the batch's phases (dispatch
+        # and the engine's encode + launch under it, d2h sync, deliver,
+        # host_index) are buffered on the FIRST one's trace — one span,
+        # not one per request — and totalled for every batch while
+        # tracing is on; queue wait is recorded for every sampled
+        # request on BOTH serving branches
+        tid = 0
+        if trace.SAMPLE:
+            t_q = time.monotonic_ns()
             for r in reqs:
                 if r.tid:
-                    trace.record_span(
-                        r.tid, "engine", "queue_wait",
-                        int(r.t0 * 1e9), int((t_q - r.t0) * 1e9),
-                        kind=kind)
+                    tid = tid or r.tid
+                    t_sub = int(r.t0 * 1e9)
+                    trace.note_span(r.tid, "engine", "queue_wait", t_sub,
+                                    t_q - t_sub, kind=kind, batch=n)
         if self._use_device(matcher, n):
             try:
                 t0 = time.monotonic()
-                with trace.bind(tids[0] if tids else 0):
-                    # the bind makes engine-level launch markers
-                    # (rules/engine.note_launch: fused vs unfused)
-                    # attach to the sampled request's trace
+                # the bind hands the engine's encode and launch spans
+                # the sampled request's trace
+                with trace.bind(tid), trace.span("engine", "dispatch",
+                                                 tid=tid, kind=kind,
+                                                 batch=n):
                     arr = self._device_submit(kind, matcher, snap, reqs)
-                if tids:
-                    trace.record_span(
-                        tids[0], "engine", "dispatch", int(t0 * 1e9),
-                        int((time.monotonic() - t0) * 1e9), kind=kind,
-                        batch=n)
                 return _Inflight(kind, matcher, reqs, snap, arr, t0,
-                                 lone_big)
+                                 lone_big, tid)
             except MemoryError:
                 raise
             except Exception as e:
                 self._device_failed(e, n)
         t0 = time.monotonic()
         idxs = self._oracle_batch(kind, matcher, snap, reqs)
-        if tids:
-            trace.record_span(tids[0], "engine", "host_index",
+        if tid:
+            trace.record_span(tid, "engine", "host_index",
                               int(t0 * 1e9),
                               int((time.monotonic() - t0) * 1e9),
                               kind=kind, batch=n)
         if lone_big:
             self._note_lone_latency("oracle", time.monotonic() - t0)
         self.stats.bump("oracle_queries", n)
-        self._deliver(reqs, idxs, matcher.snap_payload(snap), kind=kind)
+        self._deliver(reqs, idxs, matcher.snap_payload(snap), kind=kind,
+                      tid=tid)
         return None
 
     def _finish_guarded(self, inf: "_Inflight") -> None:
@@ -667,15 +681,10 @@ class ClassifyService:
         oracle and marks the device down, same as a submit failure."""
         n = len(inf.reqs)
         idxs = None
-        tids = [r.tid for r in inf.reqs if r.tid]
         try:
-            t_sync = time.monotonic()
-            idxs = np.asarray(inf.arr)[:n]
-            if tids:
-                trace.record_span(
-                    tids[0], "engine", "d2h_sync", int(t_sync * 1e9),
-                    int((time.monotonic() - t_sync) * 1e9),
-                    kind=inf.kind, batch=n)
+            with trace.span("engine", "d2h_sync", tid=inf.tid,
+                            kind=inf.kind, batch=n):
+                idxs = np.asarray(inf.arr)[:n]
             if inf.lone_big:
                 self._note_lone_latency("device", time.monotonic() - inf.t0)
             with self.stats.lock:
@@ -695,7 +704,7 @@ class ClassifyService:
         try:
             self._deliver(inf.reqs, idxs,
                           inf.matcher.snap_payload(inf.snap),
-                          kind=inf.kind)
+                          kind=inf.kind, tid=inf.tid)
         except MemoryError:
             raise
         except Exception:
@@ -760,7 +769,7 @@ class ClassifyService:
                 for r in reqs]
 
     def _deliver(self, reqs: list[_Req], idxs, payload=None,
-                 kind: str = "hint") -> None:
+                 kind: str = "hint", tid: int = 0) -> None:
         """cb(idx, payload) — or cb(verdict, pick, payload) for cpick
         batches, where a row is the fused program's (verdict, pick)
         pair (a scalar row is an error fill: both -1). payload is the
@@ -768,34 +777,38 @@ class ClassifyService:
         served the batch (None when the owner didn't register one).
         Callbacks run on the submitting loop; if that loop is gone,
         inline on this thread so cleanup (closing an accepted fd)
-        still happens."""
-        now = time.monotonic()
-        for r, idx in zip(reqs, idxs):
-            self.stats.record_latency(now - r.t0)
-            if kind == "cpick":
-                v, p = (int(idx[0]), int(idx[1])) if np.ndim(idx) \
-                    else (int(idx), int(idx))
+        still happens. tid: the batch's first sampled request."""
+        with trace.span("engine", "deliver", tid=tid, cpu=True,
+                        items=len(reqs), kind=kind):
+            now = time.monotonic()
+            for r, idx in zip(reqs, idxs):
+                self.stats.record_latency(now - r.t0)
+                if kind == "cpick":
+                    v, p = (int(idx[0]), int(idx[1])) if np.ndim(idx) \
+                        else (int(idx), int(idx))
 
-                def run(cb=r.cb, v=v, p=p) -> None:
-                    try:
-                        cb(v, p, payload)
-                    except MemoryError:
-                        raise
-                    except Exception:
-                        _log.error("classify callback failed", exc=True)
-            else:
-                i = int(idx)
+                    def run(cb=r.cb, v=v, p=p) -> None:
+                        try:
+                            cb(v, p, payload)
+                        except MemoryError:
+                            raise
+                        except Exception:
+                            _log.error("classify callback failed",
+                                       exc=True)
+                else:
+                    i = int(idx)
 
-                def run(cb=r.cb, i=i) -> None:
-                    try:
-                        cb(i, payload)
-                    except MemoryError:
-                        raise
-                    except Exception:
-                        _log.error("classify callback failed", exc=True)
+                    def run(cb=r.cb, i=i) -> None:
+                        try:
+                            cb(i, payload)
+                        except MemoryError:
+                            raise
+                        except Exception:
+                            _log.error("classify callback failed",
+                                       exc=True)
 
-            if r.loop is None or not r.loop.run_on_loop(run):
-                run()
+                if r.loop is None or not r.loop.run_on_loop(run):
+                    run()
 
     # ------------------------------------------------------------- control
 

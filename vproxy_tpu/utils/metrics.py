@@ -165,19 +165,8 @@ class Histogram(Metric):
         with self._lock:
             counts = list(self._counts)
             total, s = self._count, self._sum
-        out = []
-        cum = 0
-        for bound, n in zip(self._bounds, counts):
-            cum += n
-            lbl = _fmt_labels({**self.labels, "le": str(bound)})
-            out.append(f"{self.name}_bucket{lbl} {cum}")
-        lbl = _fmt_labels({**self.labels, "le": "+Inf"})
-        out.append(f"{self.name}_bucket{lbl} {total}")
-        base = _fmt_labels(self.labels)
-        s_str = "%d" % s if float(s).is_integer() else repr(float(s))
-        out.append(f"{self.name}_sum{base} {s_str}")
-        out.append(f"{self.name}_count{base} {total}")
-        return out
+        return _histogram_lines(self.name, self.labels, self._bounds,
+                                counts, total, s)
 
     def percentiles(self, qs=(50.0, 99.0, 99.9)) -> Optional[Dict[str, float]]:
         """-> {"n", "p50", "p99", "p999", ...} or None when empty.
@@ -201,6 +190,54 @@ class Histogram(Metric):
             out[_q_key(q)] = _bucket_quantile(self._bounds, counts, total,
                                               q / 100.0)
         return out
+
+
+def _histogram_lines(name: str, labels: Dict[str, str], bounds, counts,
+                     total: int, s: float) -> List[str]:
+    """Prometheus exposition of one histogram series: cumulative
+    `_bucket` lines, `_sum`, `_count`."""
+    out = []
+    cum = 0
+    for bound, n in zip(bounds, counts):
+        cum += n
+        lbl = _fmt_labels({**labels, "le": str(bound)})
+        out.append(f"{name}_bucket{lbl} {cum}")
+    lbl = _fmt_labels({**labels, "le": "+Inf"})
+    out.append(f"{name}_bucket{lbl} {total}")
+    base = _fmt_labels(labels)
+    s_str = "%d" % s if float(s).is_integer() else repr(float(s))
+    out.append(f"{name}_sum{base} {s_str}")
+    out.append(f"{name}_count{base} {total}")
+    return out
+
+
+class TraceSpanHistogram(Metric):
+    """/metrics view of one utils/trace span total (`span_totals()`):
+    the series `vproxy_trace_span_us{plane,span}`, in microseconds, at
+    zero until tracing has been on."""
+    mtype = "histogram"
+
+    def __init__(self, plane: str, span: str):
+        super().__init__("vproxy_trace_span_us",
+                         {"plane": plane, "span": span})
+        self._key = f"{plane}/{span}"
+
+    def _total(self) -> Optional[dict]:
+        from . import trace
+        return trace.span_totals().get(self._key)
+
+    def value(self) -> float:
+        tot = self._total()
+        return tot["n"] if tot else 0
+
+    def sample_lines(self) -> List[str]:
+        from . import trace
+        tot = self._total()
+        n = trace.TOTAL_BUCKETS
+        return _histogram_lines(
+            self.name, self.labels, [1 << k for k in range(n)],
+            tot["buckets"] if tot else [0] * (n + 1),
+            tot["n"] if tot else 0, tot["sum_ns"] / 1000.0 if tot else 0)
 
 
 def _q_key(q: float) -> str:
@@ -380,6 +417,11 @@ class GlobalInspection:
                               self._trace_c_drops, ring="lane")
         self.registry.gauge_f("vproxy_trace_drop_total",
                               self._trace_py_drops, ring="py")
+        # the batch cycle's span totals (trace.SPANS, a closed
+        # vocabulary): every series at zero before tracing is ever on
+        from . import trace as _trace
+        for plane, span in _trace.SPANS:
+            self.registry.add(TraceSpanHistogram(plane, span))
         # traffic-analytics plane (utils/sketch + native HH shards):
         # pre-registered with CLOSED label vocabularies (the PR-13
         # registry rule) — vproxy_hh_count{dim,slot} exposes the top-K

@@ -18,17 +18,44 @@ process-wide bounded buffer:
   (`vproxy_trace_drop_total{ring="lane"}`).
 * **accept**  — the python accept path (components/tcplb.py): acl,
   backend_pick, connect, splice, close, total.
-* **engine**  — classify dispatch (rules/service.py + rules/engine.py):
-  queue_wait, dispatch, launch markers (fused vs unfused
-  distinguishable), d2h_sync, classify_inline / host_index fallbacks.
+* **engine**  — classify dispatch (rules/service.py + rules/engine.py).
+  The dispatcher thread's BATCH CYCLE, one span each per batch: wait
+  (parked in the condition variable with nothing pending and nothing
+  in flight), dispatch (the `_device_submit` call; parent of the next
+  two), encode (host encode + padding of one batch: `items` real
+  queries, `cpu_ns`), launch (the jitted call: enqueue AND the
+  implicit upload of its numpy arguments; `kind`, `fused`, `bucket`),
+  d2h_sync (the blocking `np.asarray` of the result), deliver (the
+  callback loop: `items`, `cpu_ns`). Per sampled request: queue_wait
+  (`batch`), submit_lock_wait (a submitter waiting for the service's
+  lock), classify_inline / host_index fallbacks.
 * **install** — the TableInstaller (rules/engine.py): every standby
   generation install traced as compile / upload / swap spans.
 * **cluster** — the step-synchronized submit loop (cluster/submit.py):
   barrier, collective, barrier_stall, host_index — a degraded query's
   trace shows WHICH phase ate the time on the node that served it.
+* **runtime** — the interpreter: gc_pause (`gen`), from a `gc.callbacks`
+  hook that lives exactly as long as tracing is on.
+
+Two sinks. The bounded trace BUFFER holds spans of sampled requests (a
+batch's spans attach to its first sampled request; a batch without one
+buffers nothing). The span TOTALS (`span_totals()`, exported as
+`vproxy_trace_span_us{plane,span}`) take every span of the `SPANS`
+vocabulary, every batch, while tracing is on: n, sum_ns, sum_cpu_ns,
+sum_items, log2 buckets, first and last timestamp — process-lifetime,
+`reset()` leaves them. `cpu_ns` is `time.thread_time_ns()` at the span's
+two ends: encode and deliver make no blocking call, so wall - CPU there
+is time the thread was runnable and not running (GIL or scheduler).
+While tracing is on `span()` also enters a `jax.profiler.TraceAnnotation`
+named `vproxy/<plane>/<span>` (only in a process that has imported JAX),
+so the spans sit in a profiler trace on its own clock, beside the device.
 
 Sampling: `VPROXY_TPU_TRACE_SAMPLE` = N samples 1-in-N (0 = off, the
-default). Knob-off cost is one branch per site. Two deciders:
+default). Knob-off cost is one branch per site — per batch on the
+dispatcher, never per query. On, a batch costs its six `span()`s (two
+clock reads, a profiler annotation and one locked add each; encode and
+deliver two `thread_time_ns()` more — a syscall of ~6 us on some hosts)
+and a submit two clock reads. Two deciders:
 
 * `maybe_sample()` — deterministic counter-based 1-in-N (the accept
   paths; every Nth request).
@@ -50,8 +77,10 @@ surface, `tools/traceview.py` for offline artifacts, and the
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -89,6 +118,7 @@ def configure(n: int) -> None:
     sampling and python sampling flip together."""
     global SAMPLE
     SAMPLE = int(n)
+    _gc_hook_sync()
     try:
         from ..net import vtl
         if hasattr(vtl, "trace_set_sample"):
@@ -140,7 +170,7 @@ def maybe_sample() -> int:
 class bind:
     """Context manager pushing `tid` as the current trace context for
     this thread (no-op for tid=0): spans recorded by downstream code
-    (engine launch markers, installer phases) attach to the request
+    (engine encode + launch spans, installer phases) attach to the request
     that triggered them."""
 
     __slots__ = ("tid",)
@@ -223,10 +253,190 @@ def py_dropped_total() -> int:
 
 
 def reset() -> None:
-    """Test hook: drop every buffered trace (counters stay — they are
-    process-lifetime totals, like every other /metrics series)."""
+    """Test hook: drop every buffered trace (counters and span totals
+    stay — they are process-lifetime totals, like every other /metrics
+    series)."""
     with _lock:
         _traces.clear()
+
+
+# ------------------------------------------------- span totals + span()
+
+# the closed vocabulary of totalled spans (utils/metrics pre-registers
+# vproxy_trace_span_us{plane,span} for each pair, at zero)
+SPANS = (("engine", "wait"), ("engine", "dispatch"), ("engine", "encode"),
+         ("engine", "launch"), ("engine", "d2h_sync"), ("engine", "deliver"),
+         ("engine", "queue_wait"), ("engine", "submit_lock_wait"),
+         ("runtime", "gc_pause"))
+# bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
+TOTAL_BUCKETS = 27
+
+
+class _Total:
+    __slots__ = ("n", "sum_ns", "sum_cpu_ns", "sum_items", "buckets",
+                 "first_ns", "last_ns")
+
+    def __init__(self):
+        self.n = self.sum_ns = self.sum_cpu_ns = self.sum_items = 0
+        self.buckets = [0] * (TOTAL_BUCKETS + 1)
+        self.first_ns = self.last_ns = 0
+
+    def add(self, t_start_ns: int, dur_ns: int, cpu_ns: int,
+            items: int) -> None:
+        us = -(-dur_ns // 1000)     # Histogram._bucket_of, from integer ns
+        self.buckets[0 if us <= 1 else
+                     min((us - 1).bit_length(), TOTAL_BUCKETS)] += 1
+        self.n += 1
+        self.sum_ns += dur_ns
+        self.sum_cpu_ns += cpu_ns
+        self.sum_items += items
+        if not self.first_ns:
+            self.first_ns = t_start_ns
+        self.last_ns = t_start_ns + dur_ns
+
+    def as_dict(self) -> dict:
+        return {k: (list(self.buckets) if k == "buckets"
+                    else getattr(self, k)) for k in self.__slots__}
+
+
+_tot_lock = threading.Lock()
+_totals: "dict[str, _Total]" = {}
+
+
+def note_span(trace_id: int, plane: str, span: str, t_start_ns: int,
+              dur_ns: int, cpu_ns: int = 0, items: int = 0,
+              **fields) -> None:
+    """One finished span of the SPANS vocabulary into both sinks: the
+    totals always, the trace buffer when `trace_id` is nonzero. Nothing
+    once tracing is off (a span that outlives `configure(0)` is lost)."""
+    if SAMPLE <= 0:
+        return
+    key = plane + "/" + span
+    with _tot_lock:
+        tot = _totals.get(key)
+        if tot is None:
+            tot = _totals[key] = _Total()
+        tot.add(t_start_ns, dur_ns, cpu_ns, items)
+    if trace_id:
+        if cpu_ns:
+            fields["cpu_ns"] = cpu_ns
+        if items:
+            fields["items"] = items
+        record_span(trace_id, plane, span, t_start_ns, dur_ns, **fields)
+
+
+def span_totals() -> dict:
+    """{"plane/span": {n, sum_ns, sum_cpu_ns, sum_items, buckets,
+    first_ns, last_ns}} of every span totalled since the process began
+    (empty while tracing was never on)."""
+    with _tot_lock:
+        out = {k: t.as_dict() for k, t in _totals.items()}
+    if _gc_total.n:     # its one writer takes no lock, see _gc_hook
+        out["runtime/gc_pause"] = _gc_total.as_dict()
+    return out
+
+
+_ann_cls = None     # jax.profiler.TraceAnnotation, once this process has JAX
+
+
+def _annotation(name: str, fields: dict):
+    """An entered profiler annotation carrying `fields`, or None in a
+    process that has not imported JAX (the accept planes: no profiler
+    can be running there). Looked up, never imported: the gc hook can
+    fire in the middle of `import jax`."""
+    global _ann_cls
+    if _ann_cls is None:
+        _ann_cls = getattr(sys.modules.get("jax.profiler"),
+                           "TraceAnnotation", None)
+        if _ann_cls is None:
+            return None
+    ann = _ann_cls(name, **fields)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    __slots__ = ("plane", "name", "tid", "cpu", "items", "fields", "t0",
+                 "cpu0", "ann")
+
+    def __init__(self, plane, name, tid, cpu, items, fields):
+        self.plane, self.name, self.tid = plane, name, tid
+        self.cpu, self.items, self.fields = cpu, items, fields
+
+    def __enter__(self):
+        self.ann = _annotation("vproxy/" + self.plane + "/" + self.name,
+                               self.fields)
+        self.t0 = time.monotonic_ns()
+        if self.cpu:
+            self.cpu0 = time.thread_time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        cpu_ns = time.thread_time_ns() - self.cpu0 if self.cpu else 0
+        dur_ns = time.monotonic_ns() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        note_span(self.tid, self.plane, self.name, self.t0, dur_ns,
+                  cpu_ns, self.items, **self.fields)
+        return False
+
+
+class _NoSpan:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(plane: str, name: str, tid: Optional[int] = None,
+         cpu: bool = False, items: int = 0, **fields):
+    """Context manager around one phase of a batch (SPANS vocabulary):
+    on exit the span goes to the totals, and to the trace buffer under
+    `tid` (default: the thread's bound trace context) when that is
+    nonzero. cpu: also take the thread's CPU time. With tracing off
+    this is one branch and a shared no-op."""
+    if SAMPLE <= 0:
+        return _NO_SPAN
+    return _Span(plane, name, current_id() if tid is None else tid, cpu,
+                 items, fields)
+
+
+# gc_pause: collections stop every thread, so the hook's start/stop pair
+# has one writer at a time and adds to its own total WITHOUT _tot_lock —
+# a collection can begin on a thread that holds it.
+_gc_total = _Total()
+_gc_open = [0, None]    # start ns, profiler annotation
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc_open[1] = _annotation("vproxy/runtime/gc_pause",
+                                  {"gen": info["generation"]})
+        _gc_open[0] = time.monotonic_ns()
+    elif _gc_open[0]:
+        t0, ann = _gc_open
+        _gc_open[0], _gc_open[1] = 0, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _gc_total.add(t0, time.monotonic_ns() - t0, 0, 0)
+
+
+def _gc_hook_sync() -> None:
+    """The hook is installed exactly while tracing is on."""
+    if SAMPLE > 0 and _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+    elif SAMPLE <= 0 and _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+        _gc_open[0] = 0
+
+
+_gc_hook_sync()     # a nonzero VPROXY_TPU_TRACE_SAMPLE at import
 
 
 # ------------------------------------------------------------- queries
