@@ -161,6 +161,52 @@ def test_swap_metrics_and_table_bytes_surface():
     assert snap["vproxy_engine_generation"] >= m.generation
 
 
+@pytest.mark.parametrize("ranges,width,hops,share", [(3, 4, 1, 0.0),
+                                                     (40, 16, 3, 0.5)])
+def test_cidr_bucket_gauges_surface(ranges, width, hops, share):
+    """The bucket layout of an installed cidr table reads where
+    table-bytes does: per table in `list-detail security-group`, over
+    all live tables on /metrics. A one-hop table, and one network under
+    40 port ranges (three rows a bucket: two hops past the first)."""
+    from vproxy_tpu.control.app import Application
+    from vproxy_tpu.control.command import Command
+    from vproxy_tpu.utils.metrics import GlobalInspection
+    app = Application.create(workers=1)
+    try:
+        Command.execute(app, "add security-group g default deny")
+        for i in range(ranges):     # one network, `ranges` port ranges
+            Command.execute(
+                app, f"add security-group-rule f{i} to security-group g "
+                f"network 10.1.0.0/16 protocol tcp "
+                f"port-range {100 * i},{100 * i + 50} default allow")
+        Command.execute(
+            app, "add security-group-rule o to security-group g "
+            "network 10.2.0.0/24 protocol tcp port-range 1,9 default allow")
+        g = app.security_groups["g"]
+        m = g._tables[next(iter(g._tables))][0]
+        st = m.bucket_stat()
+        # each network sits in 3 groups' tables (v4, ::v4, ::ffff:v4)
+        assert st == {"width": width, "hops": hops, "used_slots": 6,
+                      "overflow_slots": 3 if hops > 1 else 0}
+        line, = Command.execute(app, "list-detail security-group")
+        assert (f"tcp backend {m.backend} rules {ranges + 1} "
+                f"table-bytes {m.published_table_bytes()} "
+                f"bucket-width {width} hops {hops} "
+                f"overflow-share {share:.4f}") in line
+        total = engine.cidr_bucket_stat()   # every live table's
+        assert total["width"] >= width and total["hops"] >= hops
+        if hops > 1:
+            assert total["overflow_share"] > 0
+        text = GlobalInspection.get().prometheus_string()
+        for name in ("vproxy_engine_cidr_bucket_width",
+                     "vproxy_engine_cidr_lookup_hops",
+                     "vproxy_engine_cidr_overflow_share"):
+            assert f"\n{name} " in text
+        assert 'vproxy_engine_table_bytes{matcher="cidr"}' in text
+    finally:
+        app.close()
+
+
 def test_default_mesh_cache_keyed_on_devices_and_batch(monkeypatch):
     """The old module-global _MESH was never invalidated — a batch-knob
     (or device-set) change after first use served a stale mesh."""

@@ -127,7 +127,7 @@ def test_hint_hash_long_host_boundaries():
     check_hints(rules, hints)
 
 
-def test_cidr_hash_route_parity():
+def _route_nets():
     rt = RouteTable()
     for i in range(200):
         ml = rnd.choice([0, 8, 12, 16, 24, 32])
@@ -139,7 +139,11 @@ def test_cidr_hash_route_parity():
             rt.add(RouteRule(f"r{i}", net))
         except ValueError:
             continue
-    nets = [r.rule for r in rt.rules]
+    return [r.rule for r in rt.rules]
+
+
+def test_cidr_hash_route_parity():
+    nets = _route_nets()
     tab = H.compile_cidr_hash(nets)
     addrs = [bytes([10 + rnd.randint(0, 6), rnd.randint(0, 255),
                     rnd.randint(0, 255), rnd.randint(0, 255)])
@@ -192,6 +196,212 @@ def test_cidr_hash_mixed_families():
         assert got[i] == want, (i, int(got[i]), want)
 
 
+# ------------------------------------------- the slot row carries its bucket
+
+FAT = Network(parse_ip("10.1.0.0"), mask_bytes(16))
+
+
+def _ranged_acl(ranges: int, others: int = 24):
+    """`ranges` disjoint port ranges [100 i, 100 i + 50] on one network
+    (one bucket), behind `others` single-range /24s."""
+    acl = [AclRule(f"o{i}", Network(bytes([10, 2, i, 0]), mask_bytes(24)),
+                   Proto.TCP, 0, 65535, i % 2 == 0) for i in range(others)]
+    acl += [AclRule(f"f{i}", FAT, Proto.TCP, 100 * i, 100 * i + 50, True)
+            for i in range(ranges)]
+    return acl
+
+
+def _check_against_oracle(m: CidrMatcher, addrs, ports):
+    snap = m.snapshot()
+    got = m.match(addrs, ports)
+    for i, a in enumerate(addrs):
+        want = m.oracle_snap(snap, a, None if ports is None else ports[i])
+        assert got[i] == want, (i, a.hex(), ports and ports[i],
+                                int(got[i]), want)
+    return got
+
+
+@pytest.mark.parametrize("ranges,width,hops", [(1, 1, 1), (3, 4, 1),
+                                               (16, 16, 1), (17, 16, 2),
+                                               (40, 16, 3)])
+def test_cidr_bucket_row_layout_and_hops(ranges, width, hops):
+    """A bucket rides in its slot's row up to BUCKET_INLINE entries and
+    continues in overflow rows past that: the verdicts stay the ordered
+    scan's whatever the hop count — the matching range first, last, in
+    each overflow row, and a port every range misses."""
+    acl = _ranged_acl(ranges)
+    m = CidrMatcher([r.network for r in acl], backend="jax", acl=acl)
+    st = m.bucket_stat()
+    assert (st["width"], st["hops"]) == (width, hops)
+    assert (st["overflow_slots"] > 0) == (hops > 1)
+    a = parse_ip("10.1.7.7")
+    ports = [100 * i + 25 for i in range(ranges)]       # inside range i
+    ports += [100 * i + 75 for i in range(ranges)]      # between ranges
+    ports += [100 * ranges + 75, 65535]                 # past the last
+    addrs = [a] * len(ports) + [parse_ip("10.2.3.9"), parse_ip("10.9.9.9")]
+    ports += [80, 80]
+    got = _check_against_oracle(m, addrs, ports)
+    assert got[ranges - 1] == len(acl) - 1      # the last range answers
+    assert (got[ranges: 2 * ranges + 2] == -1).all()    # every range misses
+
+
+def _nets_acl(acl):
+    return [r.network for r in acl], acl
+
+
+def test_cidr_fat_bucket_memory_follows_entries():
+    """Overflow rows cost what the long bucket holds, not slots x the
+    fattest bucket: 40 and 1,000 ranges on one network add rows of the
+    inline width only (and the `b_next` column a multi-hop table has)."""
+    base = H.compile_cidr_hash(*_nets_acl(_ranged_acl(16)))
+    ct = base.caps["ct"]
+    assert base.arrays["b_rows"].shape == (ct, 3 * 16)
+    assert "b_next" not in base.arrays
+
+    def nbytes(tab):
+        return sum(v.nbytes for v in tab.arrays.values())
+
+    for ranges in (40, 1000):
+        tab = H.compile_cidr_hash(*_nets_acl(_ranged_acl(ranges)))
+        rows = 3 * (-(-ranges // 16) - 1)   # the key sits in 3 groups
+        ov = H._pow2(rows, 8)
+        assert tab.caps == dict(base.caps, bk=-(-ranges // 16) * 16, ov=ov,
+                                r_cap=tab.r_cap)
+        assert tab.arrays["b_rows"].shape == (ct + ov, 3 * 16)
+        # ov rows of 16 entries x 12 B, b_next, the wider shape marker,
+        # `allow` of the rules themselves
+        assert nbytes(tab) - nbytes(base) == \
+            ov * 192 + 4 * (ct + ov) + 16 * (-(-ranges // 16) - 1) + \
+            tab.r_cap - base.r_cap
+
+
+def test_cidr_route_table_reads_one_entry_rows():
+    nets = _route_nets()
+    m = CidrMatcher(nets, backend="jax")
+    assert m._caps["bk"] == 1
+    assert m.bucket_stat() == {"width": 1, "hops": 1, "overflow_slots": 0,
+                               "used_slots": m.bucket_stat()["used_slots"]}
+    assert m.snapshot()[0]["b_rows"].shape[1] == 1
+    addrs = [bytes([10 + rnd.randint(0, 6), rnd.randint(0, 255),
+                    rnd.randint(0, 255), rnd.randint(0, 255)])
+             for _ in range(400)]
+    got = _check_against_oracle(m, addrs, None)
+    assert len(set(got.tolist())) > 5   # a /0 route leaves no miss
+
+
+def test_cidr_bucket_growth_retraces_and_same_caps_does_not():
+    """A same-caps update serves from the jitted program it had; a
+    bucket that outgrows the reused width grows the caps and is served
+    right (one retrace), as any other cap growth."""
+    def serve(ranges):
+        acl = _ranged_acl(ranges)
+        m.set_networks([r.network for r in acl], acl=acl)
+        a = [parse_ip("10.1.0.9")] * 3
+        _check_against_oracle(m, a, [100 * (ranges - 1), 75, 65535])
+        return dict(m._caps), H.cidr_hash_jit._cache_size()
+
+    acl = _ranged_acl(3)
+    m = CidrMatcher([r.network for r in acl], backend="jax", acl=acl)
+    caps3, n3 = serve(3)
+    caps4, n4 = serve(4)        # 3 -> 4 ranges: inside the width of 4
+    assert caps4 == caps3 and n4 == n3
+    caps5, n5 = serve(5)        # outgrows it: width 8, a new program
+    assert caps5["bk"] == 8 and n5 == n3 + 1
+    caps40, _ = serve(40)       # past the inline width: overflow rows
+    assert (caps40["bk"], caps40["ov"]) == (48, 8)
+    assert m.bucket_stat()["hops"] == 3
+    assert serve(36)[0] == caps40       # caps are kept, not shrunk
+
+
+def test_cidr_sharded_bucket_growth_raises_caps_exceeded():
+    acl = _ranged_acl(3)
+    stab = H.compile_cidr_hash_sharded([r.network for r in acl], 2, acl=acl)
+    caps = dict(stab.shards[0].caps)
+    acl = _ranged_acl(4)
+    same = H.compile_cidr_hash_sharded([r.network for r in acl], 2, acl=acl,
+                                       caps=caps)
+    assert same.shards[0].caps == caps
+    for ranges in (5, 40):      # a wider row; an overflow row
+        acl = _ranged_acl(ranges)
+        with pytest.raises(H.CapsExceeded):
+            H.compile_cidr_hash_sharded([r.network for r in acl], 2,
+                                        acl=acl, caps=caps)
+
+
+@pytest.mark.parametrize("ranges", [3, 400])
+def test_cidr_sharded_engine_rebuilds_past_reused_caps(ranges):
+    # each shard holds a slice of the rule list, so of the long bucket
+    acl = _ranged_acl(2)
+    m = CidrMatcher([r.network for r in acl], backend="jax-sharded", acl=acl)
+    caps2 = dict(m._caps)
+    acl = _ranged_acl(ranges)
+    m.set_networks([r.network for r in acl], acl=acl)
+    st, bk = m.bucket_stat(), m._caps["bk"]
+    assert bk > caps2["bk"]
+    assert (st["width"], st["hops"]) == (min(bk, 16), -(-bk // 16))
+    assert (st["hops"] > 1) == (ranges == 400) == (st["overflow_slots"] > 0)
+    a = [parse_ip("10.1.0.9")] * 3 + [parse_ip("10.2.5.5")]
+    _check_against_oracle(m, a, [100 * (ranges - 1) + 50, 75, 0, 9])
+
+
+@pytest.mark.parametrize("kind", ["route", "acl", "acl-fat"])
+def test_fused_route_column_is_cidr_hash_match(kind):
+    """One resolve, two callers: the fused program's route column and
+    cidr_hash_match read the same slot rows of the same device arrays
+    and give the oracle's verdicts."""
+    from vproxy_tpu.ops import fused as F
+    from vproxy_tpu.rules.maglev import MaglevMatcher, flow_slots
+    if kind == "route":
+        cm = CidrMatcher(_route_nets(), backend="jax")
+        port = None
+    else:
+        acl = _ranged_acl(40 if kind == "acl-fat" else 5)
+        cm = CidrMatcher([r.network for r in acl], backend="jax", acl=acl)
+        port = np.asarray([100 * (i % 45) + 50 * (i % 2) for i in range(64)],
+                          np.int32)
+    snap = cm.snapshot()
+    assert snap[6] is snap[0]   # no packed copy: the table's own arrays
+    addrs = [bytes([10, 1 + i % 2, rnd.randint(0, 255), 1])
+             for i in range(64)]
+    a16, fam = T.encode_ips(addrs)
+    hm = HintMatcher([HintRule(host=f"s{i}.example.com") for i in range(8)],
+                     backend="jax")
+    hsnap = hm.snapshot()
+    q = H.encode_hint_queries([Hint(host="s1.example.com")] * 64, hsnap[0],
+                              pad_to=64)
+    mm = MaglevMatcher([(f"b{i}", 1) for i in range(5)], m=251)
+    msnap = mm.snapshot()
+    slots = flow_slots(len(msnap[0]), addrs, None)
+    fused = np.asarray(F.fused_classify_pick(
+        hsnap[5], q, msnap[1], slots, snap[6], a16, fam, port))[:, 2]
+    alone = np.asarray(H.cidr_hash_match(snap[0], a16, fam, port))
+    np.testing.assert_array_equal(fused, alone)
+    want = [cm.oracle_snap(snap, a, None if port is None else int(port[i]))
+            for i, a in enumerate(addrs)]
+    np.testing.assert_array_equal(alone, want)
+
+
+@pytest.mark.parametrize("ranges", [5, 40])
+def test_cidr_program_gathers_one_row_a_slot(ranges):
+    """The program gathers by (query, group) only — two key rows, two
+    used flags, one bucket row a hop and the hop's `b_next` — and never
+    over a candidate list [B, 2 G bk]; the per-rule arrays the old walk
+    read (r_valid, min_port, max_port, cb_items) are not in the table."""
+    import jax
+    acl = _ranged_acl(ranges)
+    tab = H.compile_cidr_hash([r.network for r in acl], acl=acl)
+    assert not {"r_valid", "min_port", "max_port", "cb_items", "s_bs",
+                "s_bc", "bk_iota"} & set(tab.arrays)
+    hops, b, g = -(-ranges // 16), 32, tab.caps["g_cap"]
+    a16, fam = T.encode_ips([parse_ip("10.1.0.9")] * b)
+    jaxpr = jax.make_jaxpr(H.cidr_hash_match)(
+        tab.arrays, a16, fam, np.zeros(b, np.int32))
+    gathers = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "gather"]
+    assert len(gathers) == 4 + hops + (hops - 1)
+    for e in gathers:
+        assert e.invars[1].aval.shape == (b, g, 1), e
+
+
 def test_engine_hash_backend_update_and_growth():
     m = HintMatcher([HintRule(host="a.com")], backend="jax")
     assert m.match([Hint(host="a.com")])[0] == 0
@@ -239,8 +449,11 @@ def _scope_case(kernel):
     q = H.encode_hint_queries(
         [Hint(host=f"s{i}.example.com", uri="/a3/x") for i in range(16)],
         hsnap[0], pad_to=16)
-    cm = CidrMatcher([Network(bytes([10, i, 0, 0]), mask_bytes(16))
-                      for i in range(32)], backend="jax")
+    # an ACL table: a route table's rows hold no port range, so its
+    # program has no cidr_gate stage
+    acl = [AclRule(f"a{i}", Network(bytes([10, i, 0, 0]), mask_bytes(16)),
+                   Proto.TCP, 0, 1000 * i, True) for i in range(32)]
+    cm = CidrMatcher([r.network for r in acl], backend="jax", acl=acl)
     a16, fam = T.encode_ips([bytes([10, i, 1, 1]) for i in range(16)])
     port = np.arange(16, dtype=np.int32)
     hint_stages = ("hint_probe", "hint_candidates", "hint_score",

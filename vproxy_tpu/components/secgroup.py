@@ -106,6 +106,24 @@ class SecurityGroup:
                         acl=sub, payload=sub)
         self._tables[proto] = (m, sub)  # atomic publish
 
+    def table_stats(self) -> dict:
+        """proto name -> the installed table's operator line
+        (`list-detail security-group`): backend, rules, device bytes
+        and, where the backend has one, the hash table's bucket layout
+        (CidrMatcher.bucket_stat)."""
+        out = {}
+        for proto, (m, sub) in sorted(self._tables.items(),
+                                      key=lambda kv: kv[0].value):
+            line = (f"backend {m.backend} rules {len(sub)} "
+                    f"table-bytes {m.published_table_bytes()}")
+            b = m.bucket_stat()
+            if b:
+                share = b["overflow_slots"] / max(1, b["used_slots"])
+                line += (f" bucket-width {b['width']} hops {b['hops']} "
+                         f"overflow-share {share:.4f}")
+            out[proto.value] = line
+        return out
+
     def trivial_allow(self, proto: Proto) -> bool:
         """True when allow() can only ever answer True for `proto` (no
         rules for it + default allow) — the accept lanes serve in C only

@@ -587,6 +587,7 @@ def _h_secg(app: Application, c: Command):
         if c.action == "list":
             return list(app.security_groups.keys())
         return [f"{g.alias} -> default {'allow' if g.default_allow else 'deny'}"
+                + "".join(f" {p} {t}" for p, t in g.table_stats().items())
                 for g in app.security_groups.values()]
     if c.action == "update":
         g = _need(app.security_groups, c.alias, "security-group")

@@ -11,8 +11,8 @@ belongs inside the same sweep.
 
 Two layers live here:
 
-* **Packing** (`pack_hint_table` / `pack_cidr_table`): the compiled
-  hash tables (ops/hashmatch) re-packed into int8/int32 layouts chosen
+* **Packing** (`pack_hint_table`): the compiled hint
+  hash table (ops/hashmatch) re-packed into int8/int32 layouts chosen
   for a single linear sweep. The per-rule record — active flag, port,
   host/uri kind+len, uri score — becomes ONE int32 row (`pk_meta`,
   [r_cap, 8]) and the host+uri compare bytes ONE uint8 row
@@ -20,7 +20,10 @@ Two layers live here:
   gathers instead of the nine separate-array gathers the unfused
   kernel pays. The cuckoo slot side packs the same way: (used/klen,
   bucket_start, bucket_count) co-locate in one int32 row per slot
-  (`pk_hslot`/`pk_uslot`/`pk_cslot`), halving the probe gathers.
+  (`pk_hslot`/`pk_uslot`), halving the probe gathers. The cidr table
+  needs no packed copy: its slot rows carry their buckets already
+  (hashmatch `b_rows`), so the fused program runs `cidr_hash_match`
+  on the matcher's own device arrays.
   Packing is pure vectorized numpy and runs INSIDE the matcher's
   standby compile (rules/engine.py), so packed generations publish
   through the same double-buffered TableInstaller swap as everything
@@ -53,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import cuckoo as CK
-from .hashmatch import DOT, HOST_SHIFT, _fnv32_device
+from .hashmatch import DOT, HOST_SHIFT, cidr_hash_match
 
 def kernel_mode() -> str:
     """VPROXY_TPU_FUSED_KERNEL: "jit" (default — the fused XLA program,
@@ -126,31 +129,6 @@ def pack_hint_table(a: dict) -> dict:
         })
     CK.coop_yield()
     return out
-
-
-def pack_cidr_table(a: dict) -> dict:
-    """HashCidrTable.arrays -> packed arrays. pk_cslot (int32 [CT, 4]):
-    0 used, 1 bucket_start, 2 bucket_count, 3 reserved; pk_cmeta
-    (int32 [r_cap, 4]): 0 valid, 1 min_port, 2 max_port, 3 reserved.
-    The small per-group arrays (g_*) stay as-is — they are read once
-    per batch, not per candidate."""
-    cs = np.zeros((a["s_used"].shape[0], 4), np.int32)
-    cs[:, 0] = a["s_used"]
-    cs[:, 1] = a["s_bs"]
-    cs[:, 2] = a["s_bc"]
-    CK.coop_yield()
-    cm = np.zeros((a["r_valid"].shape[0], 4), np.int32)
-    cm[:, 0] = a["r_valid"]
-    cm[:, 1] = a["min_port"]
-    cm[:, 2] = a["max_port"]
-    CK.coop_yield()
-    return {
-        "pk_cslot": cs, "pk_cmeta": cm, "s_key": a["s_key"],
-        "cb_items": a["cb_items"], "g_fam": a["g_fam"],
-        "g_mask": a["g_mask"], "g_off": a["g_off"],
-        "g_capmask": a["g_capmask"], "g_salt1": a["g_salt1"],
-        "g_salt2": a["g_salt2"], "bk_iota": a["bk_iota"],
-    }
 
 
 # ------------------------------------------------------- fused kernel
@@ -267,50 +245,11 @@ def _hint_verdict_packed(t: dict, q: dict):
         return _reduce_best(level, c, r_cap)
 
 
-def _cidr_first_packed(t: dict, addr16, fam, port):
-    """cidr_hash_match over the packed layout: slot resolve one
-    [B, G, 4] gather + the key row; rule gate one pk_cmeta row."""
-    r_cap = t["pk_cmeta"].shape[0]
-    b = addr16.shape[0]
-    with jax.named_scope("cidr_mask"):
-        masked = addr16[:, None, :] & t["g_mask"][None]  # [B, G, 16]
-        gok = (t["g_fam"][None] >= 0) & (fam[:, None] == t["g_fam"][None])
-
-    cands = []
-    for salt in (t["g_salt1"], t["g_salt2"]):
-        with jax.named_scope("cidr_hash"):
-            h = _fnv32_device(masked, salt)
-            slot = t["g_off"][None] + (
-                h.astype(jnp.int32) & t["g_capmask"][None])
-        with jax.named_scope("cidr_probe"):
-            srec = t["pk_cslot"][slot]  # [B, G, 4]
-            key = t["s_key"][slot]      # [B, G, 16]
-            ok = gok & (srec[..., 0] > 0) & jnp.all(key == masked, axis=-1)
-            start, cnt = srec[..., 1], srec[..., 2]
-            j = t["bk_iota"][None, None, :]
-            cands.append(jnp.where(ok[:, :, None] & (j < cnt[:, :, None]),
-                                   start[:, :, None] + j, -1))
-    with jax.named_scope("cidr_candidates"):
-        slot_cand = jnp.concatenate(cands, axis=1).reshape(b, -1)
-        cand = jnp.where(slot_cand >= 0,
-                         t["cb_items"][jnp.maximum(slot_cand, 0)], -1)
-        c = jnp.maximum(cand, 0)
-    with jax.named_scope("cidr_gate"):
-        meta = t["pk_cmeta"][c]  # [B, NC, 4]
-        valid = (cand >= 0) & (meta[..., 0] > 0)
-        if port is not None:
-            valid = valid & (meta[..., 1] <= port[:, None]) & \
-                (port[:, None] <= meta[..., 2])
-    with jax.named_scope("cidr_reduce"):
-        first = jnp.min(jnp.where(valid, c, r_cap), axis=1).astype(jnp.int32)
-        return jnp.where(first < r_cap, first, -1)
-
-
 def fused_classify_pick(ht: dict, q: dict, mtab, slots,
                         ct: Optional[dict] = None, a16=None, fam=None,
                         port=None):
     """THE fused program: hint verdict + Maglev pick (+ optional
-    cidr/LPM route when a packed cidr table and addr batch ride along)
+    cidr/LPM route when a cidr table and addr batch ride along)
     in one compiled launch. -> int32 [B, 2] (verdict, pick) or
     [B, 3] (verdict, pick, route). `slots` are host-side FNV Maglev
     slots (the shared hash contract of rules/maglev.py) so the pick
@@ -320,7 +259,7 @@ def fused_classify_pick(ht: dict, q: dict, mtab, slots,
         p = jnp.take(mtab, slots, mode="clip").astype(jnp.int32)
     cols = [v, p]
     if ct is not None:
-        cols.append(_cidr_first_packed(ct, a16, fam, port))
+        cols.append(cidr_hash_match(ct, a16, fam, port))
     return jnp.stack(cols, axis=1)
 
 

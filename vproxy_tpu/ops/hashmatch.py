@@ -26,6 +26,18 @@ of table size. Semantics stay bit-for-bit the reference's:
   masked address bytes. Any rule matching a query is discoverable via
   its group's probe, so min-rule-index over all probe hits equals the
   ordered linear scan exactly (incl. ACL port-range buckets).
+  A slot carries its bucket: row s of `b_rows` holds the rules whose
+  key sits in slot s, ascending — K rule indices and, for an ACL, their
+  K range starts and K range ends — so after the key verify one row
+  gather a (query, group) is the whole candidate walk, and the port
+  gate and the least-index reduce run on that row. K is the table's
+  fattest bucket rounded to a power of two (1 for a route table, whose
+  keys are distinct), at most BUCKET_INLINE; a longer bucket continues
+  in overflow rows appended to `b_rows` and named by `b_next`, and a
+  lookup makes ceil(fattest bucket / K) hops — a static number read
+  from the table's shape (`b_shape`), 1 unless some network carries
+  more than BUCKET_INLINE port ranges. Memory is slots x K + the long
+  buckets' own entries, never slots x the fattest bucket.
 
 Query-side hashing is host-side numpy (rolling FNV-64: one pass gives
 every dot-suffix / uri-prefix hash); the LPM kernel hashes masked
@@ -618,16 +630,69 @@ def _expand_patterns(net) -> list:
     return out
 
 
+# entries of a bucket that ride in the cuckoo slot's own row of `b_rows`
+# (module docstring); 16 holds the fattest bucket of the north-star ACL
+BUCKET_INLINE = 16
+NO_RULE = np.iinfo(np.int32).max  # a pad entry's index: it never wins
+
+
+def _bucket_cap(n: int) -> int:
+    """Bucket-width cap for a fattest bucket of n rules: a power of two
+    while it fits one row, whole rows past that (so the hop count is
+    ceil(n / BUCKET_INLINE), not its next power of two)."""
+    if n <= BUCKET_INLINE:
+        return _pow2(n, 1)
+    return -(-n // BUCKET_INLINE) * BUCKET_INLINE
+
+
 @dataclass
 class HashCidrTable:
     n: int
     r_cap: int
     arrays: dict
     caps: dict = field(default_factory=dict)
+    # what the install gauges publish (engine.cidr_bucket_stat): row
+    # width, hops a lookup makes, used slots, and how many of them own
+    # an overflow row
+    buckets: dict = field(default_factory=dict)
 
 
 def _fnv32_bytes(key: bytes, salt: int) -> int:
     return int(CK.fnv32_masked(np.frombuffer(key, np.uint8), salt))
+
+
+def _bucket_rows(flat: np.ndarray, start: np.ndarray, count: np.ndarray,
+                 k: int, ov: int, ports) -> tuple:
+    """Slot buckets -> (b_rows [ct + ov, w * k] i32, b_next [ct + ov]
+    i32). Row layout is planar: k rule indices, then
+    (ACL tables, ports = (min_port, max_port) per rule) k range starts
+    and k range ends. Pad entries hold NO_RULE and the empty range
+    [1, 0]. `b_next` names the row a bucket continues in; a bucket's
+    last row names itself, so a hop past the end reads that row again
+    and the least index over the hops does not change."""
+    ct = start.shape[0]
+    idx = np.full((ct + ov, k), NO_RULE, np.int32)
+    nxt = np.arange(ct + ov, dtype=np.int32)
+    j = np.arange(k)
+    inline = j[None, :] < count[:, None]
+    idx[:ct][inline] = flat[(start[:, None] + j[None, :])[inline]]
+    CK.coop_yield()
+    r = ct
+    for s in np.nonzero(count > k)[0]:
+        prev = s
+        for lo in range(k, int(count[s]), k):
+            seg = flat[start[s] + lo: start[s] + min(lo + k, int(count[s]))]
+            idx[r, : len(seg)] = seg
+            nxt[prev] = prev = r
+            r += 1
+    if ports is None:
+        return idx, nxt
+    real = idx != NO_RULE
+    at = np.where(real, idx, 0)
+    CK.coop_yield()
+    rows = np.concatenate([idx, np.where(real, ports[0][at], 1),
+                           np.where(real, ports[1][at], 0)], axis=1)
+    return rows, nxt
 
 
 def compile_cidr_hash(networks: Sequence, acl: Optional[Sequence[AclRule]] = None,
@@ -660,7 +725,6 @@ def compile_cidr_hash(networks: Sequence, acl: Optional[Sequence[AclRule]] = Non
     tabs = []
     flat_items: list[int] = []
     off = 0
-    bk = caps.get("bk", 1)
     for gi, (fam, mask) in enumerate(g_live):
         t, items = CK.build_cuckoo(groups[(fam, mask)], 16,
                                    hasher=_fnv32_bytes, salt_base=3 + gi)
@@ -672,47 +736,54 @@ def compile_cidr_hash(networks: Sequence, acl: Optional[Sequence[AclRule]] = Non
         g_salt2[gi] = t.salt2
         t.bucket_start += len(flat_items)
         flat_items.extend(items.tolist())
-        bk = max(bk, _pow2(int(t.bucket_count.max(initial=1))))
         tabs.append(t)
         off += t.cap
 
     ct = max(caps.get("ct", 0), _pow2(max(off, 1), 256))
     s_used = np.zeros(ct, bool)
     s_key = np.zeros((ct, 16), np.uint8)
-    s_bs = np.zeros(ct, np.int32)
-    s_bc = np.zeros(ct, np.int32)
+    start = np.zeros(ct, np.int64)
+    count = np.zeros(ct, np.int64)
     o = 0
     for t in tabs:
         s_used[o: o + t.cap] = t.used
         s_key[o: o + t.cap] = t.key_bytes
-        s_bs[o: o + t.cap] = t.bucket_start
-        s_bc[o: o + t.cap] = np.minimum(t.bucket_count, bk)
+        start[o: o + t.cap] = t.bucket_start
+        count[o: o + t.cap] = t.bucket_count
         o += t.cap
 
-    cb = max(caps.get("cb", 0), _pow2(max(len(flat_items), 1), 256))
-    cb_items = np.full(cb, -1, np.int32)
-    cb_items[: len(flat_items)] = flat_items
+    bk = max(caps.get("bk", 1), _bucket_cap(int(count.max(initial=1))))
+    k = min(bk, BUCKET_INLINE)
+    hops = -(-bk // k)
+    fat = count > k
+    n_ov = int(((count[fat] - 1) // k).sum())
+    ov = max(caps.get("ov", 0), _pow2(n_ov, 8) if n_ov else 0)
 
-    r_valid = np.zeros(r_cap, bool)
-    r_valid[:n] = True
-    min_port = np.zeros(r_cap, np.int32)
-    max_port = np.full(r_cap, 65535, np.int32)
     allow = np.zeros(r_cap, bool)
+    ports = None
     if acl is not None:
+        ports = (np.zeros(r_cap, np.int32), np.zeros(r_cap, np.int32))
         for i, r in enumerate(acl):
-            min_port[i], max_port[i], allow[i] = r.min_port, r.max_port, r.allow
+            ports[0][i], ports[1][i], allow[i] = r.min_port, r.max_port, r.allow
+    b_rows, b_next = _bucket_rows(np.asarray(flat_items, np.int32),
+                                  start, count, k, ov, ports)
 
     arrays = {
         "g_fam": g_fam, "g_mask": g_mask, "g_off": g_off,
         "g_capmask": g_capmask, "g_salt1": g_salt1, "g_salt2": g_salt2,
-        "s_used": s_used, "s_key": s_key, "s_bs": s_bs, "s_bc": s_bc,
-        "cb_items": cb_items, "r_valid": r_valid,
-        "min_port": min_port, "max_port": max_port, "allow": allow,
-        "bk_iota": np.arange(bk, dtype=np.int32),
+        "s_used": s_used, "s_key": s_key, "b_rows": b_rows,
+        # [hops, K]: the kernel's two static numbers, as a shape
+        "b_shape": np.zeros((hops, k), np.int8),
+        "allow": allow,
     }
+    if hops > 1:
+        arrays["b_next"] = b_next
     return HashCidrTable(n=n, r_cap=r_cap, arrays=arrays,
                          caps={"r_cap": r_cap, "g_cap": g_cap, "ct": ct,
-                               "cb": cb, "bk": bk})
+                               "bk": bk, "ov": ov},
+                         buckets={"width": k, "hops": hops,
+                                  "used_slots": int(s_used.sum()),
+                                  "overflow_slots": int(fat.sum())})
 
 
 def _fnv32_device(masked: jnp.ndarray, salt: jnp.ndarray) -> jnp.ndarray:
@@ -729,13 +800,11 @@ def cidr_hash_match(t: dict, addr16: jnp.ndarray, fam: jnp.ndarray,
                     port: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """-> first-matching rule index [B] i32 (ordered-scan semantics), -1
     if none. addr16 [B,16] u8, fam [B] i32, port [B] i32 (ACL only)."""
-    r_cap = t["r_valid"].shape[0]
-    b = addr16.shape[0]
     with jax.named_scope("cidr_mask"):      # per-group masked address
         masked = addr16[:, None, :] & t["g_mask"][None]  # [B, G, 16]
         gok = (t["g_fam"][None] >= 0) & (fam[:, None] == t["g_fam"][None])
 
-    cands = []
+    probes = []
     for salt in (t["g_salt1"], t["g_salt2"]):
         with jax.named_scope("cidr_hash"):  # FNV over the 16 masked bytes
             h = _fnv32_device(masked, salt)
@@ -744,23 +813,33 @@ def cidr_hash_match(t: dict, addr16: jnp.ndarray, fam: jnp.ndarray,
         with jax.named_scope("cidr_probe"):  # slot gathers + key verify
             key = t["s_key"][slot]  # [B, G, 16]
             ok = gok & t["s_used"][slot] & jnp.all(key == masked, axis=-1)
-            start, cnt = t["s_bs"][slot], t["s_bc"][slot]
-            j = t["bk_iota"][None, None, :]
-            cands.append(jnp.where(ok[:, :, None] & (j < cnt[:, :, None]),
-                                   start[:, :, None] + j, -1))
-    with jax.named_scope("cidr_candidates"):    # bucket items -> rule ids
-        slot_cand = jnp.concatenate(cands, axis=1).reshape(b, -1)
-        cand = jnp.where(slot_cand >= 0,
-                         t["cb_items"][jnp.maximum(slot_cand, 0)], -1)
-        c = jnp.maximum(cand, 0)
-    with jax.named_scope("cidr_gate"):      # rule validity + port range
-        valid = (cand >= 0) & t["r_valid"][c]
-        if port is not None:
-            valid = valid & (t["min_port"][c] <= port[:, None]) & \
-                (port[:, None] <= t["max_port"][c])
-    with jax.named_scope("cidr_reduce"):    # first match = least index
-        first = jnp.min(jnp.where(valid, c, r_cap), axis=1).astype(jnp.int32)
-        return jnp.where(first < r_cap, first, -1)
+            probes.append((slot, ok))
+    with jax.named_scope("cidr_candidates"):    # a key sits in one slot
+        (slot1, ok1), (slot2, ok2) = probes
+        slot, hit = jnp.where(ok1, slot1, slot2), ok1 | ok2
+
+    # the hit slot's bucket: one row gather a hop; the port gate and the
+    # reduce run on the gathered row
+    hops, k = t["b_shape"].shape
+    gated = port is not None and t["b_rows"].shape[1] > k  # a route
+    #                            table has no range columns: every port
+    first = jnp.full(addr16.shape[0], NO_RULE, jnp.int32)
+    for hop in range(hops):
+        with jax.named_scope("cidr_candidates"):
+            row = t["b_rows"][slot]  # [B, G, w * K]
+        with jax.named_scope("cidr_gate"):      # port range
+            ok = hit[:, :, None]
+            if gated:
+                p = port[:, None, None]
+                ok = ok & (row[..., k: 2 * k] <= p) & (p <= row[..., 2 * k:])
+        with jax.named_scope("cidr_reduce"):    # first match = least index
+            first = jnp.minimum(first, jnp.min(
+                jnp.where(ok, row[..., :k], NO_RULE), axis=(1, 2)))
+        if hop + 1 < hops:
+            with jax.named_scope("cidr_candidates"):
+                slot = t["b_next"][slot]
+    with jax.named_scope("cidr_reduce"):
+        return jnp.where(first != NO_RULE, first, -1)
 
 
 def classify_hash_all(hint_t: dict, route_t: dict, acl_t: dict,

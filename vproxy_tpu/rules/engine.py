@@ -212,6 +212,9 @@ def _pad_hint_q(q: dict, cap: int, fills: dict) -> dict:
 #                                   upload + publish, background thread)
 #   vproxy_engine_table_bytes{matcher="hint"|"cidr"} — device bytes of
 #                                   every live matcher's published table
+#   vproxy_engine_cidr_bucket_width / _lookup_hops / _overflow_share —
+#                                   the cidr hash tables' bucket layout
+#                                   (cidr_bucket_stat)
 
 _gen_lock = threading.Lock()
 _GENERATION = [0]
@@ -291,6 +294,22 @@ def table_bytes_total(kind: str) -> int:
         if m._kind == kind:
             total += m.published_table_bytes()
     return total
+
+
+def cidr_bucket_stat() -> dict:
+    """Bucket layout of the live "jax" / "jax-sharded" cidr tables, for
+    /metrics: the widest bucket row, the most hops a lookup makes, and
+    the share of used cuckoo slots that own an overflow row — whether
+    the deployed tables are in the one-hop case (hops 1, share 0)."""
+    with _gen_lock:
+        matchers = list(_MATCHERS)
+    stats = [b for b in (m.bucket_stat() for m in matchers
+                         if m._kind == "cidr") if b]
+    used = sum(b["used_slots"] for b in stats)
+    return {"width": max((b["width"] for b in stats), default=0),
+            "hops": max((b["hops"] for b in stats), default=0),
+            "overflow_share": sum(b["overflow_slots"] for b in stats)
+            / used if used else 0.0}
 
 
 def _swap_hist():
@@ -969,6 +988,7 @@ class CidrMatcher:
         self._dev: Optional[dict] = None
         self._caps: Optional[dict] = None
         self._tab = None   # jax-sharded stacked table meta
+        self._buckets: Optional[dict] = None  # see bucket_stat()
         self._mesh = mesh  # jax-sharded only (lazily defaulted)
         self._fns: dict = {}  # jax-sharded jitted fns keyed by with_port
         self.generation = 0  # bumps on every publish (atomic swap)
@@ -997,7 +1017,7 @@ class CidrMatcher:
         """See HintMatcher._install — transactional standby compile."""
         networks, acl, payload = args
         old = (self._nets, self._acl, self._payload, self._tab,
-               self._dev, self._caps)
+               self._dev, self._caps, self._buckets)
         self._nets = list(networks)
         self._acl = list(acl) if acl is not None else None
         self._payload = payload
@@ -1005,7 +1025,7 @@ class CidrMatcher:
             self._recompile()
         except BaseException:
             (self._nets, self._acl, self._payload, self._tab,
-             self._dev, self._caps) = old
+             self._dev, self._caps, self._buckets) = old
             raise
 
     def published_table_bytes(self) -> int:
@@ -1014,15 +1034,23 @@ class CidrMatcher:
             return 0
         return int(sum(getattr(v, "nbytes", 0) for v in dev.values()))
 
+    def bucket_stat(self) -> Optional[dict]:
+        """The installed hash table's bucket layout (hashmatch
+        HashCidrTable.buckets; summed over the shards of a sharded
+        table): row width, hops a lookup makes, used slots and how many
+        of them own an overflow row. None on the backends with another
+        layout. Surfaced in `list-detail security-group` and, over all
+        live tables, on /metrics (cidr_bucket_stat)."""
+        return self._buckets
+
     def _recompile(self) -> None:
         itid = trace.current_id()  # nonzero only under a traced install
         t_ph = time.monotonic_ns() if itid else 0
-        hash_arrays = None  # "jax" backend: source for the packed build
         if self.backend == "jax":
             tab = H.compile_cidr_hash(self._nets, acl=self._acl, caps=self._caps)
             self._caps = tab.caps
+            self._buckets = tab.buckets
             self._dev = _to_device(tab.arrays)
-            hash_arrays = tab.arrays
         elif self.backend == "jax-fp":
             from ..ops import fphash as F
             try:
@@ -1050,6 +1078,12 @@ class CidrMatcher:
                 self._tab = compile_sharded(self._nets, shards,
                                             acl=self._acl)
             self._caps = self._tab.shards[0].caps
+            if self.backend == "jax-sharded":
+                per = [t.buckets for t in self._tab.shards]
+                self._buckets = {
+                    "width": per[0]["width"], "hops": per[0]["hops"],
+                    "used_slots": sum(b["used_slots"] for b in per),
+                    "overflow_slots": sum(b["overflow_slots"] for b in per)}
             self._dev = M.shard_hash_table(self._tab, self._mesh)
             M.release_host(self._tab)  # memory-lean: see HintMatcher
             # _fns kept: see HintMatcher._recompile
@@ -1063,17 +1097,14 @@ class CidrMatcher:
         if len(self._nets) > SMALL_TABLE:  # every backend: see HintMatcher
             from .index import CidrIndex
             idx = CidrIndex(self._nets, acl=self._acl)
-        # packed fused-dispatch tables: same standby-build + atomic
-        # pub-swap contract as HintMatcher._recompile
-        fused_dev = None
-        if hash_arrays is not None and fused_enabled():
-            from ..ops import fused as F
-            fused_dev = _to_device(F.pack_cidr_table(hash_arrays))
+        # the fused program reads the cidr table's own arrays (its slot
+        # rows carry their buckets: nothing to pack, no second upload)
+        fused_dev = self._dev if self.backend == "jax" and fused_enabled() \
+            else None
         _install_phase(itid, "compile", t_ph, matcher="cidr",
                        rules=len(self._nets))
         t_ph = time.monotonic_ns() if itid else 0
         _sync_standby(self._dev)
-        _sync_standby(fused_dev)
         _install_phase(itid, "upload", t_ph, matcher="cidr")
         time.sleep(0)  # preemption point between compile and publish
         t_ph = time.monotonic_ns() if itid else 0

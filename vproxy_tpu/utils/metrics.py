@@ -377,6 +377,16 @@ class GlobalInspection:
                 "vproxy_engine_table_bytes",
                 lambda kind=kind: self._engine_table_bytes(kind),
                 matcher=kind)
+        # the cidr hash tables' bucket layout (engine.cidr_bucket_stat):
+        # width 16 / hops 1 / share 0 is the one-hop case the kernel is
+        # sized for; hops > 1 says some network carries more port
+        # ranges than a slot row holds
+        for name, key in (("vproxy_engine_cidr_bucket_width", "width"),
+                          ("vproxy_engine_cidr_lookup_hops", "hops"),
+                          ("vproxy_engine_cidr_overflow_share",
+                           "overflow_share")):
+            self.registry.gauge_f(
+                name, lambda key=key: self._engine_cidr_bucket(key))
         # fused-dispatch accounting (rules/engine.py note_launch): total
         # device launches on the dispatch path and how many batches rode
         # the fused one-launch program — the scrape-verifiable form of
@@ -533,6 +543,12 @@ class GlobalInspection:
         import sys  # scrape must not force a jax import
         eng = sys.modules.get("vproxy_tpu.rules.engine")
         return 0.0 if eng is None else float(eng.table_bytes_total(kind))
+
+    @staticmethod
+    def _engine_cidr_bucket(key: str) -> float:
+        import sys  # scrape must not force a jax import
+        eng = sys.modules.get("vproxy_tpu.rules.engine")
+        return 0.0 if eng is None else float(eng.cidr_bucket_stat()[key])
 
     @staticmethod
     def _engine_stat(name: str) -> float:
