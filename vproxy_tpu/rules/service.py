@@ -98,6 +98,7 @@ import numpy as np
 
 from ..utils import sketch, trace
 from ..utils.log import Logger
+from ..utils.metrics import CLASSIFY_KINDS
 from .engine import SMALL_TABLE, pad_batch
 from .ir import Hint
 
@@ -163,6 +164,40 @@ class _Inflight:
         self.tid = tid    # the batch's first sampled request, 0 = none
 
 
+class _Cycle:
+    """Tracing on: one dispatcher wake that found work, as spans.
+    `engine/cycle` runs from the swap of the pending queue to the end of
+    the wake's last turn (its dispatch and the delivery of the batch
+    before it). One `engine/turn_wait` a uniform part runs from the
+    cycle's start to that part's own dispatch: the share of its queries'
+    queue_wait spent behind the other matchers of the wake. All are
+    entered at the start and left innermost first, so they nest in a
+    profiler trace; a turn_wait is buffered on its part's first sampled
+    request."""
+
+    __slots__ = ("span", "waits")
+
+    def __init__(self, parts: list):
+        self.span = trace.span("engine", "cycle", tid=0,
+                               items=sum(len(p) for _k, _m, p in parts),
+                               batches=len(parts))
+        self.span.__enter__()
+        self.waits = [
+            trace.span("engine", "turn_wait",
+                       tid=next((r.tid for r in p if r.tid), 0),
+                       items=len(p), kind=k, batch=len(p))
+            for k, _m, p in reversed(parts)]
+        for w in self.waits:
+            w.__enter__()
+
+    def turn(self) -> None:
+        """The next part's dispatch begins."""
+        self.waits.pop().__exit__(None, None, None)
+
+    def close(self) -> None:
+        self.span.__exit__(None, None, None)
+
+
 class ClassifyStats:
     """Counters surfaced via utils/metrics GlobalInspection."""
 
@@ -170,6 +205,11 @@ class ClassifyStats:
         self.queries = 0          # total submitted
         self.dispatches = 0       # device batches dispatched
         self.device_queries = 0   # queries answered by the device
+        # the same two by service kind, one increment a batch (on
+        # /metrics as vproxy_classify_batches_total{kind} and
+        # vproxy_classify_batch_queries_total{kind})
+        self.batches = dict.fromkeys(CLASSIFY_KINDS, 0)
+        self.batch_queries = dict.fromkeys(CLASSIFY_KINDS, 0)
         self.oracle_queries = 0   # queries answered by the host oracle
         self.failovers = 0        # device errors that degraded a batch
         self.last_failover = ""   # repr of the newest such error
@@ -519,35 +559,40 @@ class ClassifyService:
                 if closed:
                     return
                 continue
-            for kind, matcher, reqs in batches:
-                for part in self._split_uniform(kind, reqs):
-                    nxt = None
+            parts = [(kind, matcher, part)
+                     for kind, matcher, reqs in batches
+                     for part in self._split_uniform(kind, reqs)]
+            # tracing on: the wake and each part's wait for its turn
+            cycle = _Cycle(parts) if trace.SAMPLE else None
+            for kind, matcher, part in parts:
+                if cycle is not None:
+                    cycle.turn()
+                nxt = None
+                try:
+                    nxt = self._begin_uniform(kind, matcher, part)
+                except MemoryError:
+                    raise  # OOM contract: log-then-die (utils/oom)
+                except Exception:
+                    # the dispatcher thread must survive ANY per-batch
+                    # error (incl. oracle/delivery bugs) — a dead thread
+                    # would strand every future classify silently.
+                    # Callbacks get -1 ("no match") so callers proceed.
+                    _log.error("classify dispatch failed; delivering "
+                               "no-match to batch", exc=True)
                     try:
-                        nxt = self._begin_uniform(kind, matcher, part)
+                        self._deliver(part, [-1] * len(part), kind=kind)
                     except MemoryError:
-                        raise  # OOM contract: log-then-die (utils/oom)
+                        raise
                     except Exception:
-                        # the dispatcher thread must survive ANY
-                        # per-batch error (incl. oracle/delivery bugs)
-                        # — a dead thread would strand every future
-                        # classify silently. Callbacks get -1 ("no
-                        # match") so callers proceed.
-                        _log.error("classify dispatch failed; delivering "
-                                   "no-match to batch", exc=True)
-                        try:
-                            self._deliver(part, [-1] * len(part),
-                                          kind=kind)
-                        except MemoryError:
-                            raise
-                        except Exception:
-                            _log.error("classify delivery failed",
-                                       exc=True)
-                    if inflight is not None:
-                        # deliver the PREVIOUS batch now that the next
-                        # one is already on the device
-                        self._finish_guarded(inflight)
-                        inflight = None
-                    inflight = nxt
+                        _log.error("classify delivery failed", exc=True)
+                if inflight is not None:
+                    # deliver the PREVIOUS batch now that the next one
+                    # is already on the device
+                    self._finish_guarded(inflight)
+                    inflight = None
+                inflight = nxt
+            if cycle is not None:
+                cycle.close()
 
     def _use_device(self, matcher, n: int) -> bool:
         if self.mode == "host" or getattr(matcher, "backend", "host") == "host":
@@ -687,9 +732,12 @@ class ClassifyService:
                 idxs = np.asarray(inf.arr)[:n]
             if inf.lone_big:
                 self._note_lone_latency("device", time.monotonic() - inf.t0)
-            with self.stats.lock:
-                self.stats.dispatches += 1
-                self.stats.device_queries += n
+            st = self.stats
+            with st.lock:
+                st.dispatches += 1
+                st.device_queries += n
+                st.batches[inf.kind] += 1
+                st.batch_queries[inf.kind] += n
         except MemoryError:
             raise
         except Exception as e:

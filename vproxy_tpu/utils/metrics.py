@@ -16,6 +16,11 @@ import traceback
 from typing import Callable, Dict, List, Optional, Tuple
 
 
+# the kinds a ClassifyService batch can be (rules/service.py `_submit`):
+# the closed label vocabulary of vproxy_classify_batches_total{kind}
+CLASSIFY_KINDS = ("hint", "cidr", "cpick")
+
+
 def _fmt_labels(labels: Dict[str, str]) -> str:
     if not labels:
         return ""
@@ -328,6 +333,15 @@ class GlobalInspection:
                   "oracle_queries", "failovers", "max_batch"):
             self.registry.gauge_f(
                 f"vproxy_classify_{k}", lambda k=k: self._classify_stat(k))
+        # device batches and their queries by service kind (one matcher
+        # kind a batch): which plane's lookups fill the dispatcher
+        for k in CLASSIFY_KINDS:
+            self.registry.gauge_f(
+                "vproxy_classify_batches_total",
+                lambda k=k: self._classify_stat("batches", k), kind=k)
+            self.registry.gauge_f(
+                "vproxy_classify_batch_queries_total",
+                lambda k=k: self._classify_stat("batch_queries", k), kind=k)
         # native splice-pump counters (net/native/vtl.cpp, the hot-byte
         # black box): bytes spliced, write syscalls, short writes, TLS
         # handshakes — read through the C-ABI getter in net/vtl.py
@@ -527,10 +541,13 @@ class GlobalInspection:
         self.get_histogram("vproxy_maglev_build_ms", reservoir=256)
 
     @staticmethod
-    def _classify_stat(key: str) -> float:
+    def _classify_stat(key: str, kind: Optional[str] = None) -> float:
         from ..rules.service import ClassifyService
         svc = ClassifyService._instance
-        return 0.0 if svc is None else float(getattr(svc.stats, key))
+        if svc is None:
+            return 0.0
+        val = getattr(svc.stats, key)
+        return float(val if kind is None else val[kind])
 
     @staticmethod
     def _engine_generation() -> float:

@@ -21,9 +21,14 @@ process-wide bounded buffer:
 * **engine**  — classify dispatch (rules/service.py + rules/engine.py).
   The dispatcher thread's BATCH CYCLE, one span each per batch: wait
   (parked in the condition variable with nothing pending and nothing
-  in flight), dispatch (the `_device_submit` call; parent of the next
-  two), encode (host encode + padding of one batch: `items` real
-  queries, `cpu_ns`), launch (the jitted call: enqueue AND the
+  in flight), cycle (one per wake that found work: from the swap of
+  the pending queue to the end of the wake's last turn; `items`
+  queries taken, `batches` uniform parts begun), turn_wait (one per
+  uniform part: from the cycle's start to the part's own dispatch —
+  the share of queue_wait spent behind the other matchers of the
+  wake; `items`, `kind`, `batch`), dispatch (the `_device_submit`
+  call; parent of the next two), encode (host encode + padding of
+  one batch: `items` real queries, `cpu_ns`), launch (the jitted call: enqueue AND the
   implicit upload of its numpy arguments; `kind`, `fused`, `bucket`),
   d2h_sync (the blocking `np.asarray` of the result), deliver (the
   callback loop: `items`, `cpu_ns`). Per sampled request: queue_wait
@@ -52,10 +57,10 @@ so the spans sit in a profiler trace on its own clock, beside the device.
 
 Sampling: `VPROXY_TPU_TRACE_SAMPLE` = N samples 1-in-N (0 = off, the
 default). Knob-off cost is one branch per site — per batch on the
-dispatcher, never per query. On, a batch costs its six `span()`s (two
-clock reads, a profiler annotation and one locked add each; encode and
-deliver two `thread_time_ns()` more — a syscall of ~6 us on some hosts)
-and a submit two clock reads. Two deciders:
+dispatcher, never per query. On, a batch costs its seven `span()`s and
+a wake one more (two clock reads, a profiler annotation and one locked
+add each; encode and deliver two `thread_time_ns()` more — a syscall of
+~6 us on some hosts) and a submit two clock reads. Two deciders:
 
 * `maybe_sample()` — deterministic counter-based 1-in-N (the accept
   paths; every Nth request).
@@ -264,8 +269,9 @@ def reset() -> None:
 
 # the closed vocabulary of totalled spans (utils/metrics pre-registers
 # vproxy_trace_span_us{plane,span} for each pair, at zero)
-SPANS = (("engine", "wait"), ("engine", "dispatch"), ("engine", "encode"),
-         ("engine", "launch"), ("engine", "d2h_sync"), ("engine", "deliver"),
+SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
+         ("engine", "dispatch"), ("engine", "encode"), ("engine", "launch"),
+         ("engine", "d2h_sync"), ("engine", "deliver"),
          ("engine", "queue_wait"), ("engine", "submit_lock_wait"),
          ("runtime", "gc_pause"))
 # bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
