@@ -8,6 +8,9 @@ import socket
 import threading
 import time
 
+import numpy as np
+import pytest
+
 from vproxy_tpu.net.eventloop import SelectorEventLoop
 from vproxy_tpu.utils.events import FlightRecorder
 from vproxy_tpu.utils.metrics import (Counter, Gauge, GaugeF, GlobalInspection,
@@ -130,6 +133,97 @@ def test_histogram_thread_safety_totals():
     [t.join() for t in ts]
     assert h._count == 4000
     assert h._sum == 7.0 * 4000
+
+
+def test_histogram_thread_safety_batches_beside_samples():
+    """observe_many from some threads while others observe: no update
+    is lost (the dispatcher's batches and the submitters' inline
+    answers share the process-global histogram)."""
+    import sys
+    h = Histogram("t_us", reservoir=64)
+    batch = np.full(50, 3.0)
+
+    def many():
+        for _ in range(200):
+            h.observe_many(batch)
+
+    def one():
+        for _ in range(2000):
+            h.observe(7.0)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ts = [threading.Thread(target=f) for f in (many, one) * 4]
+        [t.start() for t in ts]
+        [t.join(60) for t in ts]
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts)
+    n, total, counts = h.state()
+    assert n == 4 * (200 * 50 + 2000) == sum(counts) == h._res_n
+    assert total == 4 * (200 * 50 * 3.0 + 2000 * 7.0)
+    assert counts[2] == 4 * 200 * 50 and counts[3] == 4 * 2000
+    assert set(h._res) <= {3.0, 7.0} and len(h._res) == 64
+
+
+def _lognormal(n, seed=30):
+    # medians of ~20 ms in us, the shape of a served batch's latencies
+    return np.random.default_rng(seed).lognormal(10.0, 1.5, n)
+
+
+_EDGES = ([0.0, 0.5, 1.0, 1.0 + 1e-7, 2.0, 2.0 ** 26, 2.0 ** 26 + 1, 1e12]
+          + [2.0 ** k for k in (1, 2, 3, 10, 20, 25)]
+          + [2.0 ** k + 1e-3 for k in (1, 2, 3, 10, 20, 25)])
+
+# id -> (buckets, reservoir, samples observed one by one first, batches)
+_OBSERVE_MANY_CASES = {
+    "empty": (27, 64, [], [[]]),
+    "empty_after_samples": (27, 64, [3.0, 70.0], [[]]),
+    "one_value": (27, 64, [], [[37.5]]),
+    "bucket_edges": (27, 64, [], [_EDGES]),
+    "lognormal_1834": (27, 4096, [], [_lognormal(1834)]),
+    "wraps_the_ring": (27, 64, list(range(50)), [_lognormal(30)]),
+    "ends_at_the_ring_end": (27, 64, list(range(50)), [_lognormal(14)]),
+    "longer_than_the_ring": (27, 64, list(range(5)), [_lognormal(200)]),
+    "exactly_the_ring": (27, 64, list(range(5)), [_lognormal(64)]),
+    "two_batches": (27, 64, [], [_lognormal(40, 1), _lognormal(41, 2)]),
+    "two_batches_past_the_ring": (27, 64, [1.5], [_lognormal(100, 1),
+                                                  _lognormal(70, 2)]),
+    "no_reservoir": (27, 0, [9.0], [_lognormal(300)]),
+    "few_buckets": (8, 0, [], [_EDGES]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OBSERVE_MANY_CASES))
+def test_observe_many_leaves_what_observe_would(case):
+    """observe_many(vs) == for v in vs: observe(v): state(), the
+    exposition text, the percentiles and the reservoir ring."""
+    buckets, reservoir, first, batches = _OBSERVE_MANY_CASES[case]
+    ref = Histogram("h_us", buckets=buckets, reservoir=reservoir)
+    got = Histogram("h_us", buckets=buckets, reservoir=reservoir)
+    for v in first:
+        ref.observe(float(v))
+        got.observe(float(v))
+    for vs in batches:
+        for v in vs:
+            ref.observe(float(v))
+        got.observe_many(np.asarray(vs, dtype=np.float64))
+    (n_ref, sum_ref, counts_ref), (n, total, counts) = ref.state(), \
+        got.state()
+    assert (n, counts) == (n_ref, counts_ref)
+    assert total == pytest.approx(sum_ref, rel=1e-9, abs=0.0)
+    # the text: every line but _sum to the letter, _sum as a number
+    lines_ref, lines = ref.sample_lines(), got.sample_lines()
+    assert [ln for ln in lines if "_sum" not in ln] \
+        == [ln for ln in lines_ref if "_sum" not in ln]
+    (s_ref,), (s_got,) = ([ln for ln in ls if "_sum" in ln]
+                          for ls in (lines_ref, lines))
+    assert float(s_got.split()[-1]) == pytest.approx(
+        float(s_ref.split()[-1]), rel=1e-9, abs=0.0)
+    assert got._res_n == ref._res_n and got._res == ref._res
+    assert len(got._res) == reservoir
+    assert all(type(v) is float for v in got._res)
+    assert got.percentiles() == ref.percentiles()
 
 
 def test_flight_recorder_ring_and_events_endpoint():

@@ -549,3 +549,209 @@ def test_batch_cycle_spans_for_each_kind(kind):
     assert all(s["kind"] == kind and s["fused"] is (kind == "cpick")
                and s["parent"] == "dispatch" for s in launches)
     assert sum(s["batch"] for s in spans if s["span"] == "dispatch") == n
+
+
+# ---- deliver: a batch's latency bookkeeping once a batch (PR 30) ----
+
+def _held_batch(svc, m, submit_all, timeout=30):
+    """Deliver what submit_all() submits as ONE dispatcher batch: a gate
+    query's callback (loop=None: it runs on the dispatcher thread) holds
+    the dispatcher while the others pile up in the pending queue."""
+    entered, release = threading.Event(), threading.Event()
+
+    def gate(*_verdict):
+        entered.set()
+        assert release.wait(timeout)
+
+    svc.submit_hint(m, Hint.of_host("gate.example.com"), gate)
+    assert entered.wait(timeout)
+    try:
+        submit_all()
+    finally:
+        release.set()
+
+
+@pytest.mark.parametrize("kind", ["hint", "cidr", "cpick"])
+@pytest.mark.parametrize("size", ["below", "at", "above"])
+def test_batch_latencies_are_recorded_once_a_batch(kind, size):
+    """A batch of n through the dispatcher is n samples in the
+    instance's reservoir and in the global histogram whatever its
+    length; from LAT_BATCH_MIN on they go through observe_many and
+    latency_batched counts them, below it nothing does. Every callback
+    gets Python ints."""
+    from vproxy_tpu.rules import service
+    from vproxy_tpu.rules.maglev import FusedPair, MaglevMatcher
+    lo = service.LAT_BATCH_MIN
+    n = {"below": lo - 1, "at": lo, "above": 3 * lo + 4}[size]
+    hm = HintMatcher(mk_rules(64))
+    cm = CidrMatcher([Network(bytes([10, i, 0, 0]), mask_bytes(16))
+                      for i in range(64)])
+    pair = FusedPair(hm, MaglevMatcher([(f"b{i}", 1) for i in range(5)],
+                                       m=251))
+    svc = ClassifyService(mode="host")
+    global_before = svc.stats.lat_hist.state()[0]
+    got, done = [], threading.Event()
+
+    def cb(*verdict):
+        got.append(verdict)
+        if len(got) == n:
+            done.set()
+
+    def submit_all():
+        for i in range(n):
+            h = Hint.of_host(f"svc{i}.example.com")
+            if kind == "hint":
+                svc.submit_hint(hm, h, cb)
+            elif kind == "cidr":
+                svc.submit_cidr(cm, bytes([10, i, 1, 2]), None, cb)
+            else:
+                svc.submit_classify_pick(pair, h, bytes([172, 16, 0, i]),
+                                         80 + i, cb)
+
+    try:
+        _held_batch(svc, hm, submit_all)
+        assert done.wait(30)
+    finally:
+        svc.close()
+    assert svc.stats.max_batch == n   # one batch, the whole of it
+    # + 1: the gate query, a lone one, took the scalar observe
+    assert svc.stats.latency_percentiles()["n"] == n + 1
+    assert svc.stats.lat_hist.state()[0] - global_before == n + 1
+    assert svc.stats.latency_batched == (n if n >= lo else 0)
+    assert [v[0] for v in got] == list(range(n))   # the order submitted
+    for verdict in got:
+        assert len(verdict) == (3 if kind == "cpick" else 2)
+        assert all(type(x) is int for x in verdict[:-1]), verdict
+
+
+def test_global_latency_count_rises_with_the_instance():
+    """The process-global vproxy_classify_latency_us series and the
+    instance's reservoir take the same samples from one batch: equal
+    count deltas, equal sums, and /metrics shows the batched counter
+    beside the histogram's own _count."""
+    from vproxy_tpu.utils.metrics import GlobalInspection
+    ClassifyService.reset()
+    svc = ClassifyService.get()
+    svc.mode = "host"
+    m = HintMatcher(mk_rules(64))
+    n = 40
+    cb, results, done = collect(n)
+    c0, s0, _b = svc.stats.lat_hist.state()
+
+    def submit_all():
+        for i in range(n):
+            svc.submit_hint(m, Hint.of_host(f"svc{i}.example.com"),
+                            lambda idx, _pl, i=i: cb(i, idx))
+
+    _held_batch(svc, m, submit_all)
+    assert done.wait(30)
+    assert results == {i: i for i in range(n)}
+    c1, s1, _b = svc.stats.lat_hist.state()
+    cl, sl, _b = svc.stats._lat_local.state()
+    assert c1 - c0 == cl == n + 1
+    assert s1 - s0 == pytest.approx(sl, rel=1e-6)
+    text = GlobalInspection.get().registry.prometheus_text()
+    assert f"vproxy_classify_latency_batched_total {n}\n" in text
+    assert f"vproxy_classify_latency_us_count {c1}\n" in text
+
+
+def test_inline_answers_record_through_the_scalar_path():
+    svc = ClassifyService.get()
+    assert svc.mode == "auto"
+    m = HintMatcher(mk_rules(8))
+    got = []
+    for i in range(20):
+        svc.submit_hint(m, Hint.of_host(f"svc{i % 8}.example.com"),
+                        lambda idx, _pl: got.append(idx))
+    assert got == [i % 8 for i in range(20)]    # inline: synchronous
+    assert all(type(i) is int for i in got)
+    assert svc.stats.latency_percentiles()["n"] == 20
+    assert svc.stats.latency_batched == 0
+
+
+def test_failing_callback_is_logged_and_the_batch_goes_on(monkeypatch):
+    """loop=None: the callback runs on the dispatcher thread with no
+    closure around it; one that raises is logged, the callbacks after
+    it in the batch still run and the dispatcher thread survives."""
+    from vproxy_tpu.rules import service
+    logged = []
+
+    class Log:
+        @staticmethod
+        def error(msg, exc=False):
+            logged.append((msg, exc))
+
+    monkeypatch.setattr(service, "_log", Log)
+    svc = ClassifyService(mode="host")
+    m = HintMatcher(mk_rules(64))
+    n = 20
+    got, done = [], threading.Event()
+
+    def cb(idx, _pl):
+        if idx in (0, 7):
+            raise ValueError(f"callback {idx} fails")
+        got.append(idx)
+        if len(got) == n - 2:
+            done.set()
+
+    def submit_all():
+        for i in range(n):
+            svc.submit_hint(m, Hint.of_host(f"svc{i}.example.com"), cb)
+
+    try:
+        _held_batch(svc, m, submit_all)
+        assert done.wait(30)
+        assert got == [i for i in range(n) if i not in (0, 7)]
+        assert logged == [("classify callback failed", True)] * 2
+        assert svc.stats.max_batch == n
+        assert svc.stats.latency_percentiles()["n"] == n + 1
+        # the thread outlived it: the next query is served by it
+        assert svc._thread.is_alive()
+        cb2, results, done2 = collect(1)
+        svc.submit_hint(m, Hint.of_host("svc9.example.com"),
+                        lambda idx, _pl: cb2(0, idx))
+        assert done2.wait(30) and results[0] == 9
+    finally:
+        svc.close()
+
+
+@pytest.mark.parametrize("kind", ["hint", "cpick"])
+def test_error_fill_delivers_no_match_to_the_batch(kind):
+    """A batch whose dispatch raises gets the scalar fill [-1] * n: a
+    plain idx -1 — and for a classify+pick batch, whose rows are
+    (verdict, pick) pairs, both -1 — with payload None."""
+    class Broken:
+        backend = "host"
+
+        def snapshot(self):
+            raise RuntimeError("no generation")
+
+    svc = ClassifyService(mode="host")
+    gate_m = HintMatcher(mk_rules(4))
+    n = 15
+    got, done = [], threading.Event()
+
+    def cb(*verdict):
+        got.append(verdict)
+        if len(got) == n:
+            done.set()
+
+    def submit_all():
+        for i in range(n):
+            h = Hint.of_host(f"svc{i}.example.com")
+            if kind == "hint":
+                svc.submit_hint(broken, h, cb)
+            else:
+                svc.submit_classify_pick(broken, h, b"\x0a\x00\x00\x01",
+                                         80, cb)
+
+    broken = Broken()
+    try:
+        _held_batch(svc, gate_m, submit_all)
+        assert done.wait(30)
+    finally:
+        svc.close()
+    want = (-1, -1, None) if kind == "cpick" else (-1, None)
+    assert got == [want] * n
+    assert all(type(x) is int for v in got for x in v[:-1])
+    assert svc.stats.latency_percentiles()["n"] == n + 1
