@@ -12,9 +12,7 @@ Phases:
       scrape.
   [2] one launch, bit-identical — classify_and_pick over a batch: the
       launch counter moves by EXACTLY 1 (the unfused chain moves it by
-      2), verdicts == the host index, picks == the host maglev oracle;
-      the 3-column fused_dispatch_all adds the cidr route, parity vs
-      the unfused cidr dispatch.
+      2), verdicts == the host index, picks == the host maglev oracle.
   [3] generation install under fused load — `add fault
       engine.swap.stall` through the grammar while classify_and_pick
       hammers: every (verdict, pick) pair comes from ONE snapshot pair
@@ -24,11 +22,9 @@ Phases:
       through a FusedPair (fused micro-batch parity) and a StepLoop
       with the maglev plane (submit_pick at zero extra launches,
       status fused:true).
-  [5] knobs + the Pallas tier — VPROXY_TPU_FUSED=0 regenerates WITHOUT
-      packed tables and falls back identically; the fused tier
-      follows a kernel-knob flip (the PR-6 stale-program family);
-      the Pallas kernel bit-verifies in interpret mode and an explicit
-      kernel=pallas without it raises on CPU.
+  [5] fallback — a "host"-backend matcher publishes no packed tables
+      and classify_and_pick serves the two-dispatch chain with the
+      same answers.
 """
 import json
 import os
@@ -60,12 +56,10 @@ def main():
     from vproxy_tpu.control.command import Command
     from vproxy_tpu.control.http_controller import HttpController
     from vproxy_tpu.rules import engine as E
-    from vproxy_tpu.rules.engine import (CidrMatcher, HintMatcher,
-                                         fused_dispatch_all)
+    from vproxy_tpu.rules.engine import HintMatcher
     from vproxy_tpu.rules.ir import Hint, HintRule
     from vproxy_tpu.rules.maglev import (FusedPair, MaglevMatcher,
                                          classify_and_pick)
-    from vproxy_tpu.utils.ip import Network, mask_bytes
     from vproxy_tpu.utils.metrics import GlobalInspection
 
     app = Application(workers=2)
@@ -131,26 +125,8 @@ def main():
         np.asarray(mm.dispatch_snap(msnap, ips, ports))
         chain = E.dispatch_launches_total() - l0
         assert chain == 2, chain
-        # the 3-column sweep: + cidr/LPM route, still one launch
-        nets = [Network(bytes((10, i % 13, 0, 0)), mask_bytes(16))
-                for i in range(64)]
-        cm = CidrMatcher(nets, backend="jax")
-        csnap = cm.snapshot()
-        addrs = ips
-        out3 = np.asarray(fused_dispatch_all(
-            hm, hsnap, cm, csnap, mm, msnap, hints, addrs, ips, ports))
-        l0 = E.dispatch_launches_total()
-        out3 = np.asarray(fused_dispatch_all(
-            hm, hsnap, cm, csnap, mm, msnap, hints, addrs, ips,
-            ports))[:b]
-        assert E.dispatch_launches_total() - l0 == 1
-        rr = np.asarray(cm.dispatch_snap(csnap, addrs, None))
-        assert np.array_equal(out3[:, 0], np.asarray(v))
-        assert np.array_equal(out3[:, 1], np.asarray(p))
-        assert np.array_equal(out3[:, 2], rr)
-        say(f"[2] {b}-query batch: fused=1 launch (chain=2, +route "
-            f"still 1), verdicts==host index, picks==maglev oracle, "
-            f"routes==unfused cidr — bit-identical")
+        say(f"[2] {b}-query batch: fused=1 launch (chain=2), "
+            f"verdicts==host index, picks==maglev oracle")
 
         # ---- [3] stalled generation install under fused load
         rules2 = [HintRule(host=f"svc{i}.ns{i % 97}.fused.example")
@@ -233,44 +209,17 @@ def main():
             f"StepLoop(maglev=) status fused=true, submit_pick answers "
             f"(verdict, pick) through the step clock")
 
-        # ---- [5] knobs + the Pallas tier
-        os.environ["VPROXY_TPU_FUSED"] = "0"
-        try:
-            hm.set_rules(list(rules2))
-            assert hm.fused_stat() == {"available": False}
-            v5, p5, _h, _m = classify_and_pick(hm, mm, probe, pips)
-            assert int(v5[0]) >= 0 and [int(x) for x in p5] == want_picks
-        finally:
-            os.environ.pop("VPROXY_TPU_FUSED", None)
-        hm.set_rules(list(rules2))
-        assert hm.fused_stat()["available"]
+        # ---- [5] fallback: no packed tables -> the two-dispatch chain
         v5c, _p, _h, _m = classify_and_pick(hm, mm, probe, pips)
-        from vproxy_tpu.ops import fused_pallas as FP
-        fn0 = E._fused_fn()
-        os.environ["VPROXY_TPU_FUSED_KERNEL"] = "pallas"
-        os.environ["VPROXY_TPU_PALLAS_INTERPRET"] = "1"
-        try:
-            assert E._fused_fn() is FP.fused_classify_pick_pallas, \
-                "stale compiled program"
-            assert E.fused_kernel_name() == "pallas"
-            v6, p6, _h, _m = classify_and_pick(hm, mm, probe, pips)
-            assert [int(x) for x in v6] == [int(x) for x in v5c] and \
-                [int(x) for x in p6] == want_picks, "pallas != jit"
-            os.environ.pop("VPROXY_TPU_PALLAS_INTERPRET")
-            try:  # explicit pallas that cannot compile RAISES
-                np.asarray(E.fused_dispatch(
-                    hm, hm.snapshot(), mm, mm.snapshot(), probe, pips))
-                raise AssertionError("kernel=pallas on CPU did not raise")
-            except ValueError as e:
-                why = str(e)
-        finally:
-            os.environ.pop("VPROXY_TPU_FUSED_KERNEL", None)
-            os.environ.pop("VPROXY_TPU_PALLAS_INTERPRET", None)
-        assert E._fused_fn() is fn0 and E.fused_kernel_name() == "jit"
-        say(f"[5] VPROXY_TPU_FUSED=0 falls back identically (no packed "
-            f"tables); the fused tier follows a kernel-knob flip and "
-            f"interpret-mode bit-verifies the Pallas kernel; explicit "
-            f"kernel=pallas on CPU raises ('{why[:42]}...')")
+        hm_host = HintMatcher(list(rules2), backend="host")
+        assert hm_host.fused_stat() == {"available": False}
+        assert E.fused_dispatch(hm_host, hm_host.snapshot(), mm,
+                                mm.snapshot(), probe, pips) is None
+        v5, p5, _h, _m = classify_and_pick(hm_host, mm, probe, pips)
+        assert [int(x) for x in v5] == [int(x) for x in v5c] and \
+            [int(x) for x in p5] == want_picks
+        say("[5] a host-backend matcher publishes no packed tables; "
+            "classify_and_pick falls back to the chain, same answers")
 
         say("FUSED VERIFY OK")
     finally:

@@ -317,27 +317,3 @@ if _vtl10.tls_available() and _vtl10.PROVIDER == "native":
 else:
     print("[10] native TLS unavailable in this env (skipped)")
 print("VERIFY SCENARIO PASSED (incl. native TLS splice)")
-
-# ---- 11. real-socket switch pipeline: sendmmsg blaster -> switch UDP
-# sock -> recvmmsg drain -> fast path -> sendmmsg egress (subprocess
-# generator; kernel-loopback-bound by nature)
-from vproxy_tpu.net import vtl as _vtl11
-if _vtl11.PROVIDER == "native":
-    import bench_switch as _BS11
-    _l11, _sw11, _cnt11, _dg11 = _BS11.build_world(backend=None)
-    try:
-        _chunks11 = [_dg11[i:i + 1024]
-                     for i in range(0, len(_dg11), 1024)]
-        _l11.call_sync(lambda: [_sw11._input_batch(c)
-                                for c in _chunks11],
-                       timeout=600)  # warm tries/caches
-        _r11 = _BS11.socket_pipeline(_l11, _sw11, _dg11, 2)
-        assert _r11 and _r11["switch_socket_egressed"] > 1000, _r11
-        print(f"[11] real-socket switch pipeline: "
-              f"{_r11['switch_socket_loopback_pps']:.0f} pps egressed "
-              f"(kernel-loopback-bound) OK")
-    finally:
-        _sw11.stop(); _l11.close()
-else:
-    print("[11] native provider unavailable (skipped)")
-print("VERIFY SCENARIO PASSED (incl. real-socket switch pipeline)")

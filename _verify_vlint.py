@@ -1,7 +1,7 @@
 """Round-15 verify drive — vlint + sanitizer wiring, end to end.
 
 Drives the static-analysis layer through its OPERATOR surfaces (the
-`python -m tools.vlint` CLI, the baseline file, the bench snapshot
+`python -m tools.vlint` CLI, the baseline file, the `--json` snapshot
 row, `make sanitize` + the TSan driver), and proves detection on the
 REAL tree, not just the committed fixtures: a scratch copy of the
 repo gets four live regressions seeded — an ABI field swap whose
@@ -211,18 +211,6 @@ def main():
                   r.returncode == 0 and m is not None
                   and "WARNING: ThreadSanitizer" not in logs,
                   m.group(1) if m else r.stdout[-200:])
-
-    # -- 4. the bench artifact row ------------------------------------
-    r = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"),
-         "--static-analysis"],
-        cwd=ROOT, capture_output=True, text=True, timeout=120,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    row = json.loads(r.stdout.strip().splitlines()[-1])
-    check("bench static_analysis row",
-          row["static_analysis"]["open"] == 0
-          and "findings_by_pass" in row["static_analysis"],
-          json.dumps(row["static_analysis"]))
 
     print(f"\nALL {PASS} CHECKS PASSED in "
           f"{time.monotonic() - t0:.1f}s")

@@ -1,7 +1,7 @@
 """Fused classify+pick dispatch (ops/fused.py + rules/engine.py).
 
 The one-launch contract: a batch's verdict (hint match) AND pick
-(Maglev) — optionally the cidr/LPM route too — come from ONE compiled
+(Maglev) come from ONE compiled
 program over int8/int32-packed tables, bit-identical to the unfused
 op chain, published through the same double-buffered TableInstaller
 swap, with the launch counter proving "one launch per batch" instead
@@ -15,13 +15,11 @@ import numpy as np
 import pytest
 
 from vproxy_tpu.rules import engine
-from vproxy_tpu.rules.engine import (CidrMatcher, HintMatcher,
-                                     fused_dispatch, fused_dispatch_all)
+from vproxy_tpu.rules.engine import HintMatcher, fused_dispatch
 from vproxy_tpu.rules.ir import Hint, HintRule
 from vproxy_tpu.rules.maglev import FusedPair, MaglevMatcher, \
     classify_and_pick
 from vproxy_tpu.utils import failpoint
-from vproxy_tpu.utils.ip import Network, mask_bytes
 
 
 @pytest.fixture(autouse=True)
@@ -76,19 +74,6 @@ def mk_ips(n, seed=5):
     return [bytes([10 + rnd.randrange(14), rnd.randrange(256),
                    rnd.randrange(256), rnd.randrange(256)])
             for _ in range(n)]
-
-
-def mk_nets(n, seed=13):
-    rnd = random.Random(seed)
-    nets = []
-    for i in range(n):
-        ml = rnd.choice([8, 12, 16, 20, 24, 28, 32])
-        ip = bytes([10 + (i % 13), rnd.randrange(256), rnd.randrange(256),
-                    rnd.randrange(256)])
-        mk = mask_bytes(ml)
-        nets.append(Network(bytes(np.frombuffer(ip, np.uint8) &
-                                  np.frombuffer(mk, np.uint8)), mk))
-    return nets
 
 
 def _unfused_chain(hm, mm, hints, ips, ports=None):
@@ -156,28 +141,6 @@ def test_fused_parity_randomized_1m_slow():
     _parity_case(1_000_000, 1024)
 
 
-def test_fused_all_route_parity():
-    """The 3-column form: verdict + pick + cidr/LPM route in one
-    launch, route bit-identical to the unfused cidr dispatch."""
-    rules = mk_rules(5_000)
-    nets = mk_nets(5_000)
-    hm = HintMatcher(rules, backend="jax")
-    cm = CidrMatcher(nets, backend="jax")
-    mm = MaglevMatcher([(f"b{i}", 1) for i in range(5)], m=251)
-    b = 128
-    hints = mk_queries(rules, b)
-    addrs = mk_ips(b, seed=29)
-    ips = mk_ips(b)
-    rv, rp = _unfused_chain(hm, mm, hints, ips)
-    rr = np.asarray(cm.dispatch_snap(cm.snapshot(), addrs, None))
-    out = np.asarray(fused_dispatch_all(
-        hm, hm.snapshot(), cm, cm.snapshot(), mm, mm.snapshot(),
-        hints, addrs, ips))[:b]
-    assert np.array_equal(rv, out[:, 0])
-    assert np.array_equal(rp, out[:, 1])
-    assert np.array_equal(rr, out[:, 2])
-
-
 def test_fused_pad_rows_never_match():
     rules = mk_rules(300)
     hm = HintMatcher(rules, backend="jax")
@@ -191,9 +154,8 @@ def test_fused_pad_rows_never_match():
 
 
 def test_fused_unavailable_fallbacks():
-    """Non-"jax" backends and VPROXY_TPU_FUSED=0 publish no packed
-    tables; classify_and_pick falls back to the overlapped chain with
-    identical results."""
+    """A non-"jax" backend publishes no packed tables; classify_and_pick
+    falls back to the overlapped chain with identical results."""
     rules = mk_rules(300)
     hm_host = HintMatcher(rules, backend="host")
     mm = MaglevMatcher([(f"b{i}", 1) for i in range(3)], m=251)
@@ -202,18 +164,6 @@ def test_fused_unavailable_fallbacks():
     v, p, _hp, _mp = classify_and_pick(hm_host, mm, mk_queries(rules, 4),
                                        mk_ips(4))
     assert len(v) == 4 and len(p) == 4
-
-
-def test_fused_disabled_by_knob(monkeypatch):
-    monkeypatch.setenv("VPROXY_TPU_FUSED", "0")
-    hm = HintMatcher(mk_rules(64), backend="jax")
-    assert hm.fused_stat() == {"available": False}
-    mm = MaglevMatcher([("b0", 1)], m=251)
-    assert fused_dispatch(hm, hm.snapshot(), mm, mm.snapshot(),
-                          mk_queries(hm.rules, 4), mk_ips(4)) is None
-    monkeypatch.delenv("VPROXY_TPU_FUSED")
-    hm.set_rules(mk_rules(64))  # next generation re-packs
-    assert hm.fused_stat()["available"]
 
 
 # ------------------------------------------------- one-launch counter
@@ -304,78 +254,6 @@ def test_maglev_install_swaps_pick_atomically():
     for i, ip in enumerate(ips):
         assert int(p[i]) == mm.pick_snap(msnap, ip)
     assert set(np.asarray(p).tolist()) <= {0, 1}
-
-
-# ------------------------------------------------ fused tier (knobs)
-
-
-def test_fused_tier_follows_the_kernel_knob(monkeypatch):
-    """The PR-6 stale-mesh family: a VPROXY_TPU_* knob change
-    mid-process must select the other program, never keep serving the
-    one for the old knob state. The tier is chosen BY NAME: the default
-    is the jit tier, and no capability probe runs."""
-    from vproxy_tpu.ops import fused as F
-    from vproxy_tpu.ops import fused_pallas as FP
-    monkeypatch.delenv("VPROXY_TPU_FUSED_KERNEL", raising=False)
-    monkeypatch.delenv("VPROXY_TPU_PALLAS_INTERPRET", raising=False)
-    fn0 = engine._fused_fn()
-    assert fn0 is F.fused_jit
-    assert engine._fused_fn() is fn0  # stable under a stable key
-    assert engine.fused_kernel_name() == "jit"
-    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "pallas")
-    monkeypatch.setenv("VPROXY_TPU_PALLAS_INTERPRET", "1")
-    fn1 = engine._fused_fn()
-    assert fn1 is FP.fused_classify_pick_pallas, \
-        "knob change served a stale compiled program"
-    assert engine.fused_kernel_name() == "pallas"
-    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "jit")
-    assert engine._fused_fn() is fn0
-    # interpret mode alone never moves serving off the jit tier
-    monkeypatch.delenv("VPROXY_TPU_FUSED_KERNEL", raising=False)
-    assert engine._fused_fn() is F.fused_jit
-    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "auto")  # retired value
-    with pytest.raises(ValueError, match="expected 'jit' or 'pallas'"):
-        engine._fused_fn()
-
-
-# ------------------------------------------------------- pallas tier
-
-
-def test_explicit_pallas_on_cpu_raises(monkeypatch):
-    """VPROXY_TPU_FUSED_KERNEL=pallas where the kernel cannot compile
-    (the CPU platform, no interpret mode) RAISES at the first fused
-    dispatch — it does not warn and serve the jit tier."""
-    from vproxy_tpu.rules.maglev import MaglevMatcher
-    monkeypatch.setenv("VPROXY_TPU_FUSED_KERNEL", "pallas")
-    monkeypatch.delenv("VPROXY_TPU_PALLAS_INTERPRET", raising=False)
-    hm = HintMatcher(mk_rules(64), backend="jax")
-    mm = MaglevMatcher([("a:1", 1), ("b:2", 1)], m=251)
-    with pytest.raises(ValueError, match="interpret mode"):
-        np.asarray(engine.fused_dispatch(
-            hm, hm.snapshot(), mm, mm.snapshot(),
-            mk_queries(hm.rules, 8), mk_ips(8)))
-
-
-def test_pallas_interpret_bit_verify():
-    """The Pallas kernel's statement of the contract, in interpret
-    mode: (verdict, pick) bit-identical to the fused jit on a
-    randomized table."""
-    from vproxy_tpu.ops import fused as F
-    from vproxy_tpu.ops import fused_pallas as FP
-    from vproxy_tpu.ops import hashmatch as H
-    rules = mk_rules(400)
-    tab = H.compile_hint_hash(rules)
-    hints = mk_queries(rules, 24)
-    q = H.encode_hint_queries(hints, tab)
-    ht = F.pack_hint_table(tab.arrays)
-    from vproxy_tpu.rules.maglev import build_table, flow_hash
-    mtab = build_table([(f"b{i}", 1) for i in range(6)], m=251)
-    ips = mk_ips(24)
-    slots = np.array([flow_hash(ip) % 251 for ip in ips], np.int64)
-    ref = np.asarray(F.fused_jit(ht, q, mtab, slots))
-    got = np.asarray(FP.fused_classify_pick_pallas(ht, q, mtab, slots,
-                                                   interpret=True))
-    assert np.array_equal(ref, got)
 
 
 # ------------------------------------------------- service + step loop

@@ -16,6 +16,7 @@ Two levels of evidence:
   pod slice would run, with DCN standing in for the coordinator.
 """
 import os
+import re
 import subprocess
 import sys
 
@@ -123,6 +124,10 @@ print(f"DIST_OK pid={pid} parity on {B_local} local queries", flush=True)
 """
 
 
+_PORT_TAKEN = re.compile(
+    r"address already in use|failed to add port to server", re.I)
+
+
 @pytest.mark.timeout(180)
 def test_real_two_process_distributed(tmp_path):
     """Spawns two coordinator-connected jax processes; each runs the
@@ -130,29 +135,36 @@ def test_real_two_process_distributed(tmp_path):
     local query slice and checks oracle parity (cross-process CPU
     collectives ride jax's default gloo implementation)."""
     import socket
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
     script = tmp_path / "worker.py"
     script.write_text(_WORKER)
     env = {k: v for k, v in os.environ.items()
            if k not in ("PYTHONPATH", "JAX_PLATFORMS", "XLA_FLAGS")}
     env["VPROXY_REPO"] = os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))
-    procs = [subprocess.Popen(
-        [sys.executable, str(script), str(pid), str(port)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-        for pid in (0, 1)]
-    outs = []
-    try:
-        for p in procs:
-            out, _ = p.communicate(timeout=150)
-            outs.append(out.decode())
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
+    # jax.distributed takes an address, not a socket, so the probed port
+    # is free only until someone else binds it: a sibling xdist worker
+    # can take it before the coordinator does. Retry on a fresh port.
+    for _attempt in range(3):
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+        procs = [subprocess.Popen(
+            [sys.executable, str(script), str(pid), str(port)],
+            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+            for pid in (0, 1)]
+        outs = []
+        try:
+            for p in procs:  # worker 0 hosts the coordinator
+                out, _ = p.communicate(timeout=150)
+                outs.append(out.decode())
+                if _PORT_TAKEN.search(outs[0]):
+                    break
+        finally:
+            for p in procs:
+                if p.poll() is None:
+                    p.kill()
+        if len(outs) == 2:
+            break
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid} failed:\n{out[-3000:]}"
         assert f"DIST_OK pid={pid}" in out, out[-2000:]

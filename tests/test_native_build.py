@@ -125,6 +125,58 @@ def test_native_so_rebuilds_and_exports_current_abi():
     assert len(vtl.LANE_STAGES) == 3
 
 
+def _toolchain() -> bool:
+    return shutil.which("make") is not None and shutil.which("g++") is not None
+
+
+def test_tier1_runs_on_the_native_provider():
+    """Where a toolchain exists the silent fallback is a failure: a
+    worker on the pure-python provider skips the native-gated files and
+    the count stops being a property of the tree."""
+    if not _toolchain():
+        pytest.skip("no toolchain: the py fallback is the provider")
+    if os.environ.get("VPROXY_TPU_FD_PROVIDER") == "py":
+        pytest.skip("py provider requested")
+    from vproxy_tpu.net import vtl
+    assert vtl.PROVIDER == "native"
+
+
+def test_concurrent_first_imports_build_once_and_all_load_native(tmp_path):
+    """A fresh checkout has no libvtl.so and xdist starts every worker
+    at once: all of them must end on the native provider (no loader
+    maps a half-written file) and exactly one of them compiles."""
+    if not _toolchain():
+        pytest.skip("no toolchain")
+    pkg = tmp_path / "vproxy_tpu"
+    root = os.path.join(NATIVE_DIR, "..")
+    for rel in ("__init__.py", "net/__init__.py", "net/vtl.py",
+                "net/vtl_py.py", "native/Makefile", "native/vtl.cpp"):
+        (pkg / rel).parent.mkdir(parents=True, exist_ok=True)
+        shutil.copy(os.path.join(root, rel), pkg / rel)
+    # the Makefile takes CXX from the environment: count the compiles
+    cxx = tmp_path / "cxx.sh"
+    cxx.write_text('#!/bin/sh\necho x >> "$CXX_COUNT"\nexec g++ "$@"\n')
+    cxx.chmod(0o755)
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("VPROXY_TPU_FD_PROVIDER", "VPROXY_TPU_VTL_SO")}
+    env.update(PYTHONPATH=str(tmp_path), CXX=str(cxx),
+               CXX_COUNT=str(tmp_path / "compiles"))
+    procs = [subprocess.Popen(
+        [sys.executable, "-c",
+         "from vproxy_tpu.net import vtl; print(vtl.PROVIDER, vtl.__file__)"],
+        cwd=tmp_path, env=env, text=True,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE) for _ in range(6)]
+    for p in procs:
+        out, err = p.communicate(timeout=120)
+        assert p.returncode == 0, err[-800:]
+        assert out.split() == ["native", str(pkg / "net" / "vtl.py")], \
+            (out, err[-800:])
+        assert "unavailable" not in err, err[-800:]
+    assert (tmp_path / "compiles").read_text().count("x") == 1
+    assert sorted(os.listdir(pkg / "native")) == [
+        ".build.lock", "Makefile", "libvtl.so", "vtl.cpp"]
+
+
 def test_uring_probe_contract():
     """The io_uring probe is a stable bitmask (bit0 setup, bits 1-5
     opcodes), cached, and never a precondition: lanes must come up on

@@ -9,6 +9,8 @@ import subprocess
 import sys
 import time
 
+import pytest
+
 from vproxy_tpu.net import vtl_py
 
 REPO = str(pathlib.Path(__file__).resolve().parents[1])
@@ -30,6 +32,23 @@ def test_env_selects_python_provider():
              "VPROXY_TPU_FD_PROVIDER": "py"})
     assert r.returncode == 0, r.stderr
     assert "py provider ok" in r.stdout
+
+
+@pytest.mark.parametrize("test_file", ["test_docker_plugin.py",
+                                       "test_tls.py"])
+def test_suite_file_runs_on_the_python_provider(test_file):
+    """The provider the loader falls back to serves these files with no
+    failure (native-only tests may skip): the docker plugin's sockets
+    and the TLS capability probe both go through LIB."""
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", f"tests/{test_file}", "-q",
+         "-p", "no:cacheprovider", "-p", "no:randomly"],
+        capture_output=True, text=True, timeout=300, cwd=REPO,
+        env={**{k: v for k, v in os.environ.items()
+                if not k.startswith("PYTEST_")},
+             "JAX_PLATFORMS": "cpu", "VPROXY_TPU_FD_PROVIDER": "py"})
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-1000:]
+    assert " passed" in r.stdout and "failed" not in r.stdout, r.stdout[-500:]
 
 
 def test_python_pump_splices_and_reports_done():

@@ -2,8 +2,7 @@
 
 The tier-1 `storm` smoke runs a scaled-down flash crowd (~seconds,
 structural assertions only — SLO differentials need full-scale load and
-are asserted by the slow-marked full run + the committed
-BENCH_r10_builder_storm.json). The stale-leader catch-up test covers
+are asserted by the slow-marked full run). The stale-leader catch-up test covers
 the cluster-plane fix the rolling-upgrade scenario forced: a restarted
 lowest-id node must pull the fleet's state, not lead with its own
 empty one.

@@ -188,3 +188,29 @@ def test_snapshot_row_shape():
                          "baselined", "open", "stale_baseline",
                          "elapsed_s"}
     assert snap["open"] == 0
+
+
+# ------------------------------------------- documents vs. code: knobs
+
+def test_documented_knobs_are_read_by_the_code():
+    """Every VPROXY_TPU_* name the README, docs/ or main.py's help text
+    mention is read from the environment somewhere under vproxy_tpu/ or
+    tools/ — a retired knob must not survive in prose."""
+    import glob
+    import re
+    name = re.compile(r"VPROXY_TPU_[A-Z0-9_]+")
+    read = re.compile(r"(?:environ(?:\.get)?\s*[\[(]|getenv\s*\()\s*"
+                      r"[\"'](VPROXY_TPU_[A-Z0-9_]+)[\"']")
+    code = set()
+    for top in ("vproxy_tpu", "tools"):
+        for dirpath, _, files in os.walk(os.path.join(ROOT, top)):
+            for f in files:
+                if f.endswith((".py", ".cpp")):
+                    with open(os.path.join(dirpath, f)) as fh:
+                        code |= set(read.findall(fh.read()))
+    assert len(code) > 50  # the read pattern still matches the idiom
+    for doc in ["README.md", os.path.join("vproxy_tpu", "main.py"),
+                *sorted(glob.glob("docs/*.md", root_dir=ROOT))]:
+        with open(os.path.join(ROOT, doc)) as fh:
+            unread = sorted(set(name.findall(fh.read())) - code)
+        assert not unread, f"{doc} names knobs nothing reads: {unread}"

@@ -245,7 +245,7 @@ def run(clients: int = 4, requests: int = 120, payload_len: int = 4096,
     report["success_rate"] = ok / total if total else 0.0
     report["pool_size"] = pool_size
     # chaos runs under VPROXY_TPU_TRACE_SAMPLE dump their worst traces
-    # like the bench --trace stage (docs/observability.md)
+    # like the storm suite (docs/observability.md)
     from vproxy_tpu.utils import trace as TR
     if TR.enabled():
         report["slowest_traces"] = TR.slowest(8)

@@ -13,7 +13,7 @@ family): every sampling site gets its own `random.Random(f"{seed}:
 <site>")` stream, string seeds hash by VALUE in CPython, so the same
 (model, seed) pair produces a byte-identical schedule in every process
 — `schedule_hash` (sha256 over the canonical JSON) is echoed into the
-replay report and BENCH rows, and two same-seed runs MUST agree on it.
+replay report, and two same-seed runs MUST agree on it.
 
 The fidelity gate closes the loop: replayed clients bind distinct
 loopback source addresses (one_session `src_ip`), so the analytics

@@ -3,8 +3,8 @@
 Chaos (tools/chaos.py) proves the fleet survives component DEATH;
 production traffic fails uglier. This harness drives five adversarial
 workloads against live components, each scored by explicit pass/fail
-SLO gates that ride into the BENCH artifact
-(`BENCH_r10_builder_storm.json`, `bench_host.py --storm`):
+SLO gates that ride into the report (`--out`); this file's own main()
+is the one way to run it:
 
   flash_crowd      a 10x client-concurrency step against a TcpLB on a
                    single worker loop. Runs TWICE at identical load —
@@ -49,7 +49,7 @@ load shape (the tier-1 `storm` smoke runs at a fraction; full scenarios
 are `slow`-marked). `--only <name>` runs one scenario.
 
 Run: env JAX_PLATFORMS=cpu python tools/storm.py [--seed N] [--scale X]
-     [--only name] [--out BENCH_r10_builder_storm.json]
+     [--only name] [--out report.json]
 """
 from __future__ import annotations
 
@@ -1176,9 +1176,9 @@ def run_all(seed: int = 0, scale: float = 1.0, only: str = None,
                                          "vproxy_udp_drop_total",
                                          "vproxy_cluster_",
                                          "vproxy_trace_"))}
-    # storm runs under VPROXY_TPU_TRACE_SAMPLE dump their worst traces
-    # like the bench --trace stage: the slowest sampled requests of an
-    # adversarial run, attribution included, right in the artifact
+    # storm runs under VPROXY_TPU_TRACE_SAMPLE dump their worst traces:
+    # the slowest sampled requests of an adversarial run, attribution
+    # included, right in the report (tools/traceview.py renders them)
     from vproxy_tpu.utils import trace as TR
     if TR.enabled():
         report["slowest_traces"] = TR.slowest(8)
@@ -1200,8 +1200,7 @@ def main(argv=None) -> int:
                     help="shrink/grow every scenario's load shape")
     ap.add_argument("--only", choices=sorted(SCENARIOS), default=None)
     ap.add_argument("--out", default=None,
-                    help="also write the report JSON here (the BENCH "
-                    "artifact, e.g. BENCH_r10_builder_storm.json)")
+                    help="also write the report JSON here")
     args = ap.parse_args(argv)
     report = run_all(seed=args.seed, scale=args.scale, only=args.only,
                      log=lambda m: print(f"[storm] {m}", file=sys.stderr))

@@ -1,15 +1,15 @@
 #!/usr/bin/env python
-"""traceview — offline text waterfalls for committed trace artifacts.
+"""traceview — offline text waterfalls for saved traces.
 
-Reads the `bench.py --trace` stage's artifact (BENCH_r*_trace.json:
-`slowest_traces` = [{"trace": id, "total_us": ..., "spans": [...]}]),
-a `GET /trace?id=` dump ({"trace": id, "spans": [...]}) or a bare
-span list, and renders the same per-span waterfall the live
-`trace <id>` command shows — so a committed BENCH round's worst
+Reads a `tools/storm.py --out` / `tools/chaos.py` report run under
+VPROXY_TPU_TRACE_SAMPLE (`slowest_traces` = [{"trace": id, "total_us":
+..., "spans": [...]}]), a `GET /trace?id=` dump ({"trace": id,
+"spans": [...]}) or a bare span list, and renders the same per-span
+waterfall the live `trace <id>` command shows — so a run's worst
 requests stay inspectable without a live process.
 
-    python tools/traceview.py BENCH_r13_builder_trace.json
-    python tools/traceview.py BENCH_r13_builder_trace.json --id 42
+    python tools/traceview.py report.json
+    python tools/traceview.py report.json --id 42
     curl -s lb:18776/trace?id=42 | python tools/traceview.py -
 
 The attribution table (per-stage p50/p99) is printed when the artifact

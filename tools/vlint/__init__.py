@@ -166,8 +166,8 @@ def run_all(root: Optional[str] = None,
 
 
 def snapshot(report: Report) -> dict:
-    """The bench.py `static_analysis` artifact row: finding counts by
-    pass + baseline totals, so the trajectory artifacts show drift."""
+    """The `python -m tools.vlint --json` row: finding counts by pass
+    + baseline totals."""
     return {
         "findings_by_pass": dict(sorted(report.counts.items())),
         "findings_total": len(report.findings),

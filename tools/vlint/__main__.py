@@ -2,7 +2,7 @@
 
 Exit codes: 0 clean (baselined findings allowed), 1 open findings or
 stale baseline entries, 2 the analyzer itself failed. `--json` emits
-the bench.py snapshot row; `--all` lists baselined findings too;
+the snapshot row (counts by pass); `--all` lists baselined findings too;
 `--no-baseline` shows the raw findings (the triage view).
 """
 from __future__ import annotations

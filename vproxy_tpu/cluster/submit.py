@@ -194,9 +194,8 @@ class StepLoop:
     def _fused_live(self) -> bool:
         """True only when the NEXT step would actually dispatch fused:
         a maglev plane is configured AND the current publishes carry
-        the packed tables + maglev column (VPROXY_TPU_FUSED=0, a
-        non-"jax" backend, or a pre-fused publish all fall back to the
-        two-dispatch chain — status must say so, not report the
+        the packed tables + maglev column (a non-"jax" backend or a
+        pre-fused publish falls back to the two-dispatch chain — status must say so, not report the
         config)."""
         if self._pair is None:
             return False

@@ -1,9 +1,12 @@
-// hostbench — epoll HTTP/1.1 load tool for the host-path req/s bench.
+// hostbench — epoll HTTP/1.1 load tool for the host path (req/s).
 //
 // The framework's TcpLB data path is the native splice pump
 // (vtl.cpp:342-537); measuring it through Python clients would measure
-// the GIL instead. This file provides the two native endpoints of the
-// harness (bench_host.py owns orchestration):
+// the GIL instead. This file provides the two native endpoints of a
+// load harness. Nothing in the tree builds or runs it today (the
+// pre-chip host harness that did is gone); its next caller is the
+// serving-plane driver of ROADMAP R1/R12, and it goes if that driver
+// lands without it. Build: g++ -O2 -o hostbench hostbench.cpp -ldl
 //
 //   hostbench server <port>
 //       single-thread epoll HTTP server: reads until CRLFCRLF, writes a

@@ -33,8 +33,26 @@ EV_PUMP_DONE = 8
 AGAIN = -errno.EAGAIN
 
 
+def _stale() -> bool:
+    src = os.path.join(_DIR, "vtl.cpp")
+    return not os.path.exists(_SO) or (
+        os.path.exists(src)
+        and os.path.getmtime(src) > os.path.getmtime(_SO))
+
+
 def _build() -> None:
-    subprocess.run(["make", "-s"], cwd=_DIR, check=True)
+    """make libvtl.so if it is missing or older than vtl.cpp. Processes
+    that import at once (pytest-xdist workers in a fresh checkout)
+    queue on an exclusive lock and re-check under it, so one compiles
+    and the rest find the finished file; the Makefile renames the .so
+    into place, so a reader outside the lock never maps a partial one."""
+    if not _stale():
+        return
+    import fcntl
+    with open(os.path.join(_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _stale():
+            subprocess.run(["make", "-s"], cwd=_DIR, check=True)
 
 
 def _load() -> ctypes.CDLL:
@@ -47,11 +65,7 @@ def _load() -> ctypes.CDLL:
     if override:
         lib = ctypes.CDLL(override)
     else:
-        src = os.path.join(_DIR, "vtl.cpp")
-        if not os.path.exists(_SO) or (
-                os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(_SO)):
-            _build()
+        _build()
         lib = ctypes.CDLL(_SO)
     c = ctypes.c_int
     p = ctypes.c_void_p
@@ -506,7 +520,7 @@ if os.environ.get("VPROXY_TPU_FDTRACE", "") == "1":
 
 def tls_available() -> bool:
     """Native TLS pump usable? (native provider + libssl resolvable)."""
-    if LIB is None:
+    if PROVIDER != "native":
         return False
     return LIB.vtl_tls_init() == 0
 

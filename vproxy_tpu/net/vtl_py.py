@@ -605,6 +605,15 @@ class PyLib:
     def vtl_poll(self, lp, tags_buf, evs_buf, cap, timeout_ms) -> int:
         return lp.poll(tags_buf, evs_buf, cap, timeout_ms)
 
+    def vtl_set_rcvbuf(self, fd, nbytes) -> int:
+        s = _socks.get(fd)
+        try:
+            if s is not None:
+                s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, nbytes)
+        except OSError:
+            pass
+        return 0
+
     def vtl_pump_new(self, lp, fd_a, fd_b, bufsize) -> int:
         return lp.pump_new(fd_a, fd_b, bufsize)
 

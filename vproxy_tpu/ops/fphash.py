@@ -1,6 +1,6 @@
 """Fingerprint-verified single-probe hash kernels — the gather-lean path.
 
-Round-3 measurement (PERF_NOTES.md) showed this device's cost model is
+A round-3 sandbox measurement (no ledger line) showed a cost model
 dominated by GATHERED-ROW COUNT: ~7ns per gathered row regardless of
 dtype/table size, with wide rows nearly free, while elementwise math and
 matmuls are orders of magnitude cheaper. The cuckoo kernels in
@@ -524,7 +524,7 @@ def default_member_mode() -> str:
 
     The round-4 fast variants (argmax+take_along entry select;
     equality-mask einsum member eval) both diverged from the oracle in
-    plain-jit context on that earlier attachment (PERF_NOTES.md §7,
+    plain-jit context on that earlier attachment (round-4 notes,
     three sightings: one-hot select, einsum/dot one-hot,
     argmax+take_along). Those sightings are UNTESTED on today's
     backend (jax 0.9.0 / libtpu on the directly attached chip). These
@@ -770,7 +770,7 @@ def _prune_acl_members(items: list, acl) -> list:
 # bits (_expand_patterns), so the whole V4 side compresses into a 16/8/8
 # direct-index trie: 3 scalar gathers per query instead of one wide row
 # gather per (query, mask-group). Under the measured ~7ns/gathered-row
-# cost model (PERF_NOTES.md) that turns the 0.10-0.26us per-query group
+# cost model (module docstring) that turns the 0.10-0.26us per-query group
 # scan into ~0.02us. Semantics are exact: each cell resolves to the
 # FIRST-matching rule in list order (min index among covering patterns)
 # — route mode paints cells in descending rule order so the lowest index

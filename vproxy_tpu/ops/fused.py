@@ -1,9 +1,9 @@
 """Fused classify+pick dispatch — one launch, one memory sweep per batch.
 
-The round-8 cost model (PERF_NOTES) showed the dispatch chain — FNV
-hash, cuckoo probe, hint gather, verdict resolve, Maglev pick — riding
-~5 separate XLA dispatches per batch, so every batch paid multiple
-launch overheads and multiple passes over the tables. Pope et al.
+The unfused dispatch chain — FNV hash, cuckoo probe, hint gather,
+verdict resolve, Maglev pick — rides several XLA dispatches per batch,
+so every batch pays multiple launch overheads and multiple passes over
+the tables. Pope et al.
 (MLSys'23) is the template: fixed-shape batches amortize launch
 overhead only when the per-batch work is ONE fused program, and Maglev
 (Eisenbud, NSDI'16) makes the pick table just another gather that
@@ -20,10 +20,7 @@ Two layers live here:
   gathers instead of the nine separate-array gathers the unfused
   kernel pays. The cuckoo slot side packs the same way: (used/klen,
   bucket_start, bucket_count) co-locate in one int32 row per slot
-  (`pk_hslot`/`pk_uslot`), halving the probe gathers. The cidr table
-  needs no packed copy: its slot rows carry their buckets already
-  (hashmatch `b_rows`), so the fused program runs `cidr_hash_match`
-  on the matcher's own device arrays.
+  (`pk_hslot`/`pk_uslot`), halving the probe gathers.
   Packing is pure vectorized numpy and runs INSIDE the matcher's
   standby compile (rules/engine.py), so packed generations publish
   through the same double-buffered TableInstaller swap as everything
@@ -31,41 +28,23 @@ Two layers live here:
 
 * **The fused kernel** (`fused_classify_pick` / `fused_jit`): one
   jitted program taking the encoded query batch plus the published
-  snapshot's packed tables (hint, optional cidr/LPM, Maglev column)
-  and returning (verdict, pick[, route]) stacked [B, 2|3] — one XLA
-  launch, one d2h transfer per batch. Verdicts are bit-identical to
+  snapshot's packed hint table and Maglev column and returning
+  (verdict, pick) stacked [B, 2] — one XLA launch, one d2h transfer
+  per batch. Verdicts are bit-identical to
   `hashmatch.hint_hash_match` (same formulas, same i32 packing
   reduction; only the gather layout changed) and picks bit-identical
   to `maglev._device_take` (same host-side FNV slots, same clipped
   take). tests/test_fused.py proves both on randomized 100k-rule
   tables.
-
-A Pallas implementation of the same contract lives in
-ops/fused_pallas.py, served only under an explicit
-VPROXY_TPU_FUSED_KERNEL=pallas (rules/engine._fused_fn re-reads the
-knob per dispatch, so a change mid-process never serves a stale
-program — the PR-6 stale-mesh family of bug).
 """
 from __future__ import annotations
-
-import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import cuckoo as CK
-from .hashmatch import DOT, HOST_SHIFT, cidr_hash_match
-
-def kernel_mode() -> str:
-    """VPROXY_TPU_FUSED_KERNEL: "jit" (default — the fused XLA program,
-    the tier that serves on every platform) or "pallas" (the
-    ops/fused_pallas.py kernel: interpret-mode on CPU when
-    VPROXY_TPU_PALLAS_INTERPRET=1; anywhere the kernel cannot compile,
-    the first dispatch raises). Re-read per call: jit statics must
-    honor mid-process changes."""
-    return os.environ.get("VPROXY_TPU_FUSED_KERNEL", "jit")
+from .hashmatch import DOT, HOST_SHIFT
 
 
 # ------------------------------------------------------------- packing
@@ -86,7 +65,7 @@ def pack_hint_table(a: dict) -> dict:
     the sweep — probe tables, uri byte columns, wildcard list — is
     OMITTED from the packed dict entirely. The dict's key set is part
     of the jit trace structure, so the compiled program for such a
-    table simply has no uri work in it (the 1M bench shape is pure
+    table simply has no uri work in it (lb-host10k's table is pure
     host rules; this is where its sweep bytes go)."""
     r_cap = a["r_active"].shape[0]
     hw = a["r_host"].shape[1]
@@ -245,22 +224,15 @@ def _hint_verdict_packed(t: dict, q: dict):
         return _reduce_best(level, c, r_cap)
 
 
-def fused_classify_pick(ht: dict, q: dict, mtab, slots,
-                        ct: Optional[dict] = None, a16=None, fam=None,
-                        port=None):
-    """THE fused program: hint verdict + Maglev pick (+ optional
-    cidr/LPM route when a cidr table and addr batch ride along)
-    in one compiled launch. -> int32 [B, 2] (verdict, pick) or
-    [B, 3] (verdict, pick, route). `slots` are host-side FNV Maglev
-    slots (the shared hash contract of rules/maglev.py) so the pick
-    column is bit-identical with every other pick plane."""
+def fused_classify_pick(ht: dict, q: dict, mtab, slots):
+    """THE fused program: hint verdict + Maglev pick in one compiled
+    launch. -> int32 [B, 2] (verdict, pick). `slots` are host-side FNV
+    Maglev slots (the shared hash contract of rules/maglev.py) so the
+    pick column is bit-identical with every other pick plane."""
     v, _level = _hint_verdict_packed(ht, q)
     with jax.named_scope("maglev_pick"):
         p = jnp.take(mtab, slots, mode="clip").astype(jnp.int32)
-    cols = [v, p]
-    if ct is not None:
-        cols.append(cidr_hash_match(ct, a16, fam, port))
-    return jnp.stack(cols, axis=1)
+    return jnp.stack([v, p], axis=1)
 
 
 fused_jit = jax.jit(fused_classify_pick)

@@ -296,7 +296,7 @@ _FNV64_OFFSET_I = CK._FNV64_OFFSET_I
 # per-call overhead dwarfs the math on accept-path-sized batches
 # (measured 309us numpy vs ~60us python at b=8, 20k rules). The
 # PR-6 crossover of 32 was measured against the 5-op dispatch chain;
-# re-measured under the fused dispatch (PERF_NOTES round 12, both 20k
+# re-measured under the fused dispatch (round 12, sandbox CPU, both 20k
 # and 200k tables) the python path's advantage ends at ~28 (b=24: 268
 # vs 316us; b=28: 324 vs 328us; b=30: 328 vs 318us; b=32: 573 vs
 # 346us) — the fused launch removed enough dispatch overhead that

@@ -13,10 +13,10 @@ the reference's provider SPI (-Dvfd, FDProvider.java:12-45) as
                 of hash behavior), gather-bound.
 * "jax-fp"    — packed fingerprint kernels (ops/fphash): ~25x fewer
                 gathered rows per query than "jax" (the measured cost
-                driver, PERF_NOTES.md). Exact for every key in the
-                table; a query key NOT in the table can false-positive
-                with probability 2^-64 per probe. The throughput path —
-                bench.py's 100k-rule TPU numbers ride this backend.
+                driver in an earlier cost model). Exact for every key
+                in the table; a query key NOT in the table can
+                false-positive with probability 2^-64 per probe. No
+                benchmark cell has run it on the chip yet.
 * "jax-dense" — the dense matmul kernels (ops/matchers): O(rules) MXU
                 work per query; kept as the brute-force cross-check and
                 for rule-axis mesh sharding experiments.
@@ -495,53 +495,21 @@ def flush_installs(timeout: Optional[float] = None) -> bool:
 # ops/fused.py packs the compiled hash tables into int8/int32 layouts
 # (one meta row + one byte row per rule, one slot row per cuckoo slot)
 # and compiles the whole dispatch chain — probe, gather, verdict
-# resolve, Maglev pick, optionally the cidr/LPM walk — into ONE jitted
-# program. The packed arrays are built INSIDE the matcher's standby
+# resolve, Maglev pick — into ONE jitted program. A "jax"-backend hint
+# matcher always publishes them; every other backend serves the
+# two-dispatch chain (fused_dispatch -> None). The packed arrays are built INSIDE the matcher's standby
 # compile below, so they publish through the same TableInstaller
 # atomic-swap as every other table: a fused reader can never pair one
 # generation's probe salts with another's packed records.
 
-def fused_enabled() -> bool:
-    """VPROXY_TPU_FUSED (default on): build packed tables on "jax"
-    matchers and serve classify+pick from the fused one-launch entry.
-    Off restores the overlapped two-dispatch chain (the A/B lever)."""
-    return os.environ.get("VPROXY_TPU_FUSED", "1") != "0"
-
-
-def _fused_fn():
-    """The fused entry for the CURRENT knob state, chosen BY NAME
-    (fused.kernel_mode, re-read per dispatch so a knob change
-    mid-process never serves a stale program): no capability probe runs
-    on the serving path, so an explicit "pallas" that the platform
-    cannot compile raises at its first dispatch instead of quietly
-    serving the jit tier."""
-    from ..ops import fused as F
-    mode = F.kernel_mode()
-    if mode == "jit":
-        return F.fused_jit
-    if mode == "pallas":
-        from ..ops import fused_pallas as FP
-        return FP.fused_classify_pick_pallas
-    raise ValueError(f"VPROXY_TPU_FUSED_KERNEL={mode!r}: "
-                     "expected 'jit' or 'pallas'")
-
-
-def fused_kernel_name() -> str:
-    """Which tier the fused entry serves with ("jit" or "pallas") —
-    surfaced in `list-detail upstream` and the HTTP engine object. The
-    configured name IS the serving tier (see _fused_fn), so a stat read
-    on the control thread costs one env lookup."""
-    from ..ops import fused as F
-    return "pallas" if F.kernel_mode() == "pallas" else "jit"
-
-
 def _fused_stat(fd: Optional[dict]) -> dict:
     """Fused-dispatch state for the operator surfaces (list-detail
     upstream / HTTP engine object) — ONE shape for both matcher kinds:
-    packed-table availability, device bytes, serving kernel tier."""
+    packed-table availability, device bytes, and the serving kernel
+    (the constant "jit": ops/fused.fused_jit is the one tier)."""
     if fd is None:
         return {"available": False}
-    return {"available": True, "kernel": fused_kernel_name(),
+    return {"available": True, "kernel": "jit",
             "packed_bytes": int(sum(getattr(v, "nbytes", 0)
                                     for v in fd.values()))}
 
@@ -554,8 +522,8 @@ def fused_dispatch(hm, hsnap: tuple, mm, msnap: tuple, hints,
     queries + host-side Maglev slots into the fused program against
     one (hint, maglev) snapshot pair. Returns the async int32 [B, 2]
     device array, or None when the fused path is unavailable for
-    these snapshots (non-"jax" backend, VPROXY_TPU_FUSED=0, or a
-    pre-fused publish) — callers fall back to the two-dispatch chain."""
+    these snapshots (non-"jax" backend or a pre-fused publish) —
+    callers fall back to the two-dispatch chain."""
     if not hints or len(hints) != len(ips):
         return None
     fd = hsnap[5] if len(hsnap) > 5 else None
@@ -568,42 +536,10 @@ def fused_dispatch(hm, hsnap: tuple, mm, msnap: tuple, hints,
     q = _fused_hint_q(hsnap[0], hints, pad_to)
     cap = q["hostb"].shape[0]
     slots = _fused_slots(mtab, ips, ports, cap)
-    fn = _fused_fn()
-    _FUSED_DISP[0] += 1
-    with launch_span("cpick", cap, fused=True):
-        return fn(fd, q, mdev, slots)
-
-
-def fused_dispatch_all(hm, hsnap: tuple, cm, csnap: tuple, mm,
-                       msnap: tuple, hints, addrs: Sequence[bytes],
-                       ips: Sequence[bytes],
-                       ports: Optional[Sequence[int]] = None,
-                       pad_to: Optional[int] = None):
-    """The full fused sweep: hint verdict + cidr/LPM route + Maglev
-    pick, one launch, int32 [B, 3] (verdict, pick, route). Route
-    queries carry no ACL port gate (route-table semantics, ports=None
-    in CidrMatcher.dispatch_snap). Always the jit tier — the Pallas
-    kernel covers the (verdict, pick) serving contract; the 3-column
-    form is the bench/step-loop shape. None when either packed table
-    is missing (fallback: the op chain)."""
-    if not hints or len(hints) != len(addrs) or len(hints) != len(ips):
-        return None
-    fd = hsnap[5] if len(hsnap) > 5 else None
-    cfd = csnap[6] if len(csnap) > 6 else None
-    if fd is None or cfd is None or not hsnap[2] or not csnap[1]:
-        return None
-    mtab, mdev = msnap[0], msnap[1]
-    if mtab is None or mdev is None:
-        return None
-    note_serving()
-    q = _fused_hint_q(hsnap[0], hints, pad_to)
-    cap = q["hostb"].shape[0]
-    slots = _fused_slots(mtab, ips, ports, cap)
-    a16, fam, _p = _encode_addrs(addrs, None, cap, items=0)
     from ..ops import fused as F
     _FUSED_DISP[0] += 1
-    with launch_span("all", cap, fused=True):
-        return F.fused_jit(fd, q, mdev, slots, cfd, a16, fam, None)
+    with launch_span("cpick", cap, fused=True):
+        return F.fused_jit(fd, q, mdev, slots)
 
 
 def _fused_hint_q(tab, hints, pad_to: Optional[int]) -> dict:
@@ -783,7 +719,7 @@ class HintMatcher:
         # standby compile and published in the SAME atomic tuple swap —
         # the fused reader's generation consistency is the pub tuple's
         fused_dev = None
-        if self.backend == "jax" and fused_enabled():
+        if self.backend == "jax":
             from ..ops import fused as F
             fused_dev = _to_device(F.pack_hint_table(self._tab.arrays))
         _install_phase(itid, "compile", t_ph, matcher="hint",
@@ -1097,10 +1033,6 @@ class CidrMatcher:
         if len(self._nets) > SMALL_TABLE:  # every backend: see HintMatcher
             from .index import CidrIndex
             idx = CidrIndex(self._nets, acl=self._acl)
-        # the fused program reads the cidr table's own arrays (its slot
-        # rows carry their buckets: nothing to pack, no second upload)
-        fused_dev = self._dev if self.backend == "jax" and fused_enabled() \
-            else None
         _install_phase(itid, "compile", t_ph, matcher="cidr",
                        rules=len(self._nets))
         t_ph = time.monotonic_ns() if itid else 0
@@ -1110,17 +1042,12 @@ class CidrMatcher:
         t_ph = time.monotonic_ns() if itid else 0
         self._pub = (self._dev, list(self._nets),
                      None if self._acl is None else list(self._acl),
-                     self._payload, self._tab, idx, fused_dev)
+                     self._payload, self._tab, idx)
         self.generation += 1
         with _gen_lock:
             _GENERATION[0] += 1
         _install_phase(itid, "swap", t_ph, matcher="cidr",
                        generation=self.generation)
-
-    def fused_stat(self) -> dict:
-        """See engine._fused_stat — packed cidr-table state."""
-        pub = self._pub
-        return _fused_stat(pub[6] if len(pub) > 6 else None)
 
     def match(self, addrs: Sequence[bytes],
               ports: Optional[Sequence[int]] = None) -> np.ndarray:

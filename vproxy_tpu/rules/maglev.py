@@ -373,7 +373,7 @@ def classify_and_pick(hint_matcher, maglev: MaglevMatcher, hints,
     from the hint matcher and backend picks from the maglev table
     against one atomic snapshot pair. On a "jax" matcher with packed
     tables published (the default) this is the FUSED one-launch
-    program (rules/engine.fused_dispatch — PERF_NOTES round 12); other
+    program (rules/engine.fused_dispatch); other
     backends keep the pre-r12 overlapped two-dispatch submit. ->
     (verdicts int32[B], picks int32[B], hint_payload, maglev_payload)."""
     from . import engine as E

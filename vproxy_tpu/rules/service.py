@@ -82,7 +82,7 @@ batch's encode overlaps the previous batch's device compute, and the
 previous result is pulled (one host round trip per batch) just before
 delivery. A straggler that missed batch k no longer waits out k's
 full round trip before k+1 even starts; that wait was THE
-service_device_p99 driver (BENCH_r06). Mesh-SHARDED backends instead
+service_device_p99 driver (round 6). Mesh-SHARDED backends instead
 submit synchronously (see _device_submit): their per-dispatch cost is
 fixed and high, so parking the dispatcher through the round trip —
 the "natural batching" window above — beats the overlap (A/B'd).
@@ -803,7 +803,7 @@ class ClassifyService:
             raise RuntimeError("failpoint device.dispatch.error")
         n = len(reqs)
         cap = pad_batch(n, lo=PAD_LO)
-        # dispatch-cost policy (A/B'd, BENCH_r08): cheap single-device
+        # dispatch-cost policy (A/B'd in round 8, sandbox): cheap single-device
         # dispatches PIPELINE (async submit — straggler overlap is the
         # r06->r08 service p99 win, 2.3ms -> 1.5ms), while mesh-sharded
         # dispatches PARK the dispatcher (sync): their fixed

@@ -13,7 +13,7 @@ variable itself; nothing is set in code), otherwise the fixed
 never derived from a temp name, a pid or the clock.
 
 This module imports jax only inside the functions that need it, so a
-supervising parent (bench.py's orchestrator) can use it without ever
+supervising parent (`python -m vproxy_tpu daemon`) can use it without ever
 claiming the accelerator.
 """
 from __future__ import annotations

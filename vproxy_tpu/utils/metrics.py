@@ -689,13 +689,13 @@ class GlobalInspection:
         return worst * 1e6
 
     def bench_snapshot(self) -> dict:
-        """The BENCH-artifact view of /metrics: per-series percentiles
+        """The flat-dict view of /metrics: per-series percentiles
         for every histogram plus raw values for counters/gauges, keyed
         by exposition name with label values folded in
         (vproxy_accept_stage_us{stage="acl"} ->
-        "vproxy_accept_stage_us.acl"). bench.py/bench_host.py/
-        bench_switch.py merge this into the BENCH json so the latency
-        contract and drop rates land in the artifact."""
+        "vproxy_accept_stage_us.acl"). tools/storm.py writes it into its
+        report so the latency contract and drop rates land beside the
+        scenario verdicts; tests read single series from it."""
         with self.registry._lock:
             metrics = list(self.registry._metrics)
         out: Dict[str, object] = {}

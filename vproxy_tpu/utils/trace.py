@@ -77,8 +77,7 @@ trace order consistently.
 
 Surfaces: `GET /trace` (inspection server + HTTP controller),
 `list[-detail] trace` and the bare `trace <id>` line on every command
-surface, `tools/traceview.py` for offline artifacts, and the
-`bench.py --trace` stage committing the per-stage attribution table.
+surface, and `tools/traceview.py` for a saved `GET /trace` body.
 """
 from __future__ import annotations
 
