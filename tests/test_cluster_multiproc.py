@@ -176,6 +176,10 @@ os._exit(0)
 """
 
 
+_PORT_TAKEN = re.compile(
+    r"address already in use|failed to add port to server", re.I)
+
+
 @pytest.mark.timeout(180)
 def test_real_two_process_cluster(tmp_path):
     """Spawns two coordinator-connected jax processes, each a full
@@ -189,12 +193,6 @@ def test_real_two_process_cluster(tmp_path):
         s.close()
         return p
 
-    coord = free_port()
-    sync = free_port()
-    hb = [free_port(socket.SOCK_DGRAM) for _ in range(2)]
-    repl = [free_port() for _ in range(2)]
-    spec = (f"127.0.0.1:{hb[0]}/{repl[0]},"
-            f"127.0.0.1:{hb[1]}/{repl[1]}")
     script = tmp_path / "worker.py"
     script.write_text(_WORKER)
     env = {k: v for k, v in os.environ.items()
@@ -202,22 +200,34 @@ def test_real_two_process_cluster(tmp_path):
            and not k.startswith("VPROXY_TPU_CLUSTER")}
     env["VPROXY_REPO"] = os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))
-    env["COORD_PORT"] = str(coord)
-    env["SYNC_PORT"] = str(sync)
-    env["CLUSTER_SPEC"] = spec
-    procs = [subprocess.Popen(
-        [sys.executable, str(script), str(pid)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
-        for pid in (0, 1)]
-    outs = []
-    try:
-        for p in procs:
-            out, _ = p.communicate(timeout=150)
-            outs.append(out.decode())
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
+    # a probed port is free only until someone else binds it: a sibling
+    # xdist worker can take it before the coordinator does (as in
+    # test_multihost). Retry the whole bring-up on fresh ports.
+    for _attempt in range(3):
+        coord = free_port()
+        sync = free_port()
+        hb = [free_port(socket.SOCK_DGRAM) for _ in range(2)]
+        repl = [free_port() for _ in range(2)]
+        env["COORD_PORT"] = str(coord)
+        env["SYNC_PORT"] = str(sync)
+        env["CLUSTER_SPEC"] = (f"127.0.0.1:{hb[0]}/{repl[0]},"
+                               f"127.0.0.1:{hb[1]}/{repl[1]}")
+        procs = [subprocess.Popen(
+            [sys.executable, str(script), str(pid)],
+            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+            for pid in (0, 1)]
+        outs = []
+        try:
+            for p in procs:
+                out, _ = p.communicate(timeout=150)
+                outs.append(out.decode())
+        finally:
+            for p in procs:
+                if p.poll() is None:
+                    p.kill()
+        if not any(p.returncode and _PORT_TAKEN.search(out)
+                   for p, out in zip(procs, outs)):
+            break
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid} failed:\n{out[-4000:]}"
         assert f"MEMBER_OK pid={pid}" in out, out[-2000:]

@@ -14,7 +14,7 @@ import pytest
 from vproxy_tpu.ops import hashmatch as H
 from vproxy_tpu.ops import tables as T
 from vproxy_tpu.rules import oracle
-from vproxy_tpu.rules.engine import CidrMatcher, HintMatcher
+from vproxy_tpu.rules.engine import CidrMatcher, HintMatcher, pad_batch
 from vproxy_tpu.rules.ir import (AclRule, Hint, HintRule, Proto, RouteRule,
                                  RouteTable)
 from vproxy_tpu.utils.ip import Network, mask_bytes, parse_ip
@@ -69,6 +69,197 @@ def check_hints(rules, hints):
                                 rules[want] if want >= 0 else None)
         if want >= 0:
             assert level[i] == oracle.match_level(h, rules[want])
+    # the same batch written into its pad bucket: the real rows' verdicts
+    # are the unpadded ones, a pad row has no probe and matches nothing
+    cap = pad_batch(len(hints) + 1)
+    pidx, plevel = H.hint_hash_match(
+        tab.arrays, H.encode_hint_queries(hints, tab, pad_to=cap))
+    pidx, plevel = np.asarray(pidx), np.asarray(plevel)
+    assert pidx.shape == (cap,)
+    assert np.array_equal(pidx[:len(hints)], idx)
+    assert np.array_equal(plevel[:len(hints)], level)
+    assert (pidx[len(hints):] == -1).all()
+
+
+# ---- the vectorized encoder against the per-hint form it replaced ----
+#
+# A plain copy of the encoder as it was before the batch became arrays
+# at its first step: a Python walk that fills the byte windows hint by
+# hint, one rolling-FNV pass a salt over the whole window, a stable
+# argsort to compact the probes, and the engine's array-level padding.
+# The served encoder must give these arrays, bit for bit.
+
+_REF_PAD = {"hp_len": -1, "hp_slot1": -1, "hp_slot2": -1,
+            "up_len": -1, "up_slot1": -1, "up_slot2": -1}
+
+
+def _ref_rolling_fnv64(qbytes, salt):
+    b, l = qbytes.shape
+    out = np.empty((b, l + 1), dtype=np.uint64)
+    h = np.full(b, H.CK.FNV64_OFFSET ^ np.uint64(salt), dtype=np.uint64)
+    out[:, 0] = h
+    with np.errstate(over="ignore"):
+        for p in range(l):
+            h = (h ^ qbytes[:, p].astype(np.uint64)) * H.CK.FNV64_PRIME
+            out[:, p + 1] = h
+    return out
+
+
+def _ref_encode(hints, tab, pad_to=0):
+    b, W, uw = len(hints), tab.hw, tab.uw
+    q_hostb = np.zeros((b, W), np.uint8)
+    q_hlen = np.zeros(b, np.int32)
+    q_has_host = np.zeros(b, bool)
+    q_urib = np.zeros((b, uw), np.uint8)
+    q_ulen = np.zeros(b, np.int32)
+    q_has_uri = np.zeros(b, bool)
+    q_port = np.zeros(b, np.int32)
+    for i, h in enumerate(hints):
+        if h.host is not None:
+            hb = h.host.encode()[::-1]
+            q_hlen[i] = min(len(hb), 1 << 20)
+            q_hostb[i, : min(len(hb), W)] = np.frombuffer(hb[:W], np.uint8)
+            q_has_host[i] = True
+        if h.uri is not None:
+            ub = h.uri.encode()
+            q_ulen[i] = min(len(ub), 1 << 20)
+            q_urib[i, : min(len(ub), uw)] = np.frombuffer(ub[:uw], np.uint8)
+            q_has_uri[i] = True
+        q_port[i] = h.port
+    h1 = _ref_rolling_fnv64(q_hostb[:, : W - 1], tab.host_salts[0])
+    h2 = _ref_rolling_fnv64(q_hostb[:, : W - 1], tab.host_salts[1])
+    pos = np.arange(W)[None, :]
+    probe_ok = np.concatenate([
+        (q_hostb == H.DOT) & (pos < q_hlen[:, None]) & (pos >= 1),
+        (q_has_host & (q_hlen <= W - 1))[:, None],
+    ], axis=1) & q_has_host[:, None]
+    probe_len = np.concatenate([
+        np.broadcast_to(pos, (b, W)), q_hlen[:, None]], axis=1
+    ).astype(np.int32)
+    need = int(probe_ok.sum(axis=1).max(initial=0))
+    maxp = next((t for t in H.MAXP_TIERS if t >= need), H.MAXP_TIERS[-1])
+    order = np.argsort(~probe_ok, axis=1, kind="stable")[:, :maxp]
+    pv = np.take_along_axis(probe_ok, order, 1)
+    pl = np.where(pv, np.take_along_axis(probe_len, order, 1), 0)
+    mask = np.uint64(tab.host_cap - 1)
+
+    def slots(hh, at, ok, m):
+        return np.where(
+            ok, (np.take_along_axis(hh, at, 1) & m).astype(np.int32), -1)
+
+    lset = np.full(tab.caps["lset"], -1, np.int32)
+    lset[: len(tab.lset)] = tab.lset
+    u1 = _ref_rolling_fnv64(q_urib, tab.uri_salts[0])
+    u2 = _ref_rolling_fnv64(q_urib, tab.uri_salts[1])
+    lv = (lset[None, :] >= 0) & (lset[None, :] <= q_ulen[:, None]) & \
+        q_has_uri[:, None]
+    ll = np.where(lv, np.maximum(lset[None, :], 0), 0)
+    umask = np.uint64(tab.uri_cap - 1)
+    q = {
+        "hostb": q_hostb, "hlen": q_hlen, "has_host": q_has_host,
+        "urib": q_urib, "ulen": q_ulen, "has_uri": q_has_uri,
+        "port": q_port,
+        "hp_len": np.where(pv, pl, -1).astype(np.int32),
+        "hp_slot1": slots(h1, pl, pv, mask),
+        "hp_slot2": slots(h2, pl, pv, mask),
+        "up_len": np.where(lv, ll, -1).astype(np.int32),
+        "up_slot1": slots(u1, ll, lv, umask),
+        "up_slot2": slots(u2, ll, lv, umask),
+    }
+    if pad_to > b:  # the engine's _pad_hint_q with _PAD_CUCKOO
+        q = {k: np.concatenate([v, np.full((pad_to - b,) + v.shape[1:],
+                                           _REF_PAD.get(k, 0), v.dtype)])
+             for k, v in q.items()}
+    return q
+
+
+def _assert_same_arrays(got, want):
+    assert set(got) == set(want)
+    for k in want:
+        assert got[k].dtype == want[k].dtype, k
+        assert got[k].shape == want[k].shape, (k, got[k].shape,
+                                               want[k].shape)
+        assert np.array_equal(got[k], want[k]), k
+
+
+def _encode_table(hw):
+    """A table whose host window is `hw` (16: a 15-byte rule host; 65:
+    a 64-byte one) and whose uris give a length set with gaps."""
+    top = "b" * (hw - 1 - 4) + ".com" if hw == 16 else \
+        "a" * 31 + "." + "b" * 32
+    rules = [HintRule(host=top), HintRule(host="a.com", uri="/x"),
+             HintRule(host="a.com", uri="/xy/z"), HintRule(uri="/static"),
+             HintRule(uri=""), HintRule(host="*", uri="/w"),
+             HintRule(host="com", port=443), HintRule(uri="*")]
+    tab = H.compile_hint_hash(rules)
+    assert tab.hw == hw and len(tab.lset) >= 4
+    return tab, top
+
+
+def _encode_corpus(kind, n, tab, top):
+    """n seeded hints. `mixed` holds every edge the fill and the probe
+    walk have; the other two are batches whose hash walk is cut to
+    nothing (no host at all) or to one column (1-byte hosts)."""
+    r = random.Random(f"{kind}/{n}/{tab.hw}")
+    uris = [None, "", "/", "/x", "/xy/z/more", "/static/éé",
+            "/w\x00w", "/" + "u" * (tab.uw + 3), "*"]
+    if kind == "uri-only":
+        return [Hint(uri=r.choice(uris), port=r.choice([0, 443]))
+                for _ in range(n)]
+    if kind == "one-byte-hosts":
+        return [Hint(host=r.choice(["a", ".", "\x00", "", None]),
+                     uri=r.choice(uris[:4])) for _ in range(n)]
+    hw = tab.hw
+    edge = []
+    for length in range(hw - 2, hw + 3):
+        # as long as the window, one less, one more...: where the host
+        # reaches reversed position hw-1, that byte is a dot
+        if length >= hw:
+            edge.append("x" * (length - hw) + "." + top)
+        else:
+            edge.append(top[hw - 1 - length:])
+    fixed = edge + [
+        None, "", top, "q" + top, ".a.com", "a.com.", "a..com", ".", "..",
+        "a\x00b.com", "\x00", "*", "q.*",
+        # multi-byte utf-8, cut by the window edge at every phase
+        "é" * hw, "x" + "é" * hw, "中" * hw + ".com",
+        "😀" * (hw // 2) + ".a.com", "é.a.com",
+        "x" * 300 + ".com", ".".join("ab" for _ in range(40)),
+    ]
+    out = []
+    for i in range(n):
+        host = fixed[i] if i < len(fixed) else (
+            rand_domain() if r.random() < 0.8 else r.choice(fixed))
+        out.append(Hint(host=host, port=r.choice([0, 80, 443]),
+                        uri=r.choice(uris)))
+    r.shuffle(out)
+    return out
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["bare", "padded"])
+@pytest.mark.parametrize("n", [29, 96, 1500])
+@pytest.mark.parametrize("kind", ["mixed", "uri-only", "one-byte-hosts"])
+@pytest.mark.parametrize("hw", [16, 65])
+def test_encode_hint_queries_equals_per_hint_form(hw, kind, n, padded):
+    tab, top = _encode_table(hw)
+    hints = _encode_corpus(kind, n, tab, top)
+    assert n > H.SMALL_ENCODE  # the vectorized path is the one under test
+    cap = pad_batch(n + 1) if padded else 0
+    _assert_same_arrays(H.encode_hint_queries(hints, tab, pad_to=cap),
+                        _ref_encode(hints, tab, pad_to=cap))
+
+
+@pytest.mark.parametrize("n", [29, 96])
+@pytest.mark.parametrize("kind", ["mixed", "uri-only", "one-byte-hosts"])
+@pytest.mark.parametrize("hw", [16, 65])
+def test_small_encoder_equals_vectorized(hw, kind, n):
+    """_encode_hint_queries_small says "bit-identical": held here, on
+    the corpus above, bare and padded."""
+    tab, top = _encode_table(hw)
+    hints = _encode_corpus(kind, n, tab, top)
+    for cap in (n, pad_batch(n + 1)):
+        _assert_same_arrays(H._encode_hint_queries_small(hints, tab, cap),
+                            H.encode_hint_queries(hints, tab, pad_to=cap))
 
 
 def test_hint_hash_parity_random():

@@ -88,39 +88,25 @@ def fnv64(key: bytes, salt: int) -> np.uint64:
     return np.uint64(h)
 
 
-def rolling_fnv64(qbytes: np.ndarray, salt: int) -> np.ndarray:
-    """uint8 [B, L] -> uint64 [B, L+1]; column p = hash of row prefix [:p].
+def rolling_fnv64(qbytes: np.ndarray, salts) -> np.ndarray:
+    """uint8 [B, L], salts [S] -> uint64 [L+1, S, B]; out[p, s, i] = the
+    salts[s]-salted FNV-1a hash of row i's prefix [:p].
 
-    Vectorized across the batch: L sequential steps of [B] ops.
+    One walk over the byte columns serves every salt: a table's two
+    cuckoo salts, or the 2 S salts of S shards' tables. L sequential
+    steps of [S, B] ops; the bytes are cast to uint64 once, column-major,
+    and the hashes are stored by column, so a step reads and writes
+    contiguous rows. Pass only the columns a probe can read (the
+    encoder's `lim`): the walk costs the columns it is given.
     """
     b, l = qbytes.shape
-    out = np.empty((b, l + 1), dtype=np.uint64)
-    h = np.full(b, FNV64_OFFSET ^ np.uint64(salt), dtype=np.uint64)
-    out[:, 0] = h
-    with np.errstate(over="ignore"):
-        for p in range(l):
-            h = (h ^ qbytes[:, p].astype(np.uint64)) * FNV64_PRIME
-            out[:, p + 1] = h
-    return out
-
-
-def rolling_fnv64_multi(qbytes: np.ndarray, salts) -> np.ndarray:
-    """uint8 [B, L], salts [S] -> uint64 [S, B, L+1]; out[s, :, p] =
-    rolling_fnv64(qbytes, salts[s])[:, p]. One pass over the byte
-    columns serves every salt — the sharded encoder's way to hash a
-    query batch for S per-shard tables without S sequential passes."""
-    b, l = qbytes.shape
     salts = np.asarray(salts, np.uint64)
-    s = salts.shape[0]
-    out = np.empty((s, b, l + 1), dtype=np.uint64)
-    h = np.ascontiguousarray(
-        np.broadcast_to(FNV64_OFFSET ^ salts[:, None], (s, b)))
-    out[:, :, 0] = h
-    with np.errstate(over="ignore"):
-        qb = qbytes.astype(np.uint64)
-        for p in range(l):
-            h = (h ^ qb[None, :, p]) * FNV64_PRIME
-            out[:, :, p + 1] = h
+    out = np.empty((l + 1, salts.shape[0], b), np.uint64)
+    out[0] = (FNV64_OFFSET ^ salts)[:, None]
+    qb = qbytes.T.astype(np.uint64, order="C")  # [L, B]
+    for p in range(l):
+        np.bitwise_xor(out[p], qb[p], out=out[p + 1])
+        np.multiply(out[p + 1], FNV64_PRIME, out=out[p + 1])
     return out
 
 

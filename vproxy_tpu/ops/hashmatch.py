@@ -255,12 +255,46 @@ def compile_hint_hash(rules: Sequence[HintRule],
               "hb_items": hbc, "ub_items": ubc, "lset": lset_cap})
 
 
+def _scatter_strings(strs: list, win: np.ndarray, qlen: np.ndarray,
+                     has: np.ndarray, reverse: bool) -> None:
+    """One column of a batch (hosts or uris, None = absent) into its
+    byte window: row i of `win` gets string i's first win.shape[1]
+    utf-8 bytes — of the reversed string where `reverse` — and
+    qlen / has its length and presence. Each string is encoded once and
+    the batch's bytes are joined into one blob; only the bytes that
+    exist are written, each at (its row, its column), so the cost is
+    the batch's bytes and not rows x window."""
+    n, w = len(strs), win.shape[1]
+    enc = [b"" if s is None else s.encode() for s in strs]
+    ln = np.fromiter(map(len, enc), np.int64, n)
+    has[:n] = [s is not None for s in strs]
+    qlen[:n] = np.minimum(ln, 1 << 20)
+    blob = np.frombuffer(b"".join(enc), np.uint8)
+    if not blob.size:
+        return
+    end = np.cumsum(ln)
+    at = np.arange(blob.size)
+    row = np.arange(0, n * w, w)  # each row's first cell in the flat window
+    # cell of each blob byte: its row's first + its offset in its string,
+    # counted from the string's last byte where the window holds the
+    # reversed string
+    flat = (np.repeat(row + (end - 1), ln) - at) if reverse \
+        else (np.repeat(row - (end - ln), ln) + at)
+    if int(ln.max()) > w:  # bytes past the window are dropped
+        keep = flat - np.repeat(row, ln) < w
+        flat, blob = flat[keep], blob[keep]
+    win.reshape(-1)[flat] = blob
+
+
 def _fill_query_windows(hints: Sequence, hw: int, uw: int, cap: int):
     """Shared query-byte-window fill for the vectorized encoders:
     -> (hostb [cap,hw] u8 reversed, hlen, has_host, urib [cap,uw] u8,
     ulen, has_uri, port). Rows past len(hints) stay zero (pad rows).
-    The small-batch encoder fuses this walk with its per-hint hashing
-    and intentionally does not share it."""
+    The batch is taken apart into its three columns once; nothing
+    walks it hint by hint after that. The small-batch encoder fuses its
+    walk with its per-hint hashing and intentionally does not share
+    this."""
+    b = len(hints)
     q_hostb = np.zeros((cap, hw), np.uint8)
     q_hlen = np.zeros(cap, np.int32)
     q_has_host = np.zeros(cap, bool)
@@ -268,22 +302,90 @@ def _fill_query_windows(hints: Sequence, hw: int, uw: int, cap: int):
     q_ulen = np.zeros(cap, np.int32)
     q_has_uri = np.zeros(cap, bool)
     q_port = np.zeros(cap, np.int32)
-    for i, h in enumerate(hints):
-        if h.host is not None:
-            hb = h.host.encode()[::-1]
-            q_hlen[i] = min(len(hb), 1 << 20)
-            q_hostb[i, : min(len(hb), hw)] = np.frombuffer(hb[:hw],
-                                                           np.uint8)
-            q_has_host[i] = True
-        if h.uri is not None:
-            ub = h.uri.encode()
-            q_ulen[i] = min(len(ub), 1 << 20)
-            q_urib[i, : min(len(ub), uw)] = np.frombuffer(ub[:uw],
-                                                          np.uint8)
-            q_has_uri[i] = True
-        q_port[i] = h.port
+    _scatter_strings([h.host for h in hints], q_hostb, q_hlen, q_has_host,
+                     reverse=True)
+    _scatter_strings([h.uri for h in hints], q_urib, q_ulen, q_has_uri,
+                     reverse=False)
+    q_port[:b] = [h.port for h in hints]
     return (q_hostb, q_hlen, q_has_host, q_urib, q_ulen, q_has_uri,
             q_port)
+
+
+def _encode_hint_arrays(hints: Sequence, cap: int, hw: int, uw: int,
+                        host_salts: Sequence[int], host_cap: int,
+                        uri_salts: Sequence[int], uri_cap: int,
+                        lset: Sequence[int], lset_w: int) -> tuple:
+    """The vectorized encoders' one body -> (q, hp_slots, up_slots): q
+    the byte windows and the probe positions (shared by every salt),
+    and the probe slots under each of `host_salts` / `uri_salts` — a
+    table's two, or S shards' 2 S — as hp_slots [NS, cap, P] /
+    up_slots [NS, cap, lset_w].
+
+    Arrays come out at `cap` rows; rows past len(hints) are pad rows
+    (zero windows, -1 probes) that no hash pass ever saw."""
+    b = len(hints)
+    (q_hostb, q_hlen, q_has_host, q_urib, q_ulen, q_has_uri,
+     q_port) = _fill_query_windows(hints, hw, uw, cap)
+
+    # --- host probes: every dot position p (suffix), then exact
+    # (p = hlen), ascending. Valid probe lengths are <= hw-1 (no rule
+    # host is longer; a boundary dot may sit AT hw-1) and <= the row's
+    # hlen, so hashing the first `lim` columns covers every probe of
+    # this batch; the columns past its longest host hash zeros nobody
+    # reads.
+    hmax = int(q_hlen[:b].max(initial=0))
+    lim = min(hw - 1, hmax)
+    hh = CK.rolling_fnv64(q_hostb[:b, :lim], host_salts)  # [lim+1,NS,b]
+    # a window byte exists only below its row's hlen and only where the
+    # hint has a host, so a DOT in the window is a probe wherever p >= 1
+    wc = min(hw, hmax)
+    dots = q_hostb[:b, :wc] == DOT
+    dots[:, :1] = False
+    exact = q_has_host[:b] & (q_hlen[:b] <= hw - 1)
+    # flatnonzero walks row-major, i.e. each row's dots ascending: a
+    # probe's place in its row is its rank among them, the exact slot
+    # the place after the last dot
+    dr, dc = np.divmod(np.flatnonzero(dots), max(wc, 1))
+    nd = np.bincount(dr, minlength=b)
+    need = int((nd + exact).max(initial=0))
+    # a row holds at most hw <= MAX_HOST + 1 probes: the last tier
+    # covers any batch
+    maxp = next((t for t in MAXP_TIERS if t >= need), MAXP_TIERS[-1])
+    er = np.flatnonzero(exact)
+    pr = np.concatenate([dr, er])          # a probe's row,
+    pl = np.concatenate([dc, q_hlen[er]])  # its length
+    at = pr * maxp + np.concatenate([      # and its cell in [cap, maxp]
+        np.arange(dr.size) - np.repeat(np.cumsum(nd) - nd, nd), nd[er]])
+    ns = len(host_salts)
+    hp_len = np.full((cap, maxp), -1, np.int32)
+    hp_slots = np.full((ns, cap, maxp), -1, np.int32)
+    hp_len.reshape(-1)[at] = pl
+    slot = (hh.reshape(lim + 1, ns * b)[pl, np.arange(ns)[:, None] * b + pr]
+            & np.uint64(host_cap - 1)).astype(np.int32)  # [ns, probes]
+    for k in range(ns):
+        hp_slots[k].reshape(-1)[at] = slot[k]
+
+    # --- uri probes at each rule-uri length <= query len: the same
+    # hash columns for every row, so one gather of whole columns
+    lens = np.full(lset_w, -1, np.int32)
+    lens[: len(lset)] = lset
+    ulim = min(uw, int(q_ulen[:b].max(initial=0)), max(lset, default=0))
+    uh = CK.rolling_fnv64(q_urib[:b, :ulim], uri_salts)  # [ulim+1,NS,b]
+    lv = (lens[None, :] >= 0) & (lens[None, :] <= q_ulen[:b, None]) & \
+        q_has_uri[:b, None]
+    up_len = np.full((cap, lset_w), -1, np.int32)
+    up_len[:b] = np.where(lv, lens[None, :], -1)
+    up_slots = np.full((len(uri_salts), cap, lset_w), -1, np.int32)
+    # a length past ulim is valid for no row; clip keeps the gather in
+    up_slots[:, :b] = np.where(
+        lv[None],
+        (uh[np.clip(lens, 0, ulim)].transpose(1, 2, 0)
+         & np.uint64(uri_cap - 1)).astype(np.int32), -1)
+    return {
+        "hostb": q_hostb, "hlen": q_hlen, "has_host": q_has_host,
+        "urib": q_urib, "ulen": q_ulen, "has_uri": q_has_uri,
+        "port": q_port, "hp_len": hp_len, "up_len": up_len,
+    }, hp_slots, up_slots
 
 
 # the python-int FNV form lives in ops/cuckoo (single source for the
@@ -302,6 +404,11 @@ _FNV64_OFFSET_I = CK._FNV64_OFFSET_I
 # 346us) — the fused launch removed enough dispatch overhead that
 # encode is a larger share of the batch, and the numpy pass amortizes
 # sooner than the old 32 default assumed.
+# All of these crossovers were measured against the vectorized path as
+# it was before PR 32 (a per-hint fill, one 63-column hash pass a salt);
+# that path now costs about half at b=32 (sandbox CPU). The constant
+# was kept, not re-measured: where the crossover lies now is a later
+# change's to find.
 SMALL_ENCODE = int(os.environ.get("VPROXY_TPU_SMALL_ENCODE", "28"))
 
 
@@ -412,69 +519,25 @@ def encode_hint_queries(hints: Sequence, tab: HashHintTable,
                         pad_to: int = 0) -> dict:
     """Hints -> device-ready query dict incl. precomputed probe slots.
 
-    Host-side work is vectorized numpy: two rolling-FNV passes over the
-    reversed host window and the uri window give every suffix/prefix
-    hash; probe positions are the dots (host) and the table's rule-uri
-    length set (uri). Batches up to SMALL_ENCODE take the per-hint
-    python path instead (same outputs, ~5x cheaper at accept-path batch
-    sizes). pad_to: emit arrays at this batch bucket, pad rows being
-    invalid probes (never encode padding).
+    Host-side work is vectorized numpy (_encode_hint_arrays): the batch
+    becomes arrays at the first step, then one rolling-FNV walk over the
+    reversed host window and one over the uri window — both salts at
+    once, only as many columns as the batch's longest host / uri — give
+    every suffix/prefix hash; probe positions are the dots (host) and
+    the table's rule-uri length set (uri). Batches up to SMALL_ENCODE
+    take the per-hint python path instead (same outputs, cheaper at
+    accept-path batch sizes). pad_to: emit arrays at this batch bucket,
+    pad rows being invalid probes (never encode padding).
     """
     if len(hints) <= SMALL_ENCODE:
         return _encode_hint_queries_small(hints, tab,
                                           max(pad_to, len(hints)))
-    b = len(hints)
-    W = tab.hw  # reversed-host compare window (suffix boundary incl.)
-    (q_hostb, q_hlen, q_has_host, q_urib, q_ulen, q_has_uri,
-     q_port) = _fill_query_windows(hints, W, tab.uw, b)
-
-    # --- host probes: exact (p = hlen) + every dot position p (suffix).
-    # Valid probe lengths p <= hw-1 (no rule host is longer), so the
-    # rolling window of hw-1 bytes covers every probe, incl. a boundary
-    # dot at position hw-1 (max-length rule host + '.').
-    h1 = CK.rolling_fnv64(q_hostb[:, : W - 1], tab.host_salts[0])
-    h2 = CK.rolling_fnv64(q_hostb[:, : W - 1], tab.host_salts[1])
-    pos = np.arange(W)[None, :]
-    probe_ok = np.concatenate([
-        (q_hostb == DOT) & (pos < q_hlen[:, None]) & (pos >= 1),
-        (q_has_host & (q_hlen <= W - 1))[:, None],  # exact slot
-    ], axis=1) & q_has_host[:, None]  # [B, W+1]
-    probe_len = np.concatenate([
-        np.broadcast_to(pos, (b, W)),
-        q_hlen[:, None],
-    ], axis=1).astype(np.int32)
-    need = int(probe_ok.sum(axis=1).max(initial=0))
-    maxp = next((t for t in MAXP_TIERS if t >= need), MAXP_TIERS[-1])
-
-    # compact valid probes to the left (stable argsort on ~ok)
-    order = np.argsort(~probe_ok, axis=1, kind="stable")[:, :maxp]
-    pv = np.take_along_axis(probe_ok, order, 1)
-    pl = np.where(pv, np.take_along_axis(probe_len, order, 1), 0)
-    hp_len = np.where(pv, pl, -1).astype(np.int32)
-    mask = np.uint64(tab.host_cap - 1)
-    hp_slot1 = np.where(pv, (np.take_along_axis(h1, pl, 1) & mask).astype(np.int32), -1)
-    hp_slot2 = np.where(pv, (np.take_along_axis(h2, pl, 1) & mask).astype(np.int32), -1)
-
-    # --- uri probes at each rule-uri length <= query len
-    lset_cap = tab.caps["lset"]
-    lset = np.full(lset_cap, -1, np.int32)
-    lset[: len(tab.lset)] = tab.lset
-    u1 = CK.rolling_fnv64(q_urib, tab.uri_salts[0])
-    u2 = CK.rolling_fnv64(q_urib, tab.uri_salts[1])
-    lv = (lset[None, :] >= 0) & (lset[None, :] <= q_ulen[:, None]) & \
-        q_has_uri[:, None]
-    ll = np.where(lv, np.maximum(lset[None, :], 0), 0)
-    umask = np.uint64(tab.uri_cap - 1)
-    up_len = np.where(lv, ll, -1).astype(np.int32)
-    up_slot1 = np.where(lv, (np.take_along_axis(u1, ll, 1) & umask).astype(np.int32), -1)
-    up_slot2 = np.where(lv, (np.take_along_axis(u2, ll, 1) & umask).astype(np.int32), -1)
-
-    return {
-        "hostb": q_hostb, "hlen": q_hlen, "has_host": q_has_host,
-        "urib": q_urib, "ulen": q_ulen, "has_uri": q_has_uri, "port": q_port,
-        "hp_len": hp_len, "hp_slot1": hp_slot1, "hp_slot2": hp_slot2,
-        "up_len": up_len, "up_slot1": up_slot1, "up_slot2": up_slot2,
-    }
+    q, hs, us = _encode_hint_arrays(
+        hints, max(len(hints), pad_to), tab.hw, tab.uw,
+        tab.host_salts, tab.host_cap, tab.uri_salts, tab.uri_cap,
+        tab.lset, tab.caps["lset"])
+    return {**q, "hp_slot1": hs[0], "hp_slot2": hs[1],
+            "up_slot1": us[0], "up_slot2": us[1]}
 
 
 def _probe_buckets(slots, plen, used, klen, kbytes, bs, bc, qbytes, iota):
@@ -1000,8 +1063,8 @@ def encode_hint_queries_sharded(hints: Sequence, stab: ShardedHashTable,
     caps guarantee every shard shares the compare windows and table
     capacities, and the probe POSITIONS (dots, uri lengths) depend only
     on query content. So this runs the byte walk and the rolling-FNV
-    pass ONCE for all shards (rolling_fnv64_multi over the salt
-    vector), instead of the S sequential re-encodes the original path
+    pass ONCE for all shards (_encode_hint_arrays over the shards'
+    salts), instead of the S sequential re-encodes the original path
     paid — measured 8x of the whole dispatch's host cost at S=8.
 
     uri probes ride the UNION of the shards' rule-uri length sets: a
@@ -1031,78 +1094,21 @@ def encode_hint_queries_sharded(hints: Sequence, stab: ShardedHashTable,
         return {k: np.stack([p[k] for p in per]) for k in per[0]}
 
     S = len(shards)
-    b = len(hints)
-    cap = max(b, pad_to or 0)
-    W = t0.hw
-    (q_hostb, q_hlen, q_has_host, q_urib, q_ulen, q_has_uri,
-     q_port) = _fill_query_windows(hints, W, t0.uw, cap)
-
-    def shared(a: np.ndarray) -> np.ndarray:
-        # shard-invariant keys: a zero-stride broadcast view on the
-        # shard axis (device_put materializes each device's slice)
-        return np.broadcast_to(a, (S,) + a.shape)
-
-    # --- host probes (positions shared; slots per shard salt)
-    h1 = CK.rolling_fnv64_multi(
-        q_hostb[:, : W - 1],
-        [t.host_salts[0] for t in shards])  # [S, cap, W]
-    h2 = CK.rolling_fnv64_multi(
-        q_hostb[:, : W - 1], [t.host_salts[1] for t in shards])
-    pos = np.arange(W)[None, :]
-    probe_ok = np.concatenate([
-        (q_hostb == DOT) & (pos < q_hlen[:, None]) & (pos >= 1),
-        (q_has_host & (q_hlen <= W - 1))[:, None],  # exact slot
-    ], axis=1) & q_has_host[:, None]  # [cap, W+1]
-    probe_len = np.concatenate([
-        np.broadcast_to(pos, (cap, W)), q_hlen[:, None],
-    ], axis=1).astype(np.int32)
-    need = int(probe_ok.sum(axis=1).max(initial=0))
-    maxp = next((t for t in MAXP_TIERS if t >= need), MAXP_TIERS[-1])
-    order = np.argsort(~probe_ok, axis=1, kind="stable")[:, :maxp]
-    pv = np.take_along_axis(probe_ok, order, 1)
-    pl = np.where(pv, np.take_along_axis(probe_len, order, 1), 0)
-    hp_len = np.where(pv, pl, -1).astype(np.int32)  # [cap, P] shared
-    mask = np.uint64(t0.host_cap - 1)
-    pl_s = np.broadcast_to(pl, (S,) + pl.shape)
-    hp_slot1 = np.where(pv[None],
-                        (np.take_along_axis(h1, pl_s, 2) & mask)
-                        .astype(np.int32), -1)
-    hp_slot2 = np.where(pv[None],
-                        (np.take_along_axis(h2, pl_s, 2) & mask)
-                        .astype(np.int32), -1)
-
-    # --- uri probes at the UNION of the shards' rule-uri length sets;
+    # --- uri probes ride the UNION of the shards' rule-uri length sets;
     # width = the caps-stable "lset_u" cap (compile_hint_hash_sharded)
     # so caps-reusing updates keep ONE query trace shape
     lset_u = stab.lset_u if stab.lset_u is not None else sorted(
         set().union(*[set(t.lset) for t in shards]))
     lw = t0.caps.get("lset_u") or _pow2(max(len(lset_u), 1), 4)
-    lset = np.full(lw, -1, np.int32)
-    lset[: len(lset_u)] = lset_u
-    u1 = CK.rolling_fnv64_multi(q_urib,
-                                [t.uri_salts[0] for t in shards])
-    u2 = CK.rolling_fnv64_multi(q_urib,
-                                [t.uri_salts[1] for t in shards])
-    lv = (lset[None, :] >= 0) & (lset[None, :] <= q_ulen[:, None]) & \
-        q_has_uri[:, None]  # [cap, lw]
-    ll = np.where(lv, np.maximum(lset[None, :], 0), 0)
-    umask = np.uint64(t0.uri_cap - 1)
-    up_len = np.where(lv, ll, -1).astype(np.int32)  # shared
-    ll_s = np.broadcast_to(ll, (S,) + ll.shape)
-    up_slot1 = np.where(lv[None],
-                        (np.take_along_axis(u1, ll_s, 2) & umask)
-                        .astype(np.int32), -1)
-    up_slot2 = np.where(lv[None],
-                        (np.take_along_axis(u2, ll_s, 2) & umask)
-                        .astype(np.int32), -1)
-
-    return {
-        "hostb": shared(q_hostb), "hlen": shared(q_hlen),
-        "has_host": shared(q_has_host),
-        "urib": shared(q_urib), "ulen": shared(q_ulen),
-        "has_uri": shared(q_has_uri), "port": shared(q_port),
-        "hp_len": shared(hp_len), "hp_slot1": hp_slot1,
-        "hp_slot2": hp_slot2,
-        "up_len": shared(up_len), "up_slot1": up_slot1,
-        "up_slot2": up_slot2,
-    }
+    # salt s of every shard, then salt s+1 of every shard: rows [:S] of
+    # the slot arrays are the shards' first-salt slots, [S:] the second
+    q, hs, us = _encode_hint_arrays(
+        hints, max(len(hints), pad_to or 0), t0.hw, t0.uw,
+        [t.host_salts[k] for k in (0, 1) for t in shards], t0.host_cap,
+        [t.uri_salts[k] for k in (0, 1) for t in shards], t0.uri_cap,
+        lset_u, lw)
+    # shard-invariant keys: a zero-stride broadcast view on the shard
+    # axis (device_put materializes each device's slice)
+    return {**{k: np.broadcast_to(v, (S,) + v.shape) for k, v in q.items()},
+            "hp_slot1": hs[:S], "hp_slot2": hs[S:],
+            "up_slot1": us[:S], "up_slot2": us[S:]}

@@ -183,22 +183,18 @@ def _install_phase(tid: int, span: str, t0_ns: int, **fields) -> None:
 
 
 # batch padding at the ARRAY level: a pad row must read as "no probes,
-# no match" to the kernel. The cuckoo query arrays mark invalid probes
-# with -1 (slot/len); everything else (fp fingerprints, byte windows,
-# flags) zero-fills — exactly what encoding an empty Hint() produces,
-# without paying the encode for it.
-_PAD_CUCKOO = {"hp_len": -1, "hp_slot1": -1, "hp_slot2": -1,
-               "up_len": -1, "up_slot1": -1, "up_slot2": -1}
-
-
-def _pad_hint_q(q: dict, cap: int, fills: dict) -> dict:
+# no match" to the kernel. The fp query arrays (fingerprints, byte
+# windows, flags) zero-fill — exactly what encoding an empty Hint()
+# produces, without paying the encode for it. (The cuckoo encoder
+# writes into its pad bucket itself: encode_hint_queries' pad_to.)
+def _pad_hint_q(q: dict, cap: int) -> dict:
     out = {}
     for k, v in q.items():
         n = v.shape[0]
         if n >= cap:
             out[k] = v
             continue
-        pad = np.full((cap - n,) + v.shape[1:], fills.get(k, 0), v.dtype)
+        pad = np.zeros((cap - n,) + v.shape[1:], v.dtype)
         out[k] = np.concatenate([v, pad])
     return out
 
@@ -544,10 +540,7 @@ def fused_dispatch(hm, hsnap: tuple, mm, msnap: tuple, hints,
 
 def _fused_hint_q(tab, hints, pad_to: Optional[int]) -> dict:
     with encode_span(len(hints)):
-        q = H.encode_hint_queries(hints, tab, pad_to=pad_to or 0)
-        if pad_to and q["hostb"].shape[0] < pad_to:
-            q = _pad_hint_q(q, pad_to, _PAD_CUCKOO)
-    return q
+        return H.encode_hint_queries(hints, tab, pad_to=pad_to or 0)
 
 
 def _encode_addrs(addrs, ports, pad_to: Optional[int],
@@ -845,10 +838,9 @@ class HintMatcher:
         # encode + padding) and one launch span (the jitted call)
         n = len(hints)
         if self.backend == "jax":
-            # ONE copy of the encode+pad idiom, shared with the fused
-            # entry: small batches encode straight into the padded
-            # bucket (the per-hint python path); big ones encode the
-            # real rows then array-pad with invalid probes
+            # ONE copy of the encode idiom, shared with the fused
+            # entry: the encoder hashes the real rows only and writes
+            # them into the padded bucket, pad rows invalid probes
             q = _fused_hint_q(tab, hints, pad_to)
             with launch_span("hint", q["hostb"].shape[0]):
                 idx, _ = H.hint_hash_jit(dev, q)
@@ -858,7 +850,7 @@ class HintMatcher:
             with encode_span(n):
                 q = F.encode_hint_queries_fp(hints, tab)
                 if pad_to and pad_to > n:
-                    q = _pad_hint_q(q, pad_to, {})
+                    q = _pad_hint_q(q, pad_to)
             # resolve the member-mode env knob HERE, per dispatch: jit
             # keys on the static mode arg, so passing None would bake
             # the first dispatch's VPROXY_TPU_FP_MEMBER into the cache
