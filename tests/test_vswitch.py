@@ -399,3 +399,123 @@ def test_switch_command_grammar(sw_env):
         assert Command.execute(app, "list switch") == []
     finally:
         app.close()
+
+
+# ------------------------------------------------- the route-table set
+
+def _routed_vpcs(sw, sizes):
+    """VPC 100+k with sizes[k] routes 10.<k>.<j>.0/24 -> vni 100, all of
+    them also holding 10.0.0.0/8 (the same prefix in every tenant)."""
+    nets = []
+    for k, n in enumerate(sizes):
+        net = sw.add_network(100 + k, Network.parse(f"10.{k}.0.0/16"))
+        net.set_routes(
+            [RouteRule(f"r{k}-{j}", Network.parse(f"10.{k}.{j}.0/24"),
+                       to_vni=100) for j in range(n)]
+            + [RouteRule(f"wide{k}", Network.parse("10.0.0.0/8"),
+                         to_vni=100 + k)])
+        nets.append(net)
+    return nets
+
+
+@pytest.mark.parametrize("backend,in_set", [("jax", True), ("host", True),
+                                            ("jax-dense", False)])
+def test_switch_vpcs_share_one_route_table_set(sw_env, backend, in_set):
+    """A switch's VPCs are views of ONE set a family where the backend
+    has a set, matchers of their own elsewhere; del_network gives the
+    view back; the answers are the VPC's own either way."""
+    elg, objs = sw_env
+    sw = Switch("sw0", elg.next(), "127.0.0.1", 0, matcher_backend=backend)
+    objs["switches"].append(sw)
+    n0, n1, n2 = _routed_vpcs(sw, [3, 5, 2])
+    sets = sw.route_sets()
+    assert (sets is not None) == in_set
+    if in_set:
+        assert n0._matcher_v4.table_set is n1._matcher_v4.table_set is sets[0]
+        assert n0._matcher_v6.table_set is sets[1]
+        assert sets[0].size() == 3 + 5 + 2 + 3 and sets[1].size() == 0
+    ip = parse_ip("10.1.4.9")       # a /24 of VPC 101, the /8 elsewhere
+    assert n1.route_lookup(ip).alias == "r1-4"
+    assert n0.route_lookup(ip).alias == "wide0"
+    assert n2.route_lookup(ip).alias == "wide2"
+    got = n1.route_lookup_batch([ip, parse_ip("10.1.9.9"),
+                                 parse_ip("11.0.0.1")])
+    assert [r and r.alias for r in got] == ["r1-4", "wide1", None]
+    sw.del_network(101)
+    if in_set:
+        assert sets[0].size() == 3 + 2 + 2
+        assert n1._matcher_v4.size() == 0
+    assert n0.route_lookup(ip).alias == "wide0"
+    n3 = sw.add_network(103, Network.parse("10.3.0.0/16"))
+    n3.add_route(RouteRule("only", Network.parse("10.1.4.0/24"), to_vni=100))
+    assert n3.route_lookup(ip).alias == "only"
+    assert n3.route_lookup(parse_ip("10.9.9.9")) is None
+    n3.remove_route("only")
+    assert n3.route_lookup(ip) is None
+
+
+def test_route_flush_of_a_mixed_vpc_burst_is_one_dispatch(sw_env):
+    """The burst's deferred lookups, three VPCs and both families mixed
+    (the set past SMALL_TABLE, so the device serves): one launch for the
+    v4 lookups of every VPC, each answered from its own VPC's table."""
+    from vproxy_tpu.rules import engine
+    elg, objs = sw_env
+    sw = Switch("sw0", elg.next(), "127.0.0.1", 0, matcher_backend="jax")
+    objs["switches"].append(sw)
+    nets = _routed_vpcs(sw, [60, 90, 40])
+    nets[2].add_route(RouteRule("six", Network.parse("fd00::/16"),
+                                to_vni=100))
+    assert sw.route_sets()[0].size() > engine.SMALL_TABLE
+    pend, want = [], []
+    for i in range(120):
+        k = i % 3
+        dst = f"10.{(i // 3) % 3}.{i % 50}.7"
+        ip = P.Ipv4(parse_ip("10.9.9.9"), parse_ip(dst), P.PROTO_UDP, b"")
+        pend.append((nets[k], None, ip, False))
+        want.append(nets[k].routes.lookup(parse_ip(dst)).alias)
+    six = P.Ipv6(parse_ip("fd00::1"), parse_ip("fd00::2"), P.PROTO_UDP, b"")
+    pend.append((nets[2], None, six, True))
+    want.append("six")
+    pend.append((nets[0], None, six, True))     # VPC 100 routes no v6
+    want.append(None)
+    got = []
+    sw.stack._route_with = lambda n, e, ip, v6, rule: got.append(
+        rule and rule.alias)
+    launches = engine.dispatch_launches_total()
+    sw.stack._route_flush(pend)
+    assert got == want
+    assert {w[:2] for w in want if w} >= {"r0", "r1", "r2", "wi", "si"}
+    # v4: one launch for all three VPCs; v6: the set is small, host scan
+    assert engine.dispatch_launches_total() == launches + 1
+
+
+def test_config_replay_syncs_a_vpcs_routes_once(tmp_path):
+    """persist.load holds the matcher syncs of `add route` lines and
+    makes one a VPC at the end of the replay."""
+    from vproxy_tpu.control import persist
+    from vproxy_tpu.control.app import Application
+    from vproxy_tpu.control.command import Command
+    from vproxy_tpu.rules import engine
+    app = Application(workers=1)
+    try:
+        cfg = tmp_path / "cfg"
+        lines = ["add switch sw0 address 127.0.0.1:0",
+                 "add vpc 7 to switch sw0 v4network 10.7.0.0/16"]
+        lines += [f"add route r{j} to vpc 7 in switch sw0 network "
+                  f"10.7.{j}.0/24 vni 7" for j in range(12)]
+        cfg.write_text("\n".join(lines) + "\n")
+        builds = engine.cidr_set_table_builds_total()
+        assert persist.load(app, str(cfg)) == len(lines)
+        assert app.held_route_syncs is None
+        net = app.switches["sw0"].networks[7]
+        assert engine.cidr_set_table_builds_total() == builds + 1
+        assert net.route_lookup(parse_ip("10.7.5.1")).alias == "r5"
+        # an operator's own command syncs at once
+        Command.execute(app, "add route late to vpc 7 in switch sw0 "
+                             "network 10.8.0.0/16 vni 7")
+        assert net.route_lookup(parse_ip("10.8.0.1")).alias == "late"
+    finally:
+        for sw in app.switches.values():
+            sw.stop()
+        for elg in app.elgs.values():
+            elg.close()

@@ -10,7 +10,7 @@ prove). The convention is enforced here as config: GUARDS names every
 guarded store and the gate calls that protect it, and the pass flags
 any function that mutates a guarded store with no gate reachable on
 the path — in its own body, in a callee (the gate may be downstream:
-add_route -> _sync_routes), or in every one of its callers (helpers
+add_route -> sync_routes), or in every one of its callers (helpers
 like SyntheticIpHolder._unindex_mac are gated by construction when all
 call sites gate).
 
@@ -63,7 +63,7 @@ GUARDS: List[Guard] = [
           gates=frozenset({"on_change"})),
     Guard("vproxy_tpu/vswitch/network.py", "VpcNetwork",
           attrs=frozenset({"routes"}),
-          gates=frozenset({"_sync_routes", "on_route_change"})),
+          gates=frozenset({"sync_routes", "on_route_change"})),
     Guard("vproxy_tpu/vswitch/switch.py", "Switch",
           attrs=frozenset({"ifaces", "networks"}),
           gates=frozenset({"_bump_registry", "_gen_bump"})),
@@ -87,6 +87,9 @@ GUARDS: List[Guard] = [
     Guard("vproxy_tpu/rules/engine.py", "CidrMatcher",
           attrs=frozenset({"_pub"}),
           only_in=frozenset({"__init__", "_recompile"})),
+    Guard("vproxy_tpu/rules/engine.py", "CidrTableSet",
+          attrs=frozenset({"_pub"}),
+          only_in=frozenset({"__init__", "_publish"})),
     Guard("vproxy_tpu/rules/maglev.py", "MaglevMatcher",
           attrs=frozenset({"_pub"}),
           only_in=frozenset({"__init__", "_recompile"})),

@@ -48,6 +48,10 @@ class Application:
         # VPROXY_TPU_CLUSTER_PEERS booted one (main.py)
         self.cluster = None
         self._resolver = None  # lazy "(default)" resolver
+        # persist.load: VPCs whose `add route`s wait for ONE matcher
+        # sync at the end of the replay (id -> VpcNetwork); None = sync
+        # a route, as an operator's command does
+        self.held_route_syncs: Optional[dict] = None
         # fired by request_drain (the `drain` command / SIGTERM path);
         # main.py registers its stop event here
         self.on_drain_request: list = []

@@ -1059,7 +1059,11 @@ def _h_route(app: Application, c: Command):
         else:
             raise CmdError("route requires `vni <n>` or `via <ip>`")
         try:
-            net.add_route(rule)
+            if app.held_route_syncs is None:
+                net.add_route(rule)
+            else:   # a config replay: one table install a VPC, at its end
+                net.add_route(rule, sync=False)
+                app.held_route_syncs[id(net)] = net
         except ValueError as e:
             raise CmdError(str(e))
         return "OK"

@@ -163,13 +163,21 @@ def load(app: Application, path: Optional[str] = None) -> int:
     of commands executed."""
     path = path or LAST_CONFIG
     n = 0
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line or line.startswith("#"):
-                continue
-            Command.execute(app, line)
-            n += 1
+    # a VPC's routes come one `add route` a line: their matcher syncs
+    # (a table build each) are held and made once a VPC, after the last
+    app.held_route_syncs = {}
+    try:
+        with open(path) as f:
+            for line in f:
+                line = line.strip()
+                if not line or line.startswith("#"):
+                    continue
+                Command.execute(app, line)
+                n += 1
+    finally:
+        held, app.held_route_syncs = app.held_route_syncs, None
+        for net in held.values():
+            net.sync_routes()
     return n
 
 

@@ -850,9 +850,12 @@ def compile_cidr_hash(networks: Sequence, acl: Optional[Sequence[AclRule]] = Non
 
 
 def _fnv32_device(masked: jnp.ndarray, salt: jnp.ndarray) -> jnp.ndarray:
-    """masked [B, G, 16] u8, salt [G] u32 -> [B, G] u32; bit-identical to
-    cuckoo.fnv32_masked (u32 wraparound multiply)."""
-    h = jnp.broadcast_to((CK.FNV32_OFFSET ^ salt)[None, :], masked.shape[:2])
+    """masked [B, G, 16] u8, salt [G] (or [B, G]) u32 -> [B, G] u32;
+    bit-identical to cuckoo.fnv32_masked (u32 wraparound multiply)."""
+    seed = CK.FNV32_OFFSET ^ salt
+    # a table set hands every lookup its own table's salts: [B, G]
+    h = seed if seed.ndim == 2 else jnp.broadcast_to(seed[None, :],
+                                                     masked.shape[:2])
     prime = jnp.uint32(CK.FNV32_PRIME)
     for p in range(16):
         h = (h ^ masked[:, :, p].astype(jnp.uint32)) * prime
@@ -860,19 +863,33 @@ def _fnv32_device(masked: jnp.ndarray, salt: jnp.ndarray) -> jnp.ndarray:
 
 
 def cidr_hash_match(t: dict, addr16: jnp.ndarray, fam: jnp.ndarray,
-                    port: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                    port: Optional[jnp.ndarray] = None,
+                    tid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """-> first-matching rule index [B] i32 (ordered-scan semantics), -1
-    if none. addr16 [B,16] u8, fam [B] i32, port [B] i32 (ACL only)."""
+    if none. addr16 [B,16] u8, fam [B] i32, port [B] i32 (ACL only).
+    tid [B] i32: the table each lookup names, where `t` holds a set of
+    tables (stack_cidr_tables: the per-group rows carry a leading table
+    axis, slots and bucket rows are flat under absolute offsets). A
+    lookup then gathers its own table's group rows; with tid None the
+    one table's rows broadcast and no such gather is traced."""
+    if tid is None:
+        def g(name):
+            return t[name][None]
+        salts = (t["g_salt1"], t["g_salt2"])
+    else:
+        def g(name):
+            return t[name][tid]
+        salts = (g("g_salt1"), g("g_salt2"))
     with jax.named_scope("cidr_mask"):      # per-group masked address
-        masked = addr16[:, None, :] & t["g_mask"][None]  # [B, G, 16]
-        gok = (t["g_fam"][None] >= 0) & (fam[:, None] == t["g_fam"][None])
+        masked = addr16[:, None, :] & g("g_mask")  # [B, G, 16]
+        gok = (g("g_fam") >= 0) & (fam[:, None] == g("g_fam"))
 
     probes = []
-    for salt in (t["g_salt1"], t["g_salt2"]):
+    for salt in salts:
         with jax.named_scope("cidr_hash"):  # FNV over the 16 masked bytes
             h = _fnv32_device(masked, salt)
-            slot = t["g_off"][None] + (
-                h.astype(jnp.int32) & t["g_capmask"][None])
+            slot = g("g_off") + (
+                h.astype(jnp.int32) & g("g_capmask"))
         with jax.named_scope("cidr_probe"):  # slot gathers + key verify
             key = t["s_key"][slot]  # [B, G, 16]
             ok = gok & t["s_used"][slot] & jnp.all(key == masked, axis=-1)
@@ -920,8 +937,135 @@ def classify_hash_all(hint_t: dict, route_t: dict, acl_t: dict,
     return jnp.stack([h_idx, r_idx, a_idx], axis=1)
 
 
+# ------------------------------------------------------- a set of tables
+#
+# Many ordered CIDR tables behind ONE program (a switch's RouteTable a
+# VNI): each table is compiled on its own (compile_cidr_hash, so a
+# change to one rebuilds one), and stack_cidr_tables lays the compiled
+# tables side by side — the small per-group rows stacked on a leading
+# table axis, the cuckoo slots and their bucket rows concatenated with
+# every group offset made absolute, overflow rows behind all slots.
+# Only the bucket width K and the hop count are unified (a narrower
+# table's rows are padded with entries that never win). A lookup names
+# its table; cidr_hash_match gathers that table's group rows by `tid`
+# and is otherwise the program a single table runs.
+
+
+def _slot_extent(a: dict) -> int:
+    """Slots a compiled table's groups really span (its `ct` is padded)."""
+    live = a["g_fam"] >= 0
+    return int((a["g_off"] + a["g_capmask"] + 1)[live].max(initial=0))
+
+
+def _widen_rows(rows: np.ndarray, k: int, K: int, planes: int) -> np.ndarray:
+    """Bucket rows [n, w * k] (w = 1: indices; 3: indices, range starts,
+    range ends) -> [n, planes * K]; pad entries hold NO_RULE and the
+    empty range [1, 0], a route table's entries every port."""
+    n, w = rows.shape[0], rows.shape[1] // k
+    out = np.empty((n, planes, K), np.int32)
+    out[:, 0] = NO_RULE
+    out[:, 0, :k] = rows[:, :k]
+    if planes == 3:
+        out[:, 1], out[:, 2] = 1, 0
+        if w == 3:
+            out[:, 1, :k] = rows[:, k: 2 * k]
+            out[:, 2, :k] = rows[:, 2 * k:]
+        else:
+            real = rows != NO_RULE
+            out[:, 1, :k] = np.where(real, 0, 1)
+            out[:, 2, :k] = np.where(real, 65535, 0)
+    return out.reshape(n, planes * K)
+
+
+def stack_cidr_tables(tabs: Sequence[Optional[HashCidrTable]],
+                      caps: Optional[dict] = None) -> tuple:
+    """tabs[i] = table id i's compiled table, None for an id no table
+    holds -> (arrays, caps, buckets) of the set. caps only grow, and
+    every shape is padded to them, so a change that fits traces nothing
+    new: t_cap tables, g_cap groups a table, ct slots, ov overflow
+    rows, bk the fattest bucket (width and hops)."""
+    caps = dict(caps or {})
+    live = [t for t in tabs if t is not None]
+    ext = [0 if t is None else _slot_extent(t.arrays) for t in tabs]
+    ovs = [0 if t is None else t.arrays["b_rows"].shape[0]
+           - t.arrays["s_key"].shape[0] for t in tabs]
+    t_cap = max(caps.get("t_cap", 0), _pow2(max(len(tabs), 1), 8))
+    g_cap = max([caps.get("g_cap", 8)]
+                + [t.arrays["g_fam"].shape[0] for t in live])
+    ct = max(caps.get("ct", 0), _pow2(max(sum(ext), 1), 256))
+    ov = max(caps.get("ov", 0), _pow2(sum(ovs), 8) if sum(ovs) else 0)
+    bk = max([caps.get("bk", 1)] + [t.caps["bk"] for t in live])
+    K = min(bk, BUCKET_INLINE)
+    hops = -(-bk // K)
+    planes = 3 if any(t.arrays["b_rows"].shape[1]
+                      > t.arrays["b_shape"].shape[1] for t in live) else 1
+    planes = max(planes, caps.get("planes", 1))
+
+    g_fam = np.full((t_cap, g_cap), -1, np.int32)
+    g_mask = np.zeros((t_cap, g_cap, 16), np.uint8)
+    g_off = np.zeros((t_cap, g_cap), np.int32)
+    g_capmask = np.zeros((t_cap, g_cap), np.int32)
+    g_salt1 = np.zeros((t_cap, g_cap), np.uint32)
+    g_salt2 = np.zeros((t_cap, g_cap), np.uint32)
+    s_used = np.zeros(ct, bool)
+    s_key = np.zeros((ct, 16), np.uint8)
+    b_rows = np.empty((ct + ov, planes * K), np.int32)
+    b_rows[:] = _widen_rows(np.full((1, 1), NO_RULE, np.int32), 1, K,
+                            planes)
+    b_next = np.arange(ct + ov, dtype=np.int32)
+    base, obase = 0, ct
+    for i, t in enumerate(tabs):
+        if t is None:
+            continue
+        CK.coop_yield()
+        a, n, no = t.arrays, ext[i], ovs[i]
+        g = a["g_fam"].shape[0]
+        g_fam[i, :g], g_mask[i, :g] = a["g_fam"], a["g_mask"]
+        g_off[i, :g] = a["g_off"] + base
+        g_capmask[i, :g] = a["g_capmask"]
+        g_salt1[i, :g], g_salt2[i, :g] = a["g_salt1"], a["g_salt2"]
+        s_used[base: base + n] = a["s_used"][:n]
+        s_key[base: base + n] = a["s_key"][:n]
+        k = a["b_shape"].shape[1]
+        own_ct = a["s_key"].shape[0]
+        b_rows[base: base + n] = _widen_rows(a["b_rows"][:n], k, K, planes)
+        if no:
+            b_rows[obase: obase + no] = _widen_rows(a["b_rows"][own_ct:],
+                                                    k, K, planes)
+            nxt = a["b_next"].astype(np.int64)
+            nxt = np.where(nxt < own_ct, nxt + base, nxt - own_ct + obase)
+            b_next[base: base + n] = nxt[:n]
+            b_next[obase: obase + no] = nxt[own_ct:]
+        base += n
+        obase += no
+    arrays = {
+        "g_fam": g_fam, "g_mask": g_mask, "g_off": g_off,
+        "g_capmask": g_capmask, "g_salt1": g_salt1, "g_salt2": g_salt2,
+        "s_used": s_used, "s_key": s_key, "b_rows": b_rows,
+        "b_shape": np.zeros((hops, K), np.int8),
+    }
+    if hops > 1:
+        arrays["b_next"] = b_next
+    return (arrays,
+            {"t_cap": t_cap, "g_cap": g_cap, "ct": ct, "ov": ov, "bk": bk,
+             "planes": planes},
+            {"width": K, "hops": hops,
+             "used_slots": sum(t.buckets["used_slots"] for t in live),
+             "overflow_slots": sum(t.buckets["overflow_slots"]
+                                   for t in live)})
+
+
+def cidr_set_match(t: dict, addr16: jnp.ndarray, fam: jnp.ndarray,
+                   tid: jnp.ndarray,
+                   port: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """cidr_hash_match over a set of tables (stack_cidr_tables): lookup
+    b is answered from table tid[b], by that table's own rule indices."""
+    return cidr_hash_match(t, addr16, fam, port, tid)
+
+
 hint_hash_jit = jax.jit(hint_hash_match)
 cidr_hash_jit = jax.jit(cidr_hash_match)
+cidr_set_jit = jax.jit(cidr_set_match)
 classify_hash_jit = jax.jit(classify_hash_all)
 
 

@@ -55,7 +55,8 @@ import os
 import threading
 import time
 import weakref
-from typing import Optional, Sequence
+from itertools import repeat
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -902,6 +903,26 @@ class HintMatcher:
         return idx
 
 
+def _cidr_scan(nets, acl, addr: bytes, port: Optional[int]) -> int:
+    """The ordered scan (RouteTable.lookup / SecurityGroup.allow): index
+    of the first network that holds addr and, for an ACL asked with a
+    port, whose range holds the port; -1 for none."""
+    for j, net in enumerate(nets):
+        if net.contains_ip(addr) and (
+                port is None or acl is None or
+                (acl[j].min_port <= port <= acl[j].max_port)):
+            return j
+    return -1
+
+
+def _cidr_checksum(nets, acl) -> int:
+    import zlib
+    text = "\n".join(map(repr, nets))
+    if acl is not None:
+        text += "\n" + "\n".join(map(repr, acl))
+    return zlib.crc32(text.encode())
+
+
 class CidrMatcher:
     """Device-backed ordered first-match CIDR matcher (routes / ACL)."""
 
@@ -1077,11 +1098,7 @@ class CidrMatcher:
         cached = self._cksum
         if cached is not None and cached[0] is snap:
             return cached[1]
-        import zlib
-        text = "\n".join(map(repr, snap[1]))
-        if snap[2] is not None:
-            text += "\n" + "\n".join(map(repr, snap[2]))
-        v = zlib.crc32(text.encode())
+        v = _cidr_checksum(snap[1], snap[2])
         self._cksum = (snap, v)
         return v
 
@@ -1095,13 +1112,7 @@ class CidrMatcher:
 
     def oracle_snap(self, snap: tuple, addr: bytes,
                     port: Optional[int] = None) -> int:
-        nets, acl = snap[1], snap[2]
-        for j, net in enumerate(nets):
-            if net.contains_ip(addr) and (
-                    port is None or acl is None or
-                    (acl[j].min_port <= port <= acl[j].max_port)):
-                return j
-        return -1
+        return _cidr_scan(snap[1], snap[2], addr, port)
 
     def index_snap(self, snap: tuple, addr: bytes,
                    port: Optional[int] = None) -> int:
@@ -1176,3 +1187,303 @@ class CidrMatcher:
             if jax.process_count() <= 1:
                 return out  # async: caller syncs + slices
         return M.to_local(out)[:n]
+
+
+# ------------------------------------------------- a set of ordered tables
+#
+# A switch holds one RouteTable a VNI (vswitch/Table.java:13). Served as
+# one CidrMatcher each, a burst that names N VPCs is N device batches —
+# N launch floors for a few lookups apiece. A CidrTableSet keeps every
+# table of one owner on the device behind ONE program
+# (ops/hashmatch.stack_cidr_tables, cidr_set_match): a lookup names its
+# table, a batch is one launch however many tables it names. Each table
+# is compiled on its own and kept on the host, so a change to one VPC
+# rebuilds that VPC's cuckoo tables and restacks the rest as they are.
+
+_SET_TABLE_BUILDS = [0]  # per-table host builds, all sets (see note_launch)
+_NO_TABLE = repeat(-1)
+
+
+def cidr_set_table_builds_total() -> int:
+    return _SET_TABLE_BUILDS[0]
+
+
+def cidr_set_tables() -> dict:
+    """{family: tables held} over the live sets, for /metrics."""
+    with _gen_lock:
+        sets = [m for m in _MATCHERS if isinstance(m, CidrTableSet)]
+    out: dict = {}
+    for ts in sets:
+        out[ts.family] = out.get(ts.family, 0) + len(ts.snapshot().tables)
+    return out
+
+
+class _SetTable(NamedTuple):
+    """One table of a set generation, as the host holds it."""
+    tid: int                  # its row of the stacked device arrays
+    nets: list
+    acl: Optional[list]
+    index: object             # CidrIndex past SMALL_TABLE entries
+    hashed: object            # its own compiled HashCidrTable ("jax")
+
+
+class _SetSnap(NamedTuple):
+    """One published generation of a set: what a batch reads, whole."""
+    dev: Optional[dict]
+    tables: dict              # view key -> _SetTable
+    tid_of: dict              # view key -> tid
+    total: int                # entries over all tables
+    gated: bool               # some table is an ACL: ports are compared
+
+
+class CidrTableView:
+    """One table of a CidrTableSet, with the face of a CidrMatcher where
+    a VpcNetwork and ClassifyService.submit_cidr use one. It holds no
+    table itself: every read goes to its set's published generation,
+    under its own key (never reused, so a released view's late lookups
+    find nothing rather than a later tenant's table)."""
+
+    _kind = "cidr"
+
+    def __init__(self, table_set: "CidrTableSet", key: int):
+        self.table_set = table_set
+        self.key = key
+        self._cksum = None  # (table, crc32): see CidrMatcher.checksum
+
+    @property
+    def backend(self) -> str:
+        return self.table_set.backend
+
+    def set_networks(self, networks: Sequence,
+                     acl: Optional[Sequence[AclRule]] = None,
+                     wait: bool = True) -> None:
+        """Install this table's next generation through the background
+        TableInstaller: its own cuckoo tables are rebuilt, the set's
+        other tables restacked as they are, the whole set republished
+        by one swap."""
+        self._submit((list(networks),
+                      list(acl) if acl is not None else None), wait)
+
+    def release(self, wait: bool = True) -> None:
+        """Give the table back (Switch.del_network): the next generation
+        holds nothing under this view, and its lookups answer -1."""
+        self._submit(None, wait)
+
+    def _submit(self, args, wait: bool) -> None:
+        t = TableInstaller.get().submit(self, args)
+        if wait:
+            t.ev.wait()
+            if t.exc is not None:
+                raise t.exc
+
+    def _install(self, args) -> None:
+        self.table_set._install_table(self, args)
+
+    def snapshot(self) -> Optional[_SetTable]:
+        """This table in the published generation (None: it holds
+        nothing). The same object until this table is installed again,
+        whatever happens to the set's other tables."""
+        return self.table_set.snapshot().tables.get(self.key)
+
+    def size(self) -> int:
+        tab = self.snapshot()
+        return len(tab.nets) if tab is not None else 0
+
+    def checksum(self) -> int:
+        tab = self.snapshot()
+        cached = self._cksum
+        if cached is not None and cached[0] is tab:
+            return cached[1]
+        v = _cidr_checksum((), None) if tab is None \
+            else _cidr_checksum(tab.nets, tab.acl)
+        self._cksum = (tab, v)
+        return v
+
+    def match(self, addrs: Sequence[bytes],
+              ports: Optional[Sequence[int]] = None) -> np.ndarray:
+        return self.table_set.match([self] * len(addrs), addrs, ports)
+
+    def oracle_one(self, addr: bytes, port: Optional[int] = None) -> int:
+        ts = self.table_set
+        return ts.oracle_snap(ts.snapshot(), addr, port, self.key)
+
+    def match_one(self, addr: bytes, port: Optional[int] = None) -> int:
+        # the crossover is the set's: one device batch serves them all
+        ts = self.table_set
+        if ts.backend == "host" or ts.size() <= SMALL_TABLE:
+            return ts.index_snap(ts.snapshot(), addr, port, self.key)
+        return int(self.match([addr], None if port is None else [port])[0])
+
+
+class CidrTableSet:
+    """Many ordered CIDR tables on one device behind one program; hands
+    out a CidrTableView a table. The ClassifyService interface is a
+    CidrMatcher's with one more column: the view each lookup names."""
+
+    _kind = "cidr"
+    BACKENDS = ("jax", "host")
+
+    def __init__(self, family: str = "any", backend: Optional[str] = None):
+        self.backend = backend or default_backend()
+        if self.backend not in self.BACKENDS:
+            raise ValueError(f"no table set on backend {self.backend!r}: "
+                             f"keep a CidrMatcher a table there")
+        self.family = family  # /metrics label: "v4" | "v6" | "any"
+        self.generation = 0
+        self._lock = threading.Lock()   # _next_key, _tids
+        self._next_key = 0
+        self._tids: dict[int, int] = {}        # live view key -> tid
+        self._caps: Optional[dict] = None
+        self._buckets: Optional[dict] = None
+        self._pub = _SetSnap(None, {}, {}, 0, False)
+        with _gen_lock:
+            _MATCHERS.add(self)
+
+    def view(self) -> CidrTableView:
+        """A new, empty table (Switch.add_network)."""
+        with self._lock:
+            key, self._next_key = self._next_key, self._next_key + 1
+            used = set(self._tids.values())
+            self._tids[key] = next(t for t in range(len(used) + 1)
+                                   if t not in used)
+        return CidrTableView(self, key)
+
+    def _install_table(self, view: CidrTableView, args) -> None:
+        """The installer's call (its one thread: installs of a set never
+        overlap): build `view`'s table alone (args None: drop it and
+        forget the view), publish the set. Transactional: a failed build
+        leaves the published generation as it was."""
+        with self._lock:
+            tid = self._tids.get(view.key)
+        if tid is None:
+            return      # released before its install ran
+        tables = dict(self._pub.tables)
+        if args is None or not args[0]:
+            changed = tables.pop(view.key, None) is not None
+        else:
+            nets, acl = args
+            hashed = index = None
+            if self.backend == "jax":
+                hashed = H.compile_cidr_hash(nets, acl=acl)
+                _SET_TABLE_BUILDS[0] += 1
+            if len(nets) > SMALL_TABLE:
+                from .index import CidrIndex
+                index = CidrIndex(nets, acl=acl)
+            tables[view.key] = _SetTable(tid, nets, acl, index, hashed)
+            changed = True
+        if changed:
+            self._publish(tables)
+        if args is None:
+            with self._lock:
+                del self._tids[view.key]
+
+    def _publish(self, tables: dict) -> None:
+        itid = trace.current_id()  # nonzero only under a traced install
+        t_ph = time.monotonic_ns() if itid else 0
+        dev = None
+        if self.backend == "jax" and tables:
+            by_tid: list = [None] * (max(t.tid for t in tables.values()) + 1)
+            for t in tables.values():
+                by_tid[t.tid] = t.hashed
+            arrays, caps, buckets = H.stack_cidr_tables(by_tid, self._caps)
+            dev = _to_device(arrays)
+        total = sum(len(t.nets) for t in tables.values())
+        _install_phase(itid, "compile", t_ph, matcher="cidr", rules=total)
+        t_ph = time.monotonic_ns() if itid else 0
+        _sync_standby(dev)
+        _install_phase(itid, "upload", t_ph, matcher="cidr")
+        time.sleep(0)  # preemption point between compile and publish
+        t_ph = time.monotonic_ns() if itid else 0
+        if dev is not None:
+            self._caps, self._buckets = caps, buckets
+        self._pub = _SetSnap(
+            dev, tables, {k: t.tid for k, t in tables.items()}, total,
+            any(t.acl is not None for t in tables.values()))
+        self.generation += 1
+        with _gen_lock:
+            _GENERATION[0] += 1
+        _install_phase(itid, "swap", t_ph, matcher="cidr",
+                       generation=self.generation)
+
+    def published_table_bytes(self) -> int:
+        dev = self._pub.dev
+        if not dev:
+            return 0
+        return int(sum(getattr(v, "nbytes", 0) for v in dev.values()))
+
+    def bucket_stat(self) -> Optional[dict]:
+        """The set's unified bucket layout (see CidrMatcher.bucket_stat)."""
+        return self._buckets
+
+    def match(self, views: Sequence[CidrTableView], addrs: Sequence[bytes],
+              ports: Optional[Sequence[int]] = None) -> np.ndarray:
+        """-> int32 [B]: lookup b's first match in views[b]'s table, by
+        that table's own indices; -1 for none. One dispatch."""
+        snap = self._pub
+        keys = [v.key for v in views]
+        if self.backend == "host":
+            return np.array(
+                [self.index_snap(snap, a, None if ports is None else ports[i],
+                                 keys[i]) for i, a in enumerate(addrs)],
+                np.int32)
+        return np.asarray(self.dispatch_snap(snap, addrs, ports, keys))
+
+    # ---- ClassifyService API (rules/service.py) ----
+
+    def size(self) -> int:
+        return self._pub.total
+
+    def snapshot(self) -> _SetSnap:
+        return self._pub
+
+    @staticmethod
+    def snap_payload(snap: _SetSnap):
+        return None     # a view registers no payload
+
+    @staticmethod
+    def oracle_snap(snap: _SetSnap, addr: bytes, port: Optional[int],
+                    key: int) -> int:
+        tab = snap.tables.get(key)
+        if tab is None:
+            return -1
+        return _cidr_scan(tab.nets, tab.acl, addr, port)
+
+    def index_snap(self, snap: _SetSnap, addr: bytes, port: Optional[int],
+                   key: int) -> int:
+        """The host's answer for one lookup of the view `key` (failover,
+        lone queries): that table's CidrIndex, or its ordered scan."""
+        note_serving()
+        tab = snap.tables.get(key)
+        if tab is None:
+            return -1
+        if tab.index is None:
+            return _cidr_scan(tab.nets, tab.acl, addr, port)
+        return tab.index.lookup(addr, None if tab.acl is None else port)
+
+    def dispatch_snap(self, snap: _SetSnap, addrs: Sequence[bytes],
+                      ports: Optional[Sequence[int]],
+                      keys: Sequence[int],
+                      pad_to: Optional[int] = None, sync: bool = True):
+        """One batch over the snapshotted generation, whatever tables it
+        names by their views' keys (see CidrMatcher.dispatch_snap). A
+        lookup whose view holds no table in this generation is encoded
+        as a pad row."""
+        note_serving()
+        n = len(addrs)
+        if snap.dev is None or not n:
+            return np.full(n, -1, np.int32)
+        a16, fam, p = _encode_addrs(
+            addrs, ports if snap.gated else None, pad_to, items=n)
+        t0 = time.monotonic_ns() if trace.SAMPLE else 0
+        col = np.fromiter(map(snap.tid_of.get, keys, _NO_TABLE),
+                          np.int32, n)
+        tid = np.zeros(a16.shape[0], np.int32)
+        np.maximum(col, 0, out=tid[:n])
+        fam[:n][col < 0] = -1
+        if t0:
+            trace.note_span(trace.current_id(), "engine", "table_set", t0,
+                            time.monotonic_ns() - t0,
+                            items=len(np.unique(col[col >= 0])),
+                            parent="dispatch")
+        with launch_span("cidr", a16.shape[0]):
+            return H.cidr_set_jit(snap.dev, a16, fam, tid, p)

@@ -344,6 +344,7 @@ class ClassifyService:
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
         # key -> (kind, matcher, list[_Req]); key identifies the matcher
+        # (a table set, for lookups of its views)
         self._pending: dict[int, tuple[str, object, list[_Req]]] = {}
         self._thread: Optional[threading.Thread] = None
         self._closed = False
@@ -362,8 +363,17 @@ class ClassifyService:
     def submit_cidr(self, matcher, addr: bytes, port: Optional[int],
                     cb: Callable[[int, object], None], loop=None) -> None:
         """Queue one route/ACL lookup; cb(first-match idx, payload), -1
-        for none. port=None skips ACL port-range gating entirely."""
-        self._submit("cidr", matcher, (addr, port), cb, loop)
+        for none. port=None skips ACL port-range gating entirely. A
+        view of a table set (engine.CidrTableView) is filed under its
+        set, the view's key riding in the payload (an int: the tuple
+        holds nothing the collector tracks): lookups of every table of
+        the set coalesce into one device batch, each answered from the
+        table it names."""
+        ts = getattr(matcher, "table_set", None)
+        if ts is not None:
+            self._submit("cidr", ts, (addr, port, matcher.key), cb, loop)
+        else:
+            self._submit("cidr", matcher, (addr, port), cb, loop)
 
     def submit_classify_pick(self, pair, hint: Hint, ip: bytes,
                              port: Optional[int],
@@ -463,8 +473,8 @@ class ClassifyService:
             if kind in ("hint", "cpick"):
                 # cpick: the FusedPair host lane -> (verdict, pick)
                 i = matcher.index_snap(snap, payload)
-            else:
-                i = matcher.index_snap(snap, payload[0], payload[1])
+            else:   # (addr, port) and, for a table set, the view's key
+                i = matcher.index_snap(snap, *payload)
         except MemoryError:
             raise
         except Exception:
@@ -821,6 +831,10 @@ class ClassifyService:
         ports = [r.payload[1] for r in reqs]
         if ports[0] is None:  # uniform batches only (see _split_uniform)
             ports = None
+        if len(reqs[0].payload) > 2:    # a table set: the view keys' column
+            return matcher.dispatch_snap(
+                snap, addrs, ports, [r.payload[2] for r in reqs],
+                pad_to=cap, sync=sync)
         return matcher.dispatch_snap(snap, addrs, ports, pad_to=cap,
                                      sync=sync)
 
@@ -836,8 +850,7 @@ class ClassifyService:
         (rules/index.py parity tests), O(table) cheaper per query."""
         if kind in ("hint", "cpick"):
             return [matcher.index_snap(snap, r.payload) for r in reqs]
-        return [matcher.index_snap(snap, r.payload[0], r.payload[1])
-                for r in reqs]
+        return [matcher.index_snap(snap, *r.payload) for r in reqs]
 
     def _deliver(self, reqs: list[_Req], idxs, payload=None,
                  kind: str = "hint", tid: int = 0) -> None:

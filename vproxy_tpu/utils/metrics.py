@@ -445,6 +445,17 @@ class GlobalInspection:
                            "overflow_share")):
             self.registry.gauge_f(
                 name, lambda key=key: self._engine_cidr_bucket(key))
+        # cidr table sets (engine.CidrTableSet: a switch's RouteTables,
+        # one program): tables held by family, and per-table host builds
+        # — a one-VPC route change moves the counter by that VPC alone
+        for fam in ("v4", "v6", "any"):
+            self.registry.gauge_f(
+                "vproxy_engine_cidr_set_tables",
+                lambda fam=fam: self._engine_cidr_set_tables(fam),
+                family=fam)
+        self.registry.gauge_f(
+            "vproxy_engine_cidr_set_table_builds_total",
+            lambda: self._engine_stat("cidr_set_table_builds_total"))
         # fused-dispatch accounting (rules/engine.py note_launch): total
         # device launches on the dispatch path and how many batches rode
         # the fused one-launch program — the scrape-verifiable form of
@@ -610,6 +621,13 @@ class GlobalInspection:
         import sys  # scrape must not force a jax import
         eng = sys.modules.get("vproxy_tpu.rules.engine")
         return 0.0 if eng is None else float(eng.cidr_bucket_stat()[key])
+
+    @staticmethod
+    def _engine_cidr_set_tables(family: str) -> float:
+        import sys  # scrape must not force a jax import
+        eng = sys.modules.get("vproxy_tpu.rules.engine")
+        return 0.0 if eng is None \
+            else float(eng.cidr_set_tables().get(family, 0))
 
     @staticmethod
     def _engine_stat(name: str) -> float:

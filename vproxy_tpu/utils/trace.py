@@ -31,7 +31,9 @@ process-wide bounded buffer:
   one batch: `items` real queries, `cpu_ns`), launch (the jitted call: enqueue AND the
   implicit upload of its numpy arguments; `kind`, `fused`, `bucket`),
   d2h_sync (the blocking `np.asarray` of the result), deliver (the
-  callback loop: `items`, `cpu_ns`). Per sampled request: queue_wait
+  callback loop: `items`, `cpu_ns`), table_set (a batch of a
+  CidrTableSet: forming its table-id column; `items` distinct tables
+  the batch names). Per sampled request: queue_wait
   (`batch`), submit_lock_wait (a submitter waiting for the service's
   lock), classify_inline / host_index fallbacks.
 * **install** — the TableInstaller (rules/engine.py): every standby
@@ -272,7 +274,7 @@ SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
          ("engine", "dispatch"), ("engine", "encode"), ("engine", "launch"),
          ("engine", "d2h_sync"), ("engine", "deliver"),
          ("engine", "queue_wait"), ("engine", "submit_lock_wait"),
-         ("runtime", "gc_pause"))
+         ("engine", "table_set"), ("runtime", "gc_pause"))
 # bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
 TOTAL_BUCKETS = 27
 

@@ -200,7 +200,12 @@ class VpcNetwork:
                  mac_timeout_ms: int = MAC_TABLE_TIMEOUT,
                  arp_timeout_ms: int = ARP_TABLE_TIMEOUT,
                  matcher_backend: Optional[str] = None,
-                 annotations: Optional[dict] = None):
+                 annotations: Optional[dict] = None,
+                 route_sets: Optional[tuple] = None):
+        """route_sets: the owning switch's (v4, v6) CidrTableSet — this
+        VPC's routes are then one table of each, behind the set's one
+        program; without them (a VPC on its own, a backend that has no
+        set) it keeps a CidrMatcher a family."""
         self.vni = vni
         self.v4net = v4net
         self.v6net = v6net
@@ -211,26 +216,48 @@ class VpcNetwork:
         self.arps = ArpTable(arp_timeout_ms)
         self.ips = SyntheticIpHolder()
         self.routes = RouteTable()
-        self._matcher_v4 = CidrMatcher(backend=matcher_backend)
-        self._matcher_v6 = CidrMatcher(backend=matcher_backend)
+        if route_sets is not None:
+            self._matcher_v4, self._matcher_v6 = (
+                ts.view() for ts in route_sets)
+        else:
+            self._matcher_v4 = CidrMatcher(backend=matcher_backend)
+            self._matcher_v6 = CidrMatcher(backend=matcher_backend)
         self.on_route_change = None  # see MacTable.on_change
         self.conntrack = None  # installed by the L4 stack
 
     # -------------------------------------------------------------- routes
 
-    def add_route(self, r: RouteRule) -> None:
+    def add_route(self, r: RouteRule, sync: bool = True) -> None:
+        """sync=False: the caller adds more and calls sync_routes() once
+        (a config replay: a table build a route is O(n^2) a VPC)."""
         self.routes.add(r)
-        self._sync_routes()
+        if sync:
+            self.sync_routes()
+
+    def set_routes(self, rules) -> None:
+        """Replace the VPC's routes with `rules`, added in order (so the
+        table holds them as RouteTable.addRule would), in ONE install."""
+        routes = RouteTable()
+        for r in rules:
+            routes.add(r)
+        self.routes = routes
+        self.sync_routes()
 
     def remove_route(self, alias: str) -> None:
         self.routes.remove(alias)
-        self._sync_routes()
+        self.sync_routes()
 
-    def _sync_routes(self) -> None:
+    def sync_routes(self) -> None:
         self._matcher_v4.set_networks([r.rule for r in self.routes.rules_v4])
         self._matcher_v6.set_networks([r.rule for r in self.routes.rules_v6])
         if self.on_route_change is not None:
             self.on_route_change()
+
+    def release(self) -> None:
+        """The switch dropped this VPC: give the set's tables back."""
+        for m in (self._matcher_v4, self._matcher_v6):
+            if hasattr(m, "table_set"):
+                m.release()
 
     def route_lookup(self, ip: bytes) -> Optional[RouteRule]:
         """LPM through the classify engine (insert order = priority,
@@ -245,27 +272,40 @@ class VpcNetwork:
         return rules[i] if i >= 0 else None
 
     def route_lookup_batch(self, addrs) -> list:
-        """Batched LPM for a drained packet burst: ONE matcher dispatch
-        per family instead of per-packet match_one (which pays a device
-        dispatch each on big tables). -> [Optional[RouteRule]] aligned
-        with addrs."""
-        from ..rules.engine import SMALL_TABLE
-        out: list = [None] * len(addrs)
-        for rules, m, fam_len in (
-                (self.routes.rules_v4, self._matcher_v4, 4),
-                (self.routes.rules_v6, self._matcher_v6, 16)):
-            idx = [i for i, a in enumerate(addrs) if len(a) == fam_len]
-            if not idx or not rules:
-                continue
-            if len(rules) <= SMALL_TABLE:
-                # small tables: match_one's host scan beats a dispatch
-                for i in idx:
-                    r = m.match_one(addrs[i])
-                    if r >= 0:
-                        out[i] = rules[r]
-                continue
-            res = m.match([addrs[i] for i in idx])
-            for i, r in zip(idx, res):
-                if r >= 0:
-                    out[i] = rules[int(r)]
-        return out
+        """Batched LPM for a drained packet burst of this VPC (see
+        route_lookup_burst). -> [Optional[RouteRule]] aligned with
+        addrs."""
+        return route_lookup_burst([(self, a) for a in addrs])
+
+
+def route_lookup_burst(lookups) -> list:
+    """Batched LPM for a drained packet burst, whatever VPCs it names:
+    lookups [(VpcNetwork, dst)] -> [Optional[RouteRule]]. ONE matcher
+    dispatch a family for all the VPCs whose routes live in one table
+    set (a switch's), one a VPC and family for those that keep their own
+    matchers — instead of per-packet match_one, which pays a device
+    dispatch each on big tables."""
+    from ..rules.engine import SMALL_TABLE
+    out: list = [None] * len(lookups)
+    groups: dict[int, tuple] = {}   # the set, or the VPC's own matcher
+    for i, (net, a) in enumerate(lookups):
+        m = net._matcher_v4 if len(a) == 4 else net._matcher_v6
+        owner = getattr(m, "table_set", m)
+        g = groups.setdefault(id(owner), (owner, [], []))
+        g[1].append(i)
+        g[2].append(m)
+    for owner, idx, ms in groups.values():
+        if owner.size() <= SMALL_TABLE:
+            # small tables: match_one's host scan beats a dispatch
+            res = [m.match_one(lookups[i][1]) for i, m in zip(idx, ms)]
+        elif owner is ms[0]:
+            res = owner.match([lookups[i][1] for i in idx])
+        else:
+            res = owner.match(ms, [lookups[i][1] for i in idx])
+        for i, r in zip(idx, res):
+            if r >= 0:
+                net, a = lookups[i]
+                rules = net.routes.rules_v4 if len(a) == 4 \
+                    else net.routes.rules_v6
+                out[i] = rules[int(r)]
+    return out

@@ -14,7 +14,7 @@ from typing import Optional
 
 from ..utils.log import Logger
 from . import swmetrics
-from .network import VpcNetwork
+from .network import VpcNetwork, route_lookup_burst
 from .packets import (ARP_REPLY, ARP_REQUEST, BROADCAST_MAC, ETHER_TYPE_ARP,
                       ETHER_TYPE_IPV4, ETHER_TYPE_IPV6, ICMP_ECHO_REPLY,
                       ICMP_ECHO_REQ, ICMP_TIME_EXCEEDED, ICMPV6_ECHO_REPLY,
@@ -216,17 +216,11 @@ class NetworkStack:
         self._route_with(net, ether, ip, v6, net.route_lookup(ip.dst))
 
     def _route_flush(self, pend: list) -> None:
-        groups: dict[int, list[int]] = {}
-        nets: dict[int, VpcNetwork] = {}
-        for i, (net, _e, _ip, _v) in enumerate(pend):
-            groups.setdefault(id(net), []).append(i)
-            nets[id(net)] = net
-        for key, idxs in groups.items():
-            net = nets[key]
-            rules = net.route_lookup_batch([pend[i][2].dst for i in idxs])
-            for i, rule in zip(idxs, rules):
-                n_, e_, ip_, v6_ = pend[i]
-                self._route_with(n_, e_, ip_, v6_, rule)
+        """The burst's deferred lookups, all VPCs in one call."""
+        rules = route_lookup_burst([(net, ip.dst)
+                                    for net, _e, ip, _v in pend])
+        for (net, ether, ip, v6), rule in zip(pend, rules):
+            self._route_with(net, ether, ip, v6, rule)
 
     def _route_with(self, net: VpcNetwork, ether: Ethernet, ip, v6: bool,
                     rule) -> None:

@@ -75,6 +75,10 @@ class Switch:
         self.bare_access = bare_vxlan_access or SecurityGroup.allow_all()
         self.matcher_backend = matcher_backend
         self.networks: dict[int, VpcNetwork] = {}
+        # the VPCs' route tables as ONE table set a family (one device
+        # batch a burst, however many VPCs it names); made with the
+        # first VPC, None on a backend that has no set
+        self._route_sets: Optional[tuple] = None
         # user -> (key, vni, password); password kept for config persistence
         # (Shutdown.currentConfig serializes users with their passwords)
         self.users: dict[str, tuple[bytes, int, str]] = {}
@@ -462,7 +466,8 @@ class Switch:
             raise ValueError(f"vpc {vni} already exists")
         net = VpcNetwork(vni, v4net, v6net, self.mac_table_timeout_ms,
                          self.arp_table_timeout_ms, self.matcher_backend,
-                         annotations=annotations)
+                         annotations=annotations,
+                         route_sets=self.route_sets())
         # every table mutation (mapping changes only, not timestamp
         # refreshes) invalidates the native flow cache via one atomic
         net.macs.on_change = self._gen_bump
@@ -476,8 +481,22 @@ class Switch:
     def del_network(self, vni: int) -> None:
         if vni not in self.networks:
             raise KeyError(vni)
-        del self.networks[vni]
+        net = self.networks.pop(vni)
         self._gen_bump()
+        net.release()
+
+    def route_sets(self) -> Optional[tuple]:
+        """(v4, v6) CidrTableSet the VPCs' routes live in, where the
+        matcher backend (the configured one, else the engine's default)
+        has a set; None where every VPC keeps its own matchers."""
+        if self._route_sets is None:
+            from ..rules.engine import CidrTableSet, default_backend
+            backend = self.matcher_backend or default_backend()
+            if backend not in CidrTableSet.BACKENDS:
+                return None
+            self._route_sets = (CidrTableSet("v4", backend),
+                                CidrTableSet("v6", backend))
+        return self._route_sets
 
     def add_user(self, user: str, password: str, vni: int) -> None:
         """user: 3-8 chars [a-zA-Z0-9], stored '+'-padded to 8 (the wire
