@@ -453,9 +453,12 @@ def _install_under_load(svc, hm, hints, want_h, rules2, changed: int,
     """hm.set_rules(rules2) while a closed loop (window 64) keeps hint
     queries in flight over rules the change cannot touch; then *probe*
     must answer *changed*. Gates: generation +1, new rule serves, every
-    query under the install right, no failover."""
+    query under the install right, no failover, and the publish froze
+    the new generation out of the collector's reach (utils/heap)."""
     from vproxy_tpu.rules import engine as E
+    from vproxy_tpu.utils import heap
     gen0, total0 = hm.generation, E.generation_total()
+    frozen0 = heap.freezes_total("publish")
     stop = threading.Event()
     bg = {"n": 0, "wrong": 0}
     idxs = [i for i in range(len(hints)) if want_h[i] != changed]
@@ -503,10 +506,16 @@ def _install_under_load(svc, hm, hints, want_h, rules2, changed: int,
          f"width: queries under the install: n={bg['n']} wrong="
          f"{bg['wrong']} failovers={svc.stats.failovers} "
          f"last={svc.stats.last_failover!r}")
+    gate(heap.freezes_total("publish") == frozen0 + 1
+         and heap.frozen_objects() >= len(rules2),
+         f"width: the publish under load froze "
+         f"{heap.freezes_total('publish') - frozen0} times, "
+         f"{heap.frozen_objects()} objects frozen")
     say(f"width: generation {gen0}->{hm.generation} installed under load "
         f"in {install_s:.1f}s (paced standby build), {bg['n']} queries "
         f"answered meanwhile, wrong={bg['wrong']}; the changed rule "
-        f"answers {got}")
+        f"answers {got}; {heap.frozen_objects()} objects frozen, "
+        f"{heap.reexaminations_total()} re-examinations")
     return {"seconds": round(install_s, 2), "queries_during": bg["n"]}
 
 

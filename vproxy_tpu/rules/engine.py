@@ -64,7 +64,7 @@ from ..ops import hashmatch as H
 from ..ops import tables as T
 from ..ops.bitmatch import unpack_bits
 from ..ops.matchers import cidr_match_jit, hint_match_jit, table_arrays
-from ..utils import trace
+from ..utils import heap, trace
 from ..utils.log import Logger
 from . import oracle
 from .ir import AclRule, Hint, HintRule, Proto
@@ -460,6 +460,10 @@ class TableInstaller:
                         int((time.monotonic() - t0) * 1e9),
                         matcher=getattr(matcher, "_kind", "?"))
                 _swap_hist().observe((time.monotonic() - t0) * 1e3)
+                # the generation just published is long-lived: out of
+                # the collector's reach before the waiters go on, so
+                # no later full collection walks it (utils/heap)
+                heap.settle("publish", idle=not serving_recent())
             except MemoryError as e:
                 # OOM keeps the log-then-die contract (utils/oom), but
                 # the waiters must still see a FAILED install — a

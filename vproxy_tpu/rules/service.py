@@ -102,10 +102,10 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from ..utils import sketch, trace
+from ..utils import heap, sketch, trace
 from ..utils.log import Logger
 from ..utils.metrics import CLASSIFY_KINDS
-from .engine import SMALL_TABLE, pad_batch
+from .engine import SMALL_TABLE, pad_batch, serving_recent
 from .ir import Hint
 
 _log = Logger("classify")
@@ -413,6 +413,13 @@ class ClassifyService:
                 ent[2].append(_Req(payload, cb, loop, tid))
             if not inline:
                 if self._thread is None:
+                    # what the process built since its last install
+                    # (listeners, groups, its callers' closures) is as
+                    # long-lived as the tables: frozen before the
+                    # dispatcher exists, once (utils/heap). Here and
+                    # not in _run: there the submitters would queue a
+                    # whole window behind the dispatcher's first wake
+                    heap.settle("serve_start", idle=not serving_recent())
                     self._thread = threading.Thread(
                         target=self._run, name="classify-dispatch",
                         daemon=True)

@@ -467,6 +467,19 @@ class GlobalInspection:
         self.registry.gauge_f("vproxy_engine_fused_dispatches_total",
                               lambda: self._engine_stat(
                                   "fused_dispatches_total"))
+        # the collector and the rule heap (utils/heap): objects frozen
+        # out of the collector's reach, the freezes by the event that
+        # made them, and the full re-examinations the growth rule asked
+        from . import heap as _heap
+        self.registry.gauge_f("vproxy_runtime_heap_frozen_objects",
+                              lambda: float(_heap.frozen_objects()))
+        for ev in _heap.EVENTS:
+            self.registry.gauge_f(
+                "vproxy_runtime_heap_freezes_total",
+                lambda ev=ev: float(_heap.freezes_total(ev)), event=ev)
+        self.registry.gauge_f(
+            "vproxy_runtime_heap_reexaminations_total",
+            lambda: float(_heap.reexaminations_total()))
         # cluster plane (vproxy_tpu/cluster): fleet membership, rule
         # generation convergence, and the step-synchronized dispatch
         # clock — all 0 until a ClusterNode boots
