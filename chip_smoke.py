@@ -24,6 +24,16 @@ Legs, all through the entry points a user reaches:
   (device_queries == requests, oracle_queries == 0, failovers == 0).
   The same bursts under the default `auto` policy print the
   inline/device split (information, not a gate).
+* grouped — the same served path over `source` groups: a second
+  upstream of 8 server-groups of method `source`, 3 id-backends each, so
+  its accept path submits classify AND the matched group's pick in one
+  call (maglev.GroupedPair, one launch a batch). A burst, then one
+  backend is stopped and its health check takes it down (one group's
+  row is rebuilt, no other), then a second burst: on both sides of the
+  edge every response comes from the backend the host planes name
+  (rules/oracle.py for the group, ServerGroup.next for the member), the
+  device made every pick, and after the edge nobody reaches the dead
+  backend.
 * width — the north-star table (100k hint rules, 50k routes, 5k ACLs;
   benchmark/gen.py, the tables of the northstar-100k cells) installed
   through set_rules/set_networks (the
@@ -164,6 +174,7 @@ class IdBackends:
         self.sel = selectors.DefaultSelector()
         self.ports: list[int] = []
         self._stop = False
+        self._dead: set = set()     # listeners to close, by backend
         for i in range(n):
             s = socket.socket()
             s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
@@ -176,8 +187,17 @@ class IdBackends:
                                        name="id-backends")
         self.thread.start()
 
+    def stop(self, i: int) -> None:
+        """Backend i goes away: its listener is closed (on the selector
+        thread), so the next health check finds the port refused."""
+        self._dead.add(i)
+
     def _run(self) -> None:
         while not self._stop:
+            for key in [k for k in self.sel.get_map().values()
+                        if k.data[0] == "listen" and k.data[1] in self._dead]:
+                self.sel.unregister(key.fileobj)
+                key.fileobj.close()
             for key, _ in self.sel.select(0.2):
                 kind, i, buf = key.data
                 sock = key.fileobj
@@ -361,6 +381,132 @@ def served_leg(n_groups: int = 256, bursts: int = 3,
              f"{auto['failovers']} last={auto['last_failover']!r}")
         ev.update(device=dev, auto=auto, backend=m.backend,
                   generation=m.generation)
+    finally:
+        os.environ.pop("VPROXY_TPU_CLASSIFY", None)
+        app.close()
+        ClassifyService.reset()
+        backends.close()
+    return ev
+
+
+# ------------------------------------------------------------ grouped leg
+
+def grouped_leg(n_groups: int = 8, per_group: int = 3,
+                burst: int = 64) -> dict:
+    from vproxy_tpu.control.app import Application
+    from vproxy_tpu.control.command import Command
+    from vproxy_tpu.rules import engine as E
+    from vproxy_tpu.rules import maglev as MG
+    from vproxy_tpu.rules import oracle
+    from vproxy_tpu.rules.ir import Hint
+    from vproxy_tpu.rules.service import ClassifyService
+    from vproxy_tpu.utils.ip import parse_ip
+
+    def host_of(i: int) -> str:
+        return f"grp{i}.ns{i % 3}.smoke.example.com"
+
+    backends = IdBackends(n_groups * per_group)
+    app = Application.create()
+    ev: dict = {}
+    os.environ["VPROXY_TPU_CLASSIFY"] = "device"
+    ClassifyService.reset()
+    try:
+        Command.execute(app, "add upstream u1")
+        for i in range(n_groups):
+            Command.execute(
+                app, f"add server-group s{i} timeout 300 period 300 "
+                     f"up 1 down 2 method source")
+            for b in range(per_group):
+                Command.execute(
+                    app, f"add server b{b} to server-group s{i} address "
+                         f"127.0.0.1:{backends.ports[i * per_group + b]} "
+                         f"weight 10")
+            Command.execute(
+                app, f"add server-group s{i} to upstream u1 weight 10 "
+                     f'annotations {{"vproxy/hint-host":"{host_of(i)}"}}')
+        ups = app.upstreams["u1"]
+        groups = [app.server_groups[f"s{i}"] for i in range(n_groups)]
+        deadline = time.time() + 60
+        while time.time() < deadline and not all(
+                s.healthy for g in groups for s in g.servers):
+            time.sleep(0.05)
+        gate(all(s.healthy for g in groups for s in g.servers),
+             "grouped: backends never went healthy")
+        E.flush_installs(30)
+        gate(ups._picks.size() == n_groups,
+             f"grouped: the set holds {ups._picks.size()} tables, want "
+             f"{n_groups}")
+        Command.execute(app, "add tcp-lb lb1 address 127.0.0.1:0 "
+                             "upstream u1 protocol http-splice")
+        port = app.tcp_lbs["lb1"].bind_port
+        client = parse_ip("127.0.0.1")  # every request's source address
+        id_of = {p: i for i, p in enumerate(backends.ports)}
+        m = ups._matcher
+        hosts = [("w%d." % k if k % 2 else "") + host_of(k % n_groups)
+                 for k in range(burst)]
+
+        def one_burst(tag: str) -> dict:
+            svc = ClassifyService.get()
+            before = (svc.stats.snapshot(), dict(svc.stats.group_picks),
+                      E.dispatch_launches_total())
+            rules, handles = m.rules, m.snapshot()[3]
+            want = []
+            for h in hosts:     # the host planes: the oracle, the group
+                idx = oracle.search(rules, Hint.of_host_uri(h, "/"))
+                want.append(str(id_of[
+                    handles[idx].group.next(client).svr.port]))
+            got = _burst(port, hosts)
+            wrong = 0
+            for h, res, w in zip(hosts, got, want):
+                if isinstance(res, Exception) or res[1] != w:
+                    wrong += 1
+                    if wrong <= 5:
+                        say(f"  grouped[{tag}]: {h} -> {res!r}, want "
+                            f"backend {w}")
+            st = svc.stats.snapshot()
+            d = {k: st[k] - before[0][k] for k in (
+                "queries", "dispatches", "device_queries",
+                "oracle_queries", "failovers")}
+            d["device_picks"] = svc.stats.group_picks["device"] \
+                - before[1]["device"]
+            d["launches"] = E.dispatch_launches_total() - before[2]
+            d["wrong"] = wrong
+            d["bodies"] = sorted({r[1] for r in got
+                                  if not isinstance(r, Exception)})
+            say(f"grouped[{tag}]: {burst} requests: {d}")
+            gate(wrong == 0, f"grouped[{tag}]: {wrong} responses from a "
+                             f"backend the host planes do not name")
+            gate(d["device_queries"] == burst and d["oracle_queries"] == 0
+                 and d["failovers"] == 0 and d["device_picks"] == burst,
+                 f"grouped[{tag}]: the device did not make every pick "
+                 f"({d}, last={svc.stats.last_failover!r})")
+            gate(d["launches"] == d["dispatches"],
+                 f"grouped[{tag}]: {d['launches']} launches for "
+                 f"{d['dispatches']} batches")
+            return d
+
+        ev["before"] = one_burst("before the edge")
+        # the backend this client reaches in group 3 goes away: its
+        # clients must move, nobody else's
+        victim = groups[3].next(client).svr
+        builds = MG.set_table_builds_total()
+        t0 = time.time()
+        backends.stop(id_of[victim.port])
+        while time.time() - t0 < 30 and victim.healthy:
+            time.sleep(0.02)
+        gate(not victim.healthy, "grouped: the health check never took "
+                                 "the stopped backend down")
+        E.flush_installs(30)
+        ev["edge_s"] = round(time.time() - t0, 2)
+        ev["row_builds"] = MG.set_table_builds_total() - builds
+        gate(ev["row_builds"] == 1,
+             f"grouped: one group's edge rebuilt {ev['row_builds']} rows")
+        ev["after"] = one_burst("after the edge")
+        gate(str(id_of[victim.port]) not in ev["after"]["bodies"],
+             "grouped: a request reached the dead backend")
+        say(f"grouped: edge {ev['edge_s']}s from stop to published row, "
+            f"{ev['row_builds']} row rebuilt, remap "
+            f"{ups._picks.last_remap:.3f}")
     finally:
         os.environ.pop("VPROXY_TPU_CLASSIFY", None)
         app.close()
@@ -709,7 +855,8 @@ def main() -> int:
     # width leg, does — by design)
     for name, leg in (("native", native_leg),
                       ("width", lambda: width_leg(jlog)),
-                      ("served", served_leg)):
+                      ("served", served_leg),
+                      ("grouped", grouped_leg)):
         t0 = time.time()
         try:
             leg()

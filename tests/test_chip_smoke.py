@@ -34,6 +34,10 @@ def test_legs_at_tiny_size():
     assert width["service"]["failovers"] == 0
     assert width["fused"] == {"available": True, "kernel": "jit",
                               "packed_bytes": width["fused"]["packed_bytes"]}
+    grouped = S.grouped_leg(n_groups=4, per_group=3, burst=8)
+    assert grouped["before"]["device_picks"] == 8 == \
+        grouped["after"]["device_picks"]
+    assert grouped["row_builds"] == 1
     assert S.FAILURES == []
 
 

@@ -678,12 +678,19 @@ class ServerGroup:
                 "backends": len(st["servers"]),
                 "last_remap": round(self.maglev_last_remap, 4)}
 
-    def maglev_table(self, fam=None):
-        """(servers, table) snapshot for the current health generation
-        — the lane compiler and the parity tests read this."""
+    def maglev_row(self, fam=None):
+        """(hv, servers, table) snapshot: the table with the member list
+        it indexes and the health_version it was built at — what a copy
+        kept elsewhere (the upstream's pick-table set) checks itself
+        against before it answers for `_source_next`."""
         with self._lock:
             st = self._maglev_state(fam)
-            return list(st["servers"]), st["table"]
+            return st["hv"], list(st["servers"]), st["table"]
+
+    def maglev_table(self, fam=None):
+        """(servers, table) for the current health generation — the
+        lane compiler and the parity tests read this."""
+        return self.maglev_row(fam)[1:]
 
     def _source_next(self, source_ip: bytes, fam,
                      exclude=None) -> Optional[Connector]:

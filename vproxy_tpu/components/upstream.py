@@ -6,6 +6,10 @@ ServerGroups (seq :68-116), hint selection via searchForGroup (:187-198).
 THE difference: the linear annotation scan is replaced by the device
 HintMatcher (vproxy_tpu/rules/engine.py) — the rule table lives in HBM
 and single queries or micro-batches go through the same compiled kernel.
+Beside it the upstream owns a maglev.MaglevTableSet — one pick table a
+`source` group, a row each — and the GroupedPair over both: the async
+accept path (next_async / seek_async without a `fam`) asks for the
+group AND the group's pick in one submit.
 """
 from __future__ import annotations
 
@@ -15,6 +19,7 @@ from typing import Optional, Sequence
 
 from ..rules.engine import HintMatcher
 from ..rules.ir import Hint, HintRule
+from ..rules.maglev import GroupedPair, MaglevTableSet
 from ..utils.metrics import accept_stage_observe
 from .servergroup import Connector, ServerGroup
 
@@ -26,6 +31,24 @@ class GroupHandle:
         self.group = group
         self.weight = weight
         self.annotations = annotations or HintRule()
+        # set by Upstream.add: the group's row of the upstream's
+        # pick-table set, and the change listener that re-installs it
+        self.ref = -1
+        self.on_edge = None
+
+    def pick_row(self):
+        """What the group's row of the pick-table set holds (called on
+        the installer thread): the table `_source_next` reads at this
+        health generation, with that generation and the member list the
+        table indexes as the row's payload; None for a group that is
+        not `source` or has no healthy member."""
+        g = self.group
+        if g.method != "source":
+            return None
+        hv, servers, table = g.maglev_row()
+        if not servers:
+            return None
+        return table, [g.maglev_identity(s) for s in servers], (hv, servers)
 
     def merged_rule(self) -> HintRule:
         """Handle annotations take precedence over the group's own
@@ -46,6 +69,9 @@ class Upstream:
         # analytics attribution: the ClassifyService credits device
         # launches/batch occupancy to this upstream by this name
         self._matcher.owner_alias = alias
+        self._picks = MaglevTableSet(backend=self._matcher.backend)
+        self._pair = GroupedPair(self._matcher, self._picks)
+        self._pair.owner_alias = alias
         self._wrr_seq: list[int] = []
         self._wrr_groups: list[GroupHandle] = []
         self._wrr_cursor = 0
@@ -80,6 +106,10 @@ class Upstream:
             if any(h.group is group for h in self.handles):
                 raise ValueError(f"group {group.alias} already in upstream {self.alias}")
             h = GroupHandle(group, weight, annotations)
+            h.ref = self._picks.alloc()
+            h.on_edge = lambda: self._row_edge(h)
+            group.on_change(h.on_edge)
+            h.on_edge()     # ahead of the rules that name the row
             self.handles.append(h)
             self._recalc()
         self._fire()
@@ -91,10 +121,30 @@ class Upstream:
                 if h.group is group:
                     del self.handles[i]
                     self._recalc()
+                    group.off_change(h.on_edge)
+                    self._picks.release(h.ref)
                     break
             else:
                 raise KeyError(group.alias)
         self._fire()
+
+    def _row_edge(self, h: GroupHandle) -> None:
+        """A health or membership edge of ONE group: enqueue its own
+        row, built on the installer thread — bump and defer, the
+        listener may run under the group's lock. A group that is not
+        `source` and holds no table has nothing to install: a `wrr`
+        group's edges never reach the installer."""
+        if h.group.method == "source" \
+                or h.ref in self._picks.snapshot().rows:
+            self._picks.install(h.ref, h.pick_row, wait=False)
+
+    def close(self) -> None:
+        """The upstream is gone: its groups live on and stop feeding
+        its pick-table set."""
+        with self._lock:
+            for h in self.handles:
+                h.group.off_change(h.on_edge)
+                self._picks.release(h.ref)
 
     def set_annotations(self, group: ServerGroup, annotations: HintRule) -> None:
         with self._lock:
@@ -111,8 +161,9 @@ class Upstream:
         # the handle list is the rules' payload: published atomically
         # with the compiled table so async classify results map their
         # index through the SAME generation (see HintMatcher._pub)
-        self._matcher.set_rules([h.merged_rule() for h in self.handles],
-                                payload=list(self.handles))
+        self._pair.set_rules([h.merged_rule() for h in self.handles],
+                             payload=list(self.handles),
+                             groups=[h.ref for h in self.handles])
         groups = [h for h in self.handles if h.weight > 0]
         self._wrr_groups = groups
         self._wrr_seq = ServerGroup._wrr_compute(groups) if groups else []
@@ -255,14 +306,14 @@ class Upstream:
             accept_stage_observe("backend_pick", time.monotonic() - t0)
             cb(c)
             return
-        from ..rules.service import ClassifyService
         t_sub = time.monotonic()
 
-        def on_idx(idx: int, handles) -> None:
+        def on_idx(idx: int, handles, pick: int = -1, rows=None) -> None:
             t_idx = time.monotonic()
             accept_stage_observe("classify", t_idx - t_sub)
             if handles and 0 <= idx < len(handles):
-                c = handles[idx].group.next(source_ip, fam)
+                c = _picked(handles[idx], pick, rows) \
+                    or handles[idx].group.next(source_ip, fam)
                 if c is not None:
                     accept_stage_observe("backend_pick",
                                          time.monotonic() - t_idx)
@@ -272,7 +323,7 @@ class Upstream:
             accept_stage_observe("backend_pick", time.monotonic() - t_idx)
             cb(c)
 
-        ClassifyService.get().submit_hint(self._matcher, hint, on_idx, loop)
+        self._submit(source_ip, hint, fam, on_idx, loop)
 
     def seek_async(self, source_ip: bytes, hint: Hint, cb,
                    fam: Optional[str] = None, loop=None) -> None:
@@ -280,12 +331,52 @@ class Upstream:
         if not self.handles:
             cb(None)
             return
-        from ..rules.service import ClassifyService
 
-        def on_idx(idx: int, handles) -> None:
+        def on_idx(idx: int, handles, pick: int = -1, rows=None) -> None:
             if handles and 0 <= idx < len(handles):
-                cb(handles[idx].group.next(source_ip, fam))
+                cb(_picked(handles[idx], pick, rows)
+                   or handles[idx].group.next(source_ip, fam))
             else:
                 cb(None)
 
-        ClassifyService.get().submit_hint(self._matcher, hint, on_idx, loop)
+        self._submit(source_ip, hint, fam, on_idx, loop)
+
+    def _submit(self, source_ip: bytes, hint: Hint, fam: Optional[str],
+                on_idx, loop) -> None:
+        """One classify submit for next_async / seek_async;
+        on_idx(idx, handles[, pick, rows]). Where some group holds a
+        pick table and the caller names no family (a family narrows the
+        member set the table was built over), classify AND the matched
+        group's pick ride one submit — on backend "jax" one launch."""
+        from ..rules.service import ClassifyService
+        svc = ClassifyService.get()
+        if fam is not None or not self._picks.size():
+            svc.submit_hint(self._matcher, hint, on_idx, loop)
+            return
+
+        def on_pick(idx: int, pick: int, payload) -> None:
+            handles, rows = payload or (None, None)   # None: an error fill
+            on_idx(idx, handles, pick, rows)
+
+        svc.submit_classify_pick(self._pair, hint, source_ip, None,
+                                 on_pick, loop)
+
+
+def _picked(h: GroupHandle, pick: int, rows) -> Optional[Connector]:
+    """The connector a device pick names: member `pick` of the list the
+    group's row was built over, if the row is of the group's health
+    generation NOW — the check `_source_next` makes on its own table —
+    and the member still healthy; else None, and the caller asks the
+    group. A row is installed behind its edge (bump and defer), so
+    between a removal, a re-weighting or a health edge and the
+    installer's publish the device's pick is a stale table's and is
+    not used."""
+    if pick < 0 or h.group.method != "source":
+        return None
+    row = rows.get(h.ref)
+    if row is None or row[0] != h.group.health_version:
+        return None
+    servers = row[1]
+    if pick >= len(servers) or not servers[pick].healthy:
+        return None
+    return Connector(servers[pick], h.group)

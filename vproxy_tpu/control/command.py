@@ -451,6 +451,7 @@ def _h_ups(app: Application, c: Command):
             if users:
                 raise CmdError(f"upstream {c.alias} is in use by {users}")
         del app.upstreams[c.alias]
+        ups.close()
         return "OK"
     raise CmdError(f"unsupported action {c.action} for upstream")
 
@@ -520,6 +521,7 @@ def _h_sg(app: Application, c: Command):
             if c.params["method"] not in ServerGroup.METHODS:
                 raise CmdError(f"unknown method {c.params['method']}")
             sg.method = c.params["method"]
+            sg._fire_change()   # lane entries, an upstream's pick row
         if "annotations" in c.params:
             sg.annotations = _anno_to_rule(c.params["annotations"])
             for ups in app.upstreams.values():

@@ -236,3 +236,33 @@ def fused_classify_pick(ht: dict, q: dict, mtab, slots):
 
 
 fused_jit = jax.jit(fused_classify_pick)
+
+
+def fused_group_pick(ht: dict, q: dict, rule_group, owner, set_tab, slots):
+    """The grouped fused program: hint verdict, then the pick from the
+    Maglev table of the server-group the matched rule names — a
+    dependent chain (verdict -> rule_group row -> that group's table)
+    inside one compiled launch. -> int32 [B, 2] (verdict, pick).
+
+    rule_group (int32 [r_cap, 2], published with the hint generation):
+    the set row the rule's group owns and the token that row was handed
+    out under, (-1, -1) for a rule that names no group. owner (int32
+    [groups_cap], published with the set generation): the token under
+    which each row's table was installed, -1 where the row holds none.
+    A row answers only under its own token, so a hint generation paired
+    with a set generation in which the row belongs to nobody yet, or to
+    another group already, answers pick -1 — never another group's
+    backend. set_tab ([groups_cap, M], rules/maglev.MaglevTableSet): one
+    table a row; `slots` are the host-side FNV slots of the one M
+    (maglev.flow_slots, the shared hash contract)."""
+    v, _level = _hint_verdict_packed(ht, q)
+    with jax.named_scope("group_pick"):
+        rg = rule_group[jnp.maximum(v, 0)]          # [B, 2]
+        row = jnp.maximum(rg[:, 0], 0)
+        ok = (v >= 0) & (rg[:, 0] >= 0) & (owner[row] == rg[:, 1])
+        p = set_tab[row, jnp.clip(slots, 0, set_tab.shape[1] - 1)]
+        p = jnp.where(ok, p.astype(jnp.int32), -1)
+    return jnp.stack([v, p], axis=1)
+
+
+group_jit = jax.jit(fused_group_pick)

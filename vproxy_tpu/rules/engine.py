@@ -533,14 +533,46 @@ def fused_dispatch(hm, hsnap: tuple, mm, msnap: tuple, hints,
     mtab, mdev = msnap[0], msnap[1]
     if mtab is None or mdev is None:
         return None
+    q, slots = _fused_encode(hsnap, len(mtab), hints, ips, ports, pad_to)
+    from ..ops import fused as F
+    with launch_span("cpick", len(slots), fused=True):
+        return F.fused_jit(fd, q, mdev, slots)
+
+
+def grouped_dispatch(hsnap: tuple, ssnap, m: int, hints,
+                     ips: Sequence[bytes],
+                     ports: Optional[Sequence[int]] = None,
+                     pad_to: Optional[int] = None):
+    """fused_dispatch for a maglev.GroupedPair: ONE launch answering
+    (verdict, pick from the table of the group the matched rule names)
+    against one (hint, pick-table set) snapshot pair — the rule -> group
+    column from the hint generation, the tables and their owner tokens
+    from the set's. None when either side has no device form (a backend
+    other than "jax", a hint generation installed without a group
+    column, a set that holds no table): the pair then picks on the
+    host."""
+    if not hints or len(hints) != len(ips):
+        return None
+    fd = hsnap[5] if len(hsnap) > 5 else None
+    col = hsnap[6] if len(hsnap) > 6 else None
+    if fd is None or not hsnap[2] or col is None or col[1] is None \
+            or ssnap.dev is None:
+        return None
+    q, slots = _fused_encode(hsnap, m, hints, ips, ports, pad_to)
+    from ..ops import fused as F
+    with launch_span("cpick", len(slots), fused=True):
+        return F.group_jit(fd, q, col[1], ssnap.dev[1], ssnap.dev[0], slots)
+
+
+def _fused_encode(hsnap: tuple, m: int, hints, ips, ports,
+                  pad_to: Optional[int]) -> tuple:
+    """The host half of one fused batch, either program's: the encoded
+    hint queries and the Maglev slots of a table of m slots, both
+    padded to the batch's bucket; counts the fused dispatch."""
     note_serving()
     q = _fused_hint_q(hsnap[0], hints, pad_to)
-    cap = q["hostb"].shape[0]
-    slots = _fused_slots(mtab, ips, ports, cap)
-    from ..ops import fused as F
     _FUSED_DISP[0] += 1
-    with launch_span("cpick", cap, fused=True):
-        return F.fused_jit(fd, q, mdev, slots)
+    return q, _fused_slots(m, ips, ports, q["hostb"].shape[0])
 
 
 def _fused_hint_q(tab, hints, pad_to: Optional[int]) -> dict:
@@ -565,14 +597,14 @@ def _encode_addrs(addrs, ports, pad_to: Optional[int],
     return a16, fam, p
 
 
-def _fused_slots(mtab, ips, ports, cap: int) -> np.ndarray:
-    """Host-side Maglev slots (maglev.flow_slots — THE one copy of the
-    slot-hash contract, so fused picks are bit-identical to every
-    other pick plane); pad rows ride slot 0 and are sliced off by the
-    caller."""
+def _fused_slots(m: int, ips, ports, cap: int) -> np.ndarray:
+    """Host-side Maglev slots of a table of m slots (maglev.flow_slots
+    — THE one copy of the slot-hash contract, so fused picks are
+    bit-identical to every other pick plane); pad rows ride slot 0 and
+    are sliced off by the caller."""
     from .maglev import flow_slots
     with encode_span(0):    # the batch's queries count at the hint encode
-        slots = flow_slots(len(mtab), ips, ports)
+        slots = flow_slots(m, ips, ports)
         if cap > len(slots):
             slots = np.concatenate([slots, np.zeros(cap - len(slots),
                                                     np.int64)])
@@ -601,9 +633,13 @@ class HintMatcher:
         # rules (e.g. Upstream's GroupHandle list) so a matched index is
         # always interpreted against the same generation it was matched
         # in; `index` is the O(probes) host-side HintIndex the latency
-        # budget policy answers lone queries from (rules/index.py)
+        # budget policy answers lone queries from (rules/index.py);
+        # then the packed fused tables, and the rule -> group column of
+        # a maglev.GroupedPair's matcher (refs, device [r_cap, 2]) —
+        # None unless set_rules was given `groups`
         self._pub: tuple = (None, None, [], payload, None)
         self._payload = payload
+        self._groups: Optional[list] = None
         self._cksum = None  # (pub-tuple, crc32) cache — see checksum()
         self._recompile()
         with _gen_lock:
@@ -614,13 +650,23 @@ class HintMatcher:
         return list(self._pub[2])  # the PUBLISHED generation
 
     def set_rules(self, rules: Sequence[HintRule], payload=None,
-                  wait: bool = True) -> None:
+                  wait: bool = True,
+                  groups: Optional[Sequence[int]] = None) -> None:
         """Install a new rule generation via the background
         TableInstaller (standby compile + atomic publish). wait=True
         (default) blocks THIS caller until the publish — dispatchers
         never block either way; wait=False returns immediately (the
-        caller reads the old generation until the swap lands)."""
-        t = TableInstaller.get().submit(self, (list(rules), payload))
+        caller reads the old generation until the swap lands).
+        groups: per rule, the ref of the maglev.MaglevTableSet row its
+        server-group owns (-1: none) — published in the same tuple as
+        the tables, so a verdict is never read against another
+        generation's column."""
+        if groups is not None and len(groups) != len(rules):
+            raise ValueError(f"{len(groups)} group refs for "
+                             f"{len(rules)} rules")
+        t = TableInstaller.get().submit(
+            self, (list(rules), payload,
+                   None if groups is None else list(groups)))
         if wait:
             t.ev.wait()
             if t.exc is not None:
@@ -631,11 +677,13 @@ class HintMatcher:
         generation (never called concurrently — one installer thread).
         Transactional: a failed compile restores the serving rule list
         so every read surface still describes the published table."""
-        rules, payload = args
+        rules, payload = args[:2]
+        groups = args[2] if len(args) > 2 else None
         old = (self._rules, self._payload, self._tab, self._dev,
-               self._caps)
+               self._caps, self._groups)
         self._rules = list(rules)
         self._payload = payload
+        self._groups = groups
         try:
             self._recompile()
         except BaseException:
@@ -643,7 +691,7 @@ class HintMatcher:
             # — a half-updated (_tab, _dev) pair would hash queries
             # with one generation's salts against the other's table
             (self._rules, self._payload, self._tab, self._dev,
-             self._caps) = old
+             self._caps, self._groups) = old
             raise
 
     def published_table_bytes(self) -> int:
@@ -720,16 +768,27 @@ class HintMatcher:
         if self.backend == "jax":
             from ..ops import fused as F
             fused_dev = _to_device(F.pack_hint_table(self._tab.arrays))
+        group_col = None    # (refs, their device column or None)
+        if self._groups is not None:
+            gdev = None
+            if fused_dev is not None:
+                import jax
+                from .maglev import group_column
+                gdev = jax.device_put(group_column(
+                    self._groups, fused_dev["pk_meta"].shape[0]))
+            group_col = (self._groups, gdev)
         _install_phase(itid, "compile", t_ph, matcher="hint",
                        rules=len(self._rules))
         t_ph = time.monotonic_ns() if itid else 0
         _sync_standby(self._dev)
         _sync_standby(fused_dev)
+        if group_col is not None:
+            _sync_standby({"rule_group": group_col[1]})
         _install_phase(itid, "upload", t_ph, matcher="hint")
         time.sleep(0)  # preemption point between compile and publish
         t_ph = time.monotonic_ns() if itid else 0
         self._pub = (self._tab, self._dev, list(self._rules), self._payload,
-                     idx, fused_dev)
+                     idx, fused_dev, group_col)
         self.generation += 1
         with _gen_lock:
             _GENERATION[0] += 1

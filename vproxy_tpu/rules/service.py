@@ -244,6 +244,11 @@ class ClassifyStats:
         # /metrics as vproxy_classify_latency_batched_total, beside the
         # histogram's own _count
         self.latency_batched = 0
+        # lookups of a maglev.GroupedPair whose pick came out of the
+        # matched group's own table (verdict >= 0 and pick >= 0), by
+        # where the pick was made; one increment a batch (on /metrics
+        # as vproxy_classify_group_picks_total{where})
+        self.group_picks = {"device": 0, "host": 0}
         # counter read-modify-writes go through `lock` (writers are the
         # dispatcher thread AND every inline-answering submit thread)
         self.lock = threading.Lock()
@@ -379,13 +384,17 @@ class ClassifyService:
                              port: Optional[int],
                              cb: Callable[[int, int, object], None],
                              loop=None) -> None:
-        """Queue one fused classify+pick against a maglev.FusedPair:
-        cb(verdict_idx, pick_idx, (hint_payload, maglev_payload)).
-        Micro-batches ride the fused ONE-launch program
-        (rules/engine.fused_dispatch); lone queries take the inline
-        host lane (hint index + O(1) maglev read), same fast-lane
-        policy as plain hint submits. port=None = source affinity
-        (the shared Maglev hash contract)."""
+        """Queue one fused classify+pick against a maglev.FusedPair or
+        GroupedPair: cb(verdict_idx, pick_idx, (hint_payload,
+        maglev_payload)). Micro-batches ride the pair's ONE-launch
+        program (rules/engine.fused_dispatch / grouped_dispatch); lone
+        queries take the inline host lane (hint index + O(1) maglev
+        read), same fast-lane policy as plain hint submits. A grouped
+        pair's pick is a slot of the table of the group the matched
+        rule names (an index into that group's published member list),
+        -1 where the verdict is -1 or its group holds no table.
+        port=None = source affinity (the shared Maglev hash
+        contract)."""
         self._submit("cpick", pair, (hint, ip, port), cb, loop)
 
     def _submit(self, kind: str, matcher, payload, cb, loop) -> None:
@@ -496,6 +505,9 @@ class ClassifyService:
         with st.lock:
             st.oracle_queries += 1
             st.max_batch = max(st.max_batch, 1)
+            if kind == "cpick" and i[0] >= 0 and i[1] >= 0 \
+                    and getattr(matcher, "grouped", False):
+                st.group_picks["host"] += 1     # a batch of one
         st.record_latency(dt)
         if big:
             self._note_lone_latency("oracle", dt)
@@ -736,9 +748,29 @@ class ClassifyService:
         if lone_big:
             self._note_lone_latency("oracle", time.monotonic() - t0)
         self.stats.bump("oracle_queries", n)
+        self._note_group_picks(matcher, idxs, "host")
         self._deliver(reqs, idxs, matcher.snap_payload(snap), kind=kind,
                       tid=tid)
         return None
+
+    def _note_group_picks(self, matcher, rows, where: str,
+                          tid: int = 0) -> None:
+        """A batch of a grouped pair, answered: count the lookups whose
+        pick came out of the matched group's table, in one vectorised
+        pass over the batch's (verdict, pick) rows. A device batch also
+        leaves the `engine/group_pick` span (items = that count)."""
+        if not getattr(matcher, "grouped", False):
+            return
+        t0 = time.monotonic_ns() if trace.SAMPLE and where == "device" \
+            else 0
+        rows = np.asarray(rows).reshape(-1, 2)
+        n = int(np.count_nonzero((rows[:, 0] >= 0) & (rows[:, 1] >= 0)))
+        with self.stats.lock:
+            self.stats.group_picks[where] += n
+        if t0:
+            trace.note_span(tid, "engine", "group_pick", t0,
+                            time.monotonic_ns() - t0, items=n,
+                            batch=len(rows))
 
     def _finish_guarded(self, inf: "_Inflight") -> None:
         """_finish_inflight behind the dispatcher's survival guard: the
@@ -782,6 +814,8 @@ class ClassifyService:
             raise
         except Exception as e:
             self._device_failed(e, n)
+        where = "host" if getattr(inf.arr, "picks_on_host", False) \
+            else "device"
         if idxs is None:
             t0 = time.monotonic()
             idxs = self._oracle_batch(inf.kind, inf.matcher, inf.snap,
@@ -789,6 +823,8 @@ class ClassifyService:
             if inf.lone_big:
                 self._note_lone_latency("oracle", time.monotonic() - t0)
             self.stats.bump("oracle_queries", n)
+            where = "host"
+        self._note_group_picks(inf.matcher, idxs, where, inf.tid)
         try:
             self._deliver(inf.reqs, idxs,
                           inf.matcher.snap_payload(inf.snap),

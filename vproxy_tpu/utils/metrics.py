@@ -456,6 +456,22 @@ class GlobalInspection:
         self.registry.gauge_f(
             "vproxy_engine_cidr_set_table_builds_total",
             lambda: self._engine_stat("cidr_set_table_builds_total"))
+        # per-group pick tables (maglev.MaglevTableSet: an Upstream's
+        # `source` groups, one program): tables held, per-group row
+        # installs — a one-group health edge moves the counter by one —
+        # and the lookups of a GroupedPair whose pick came out of the
+        # matched group's table, by where it was made
+        self.registry.gauge_f("vproxy_maglev_set_groups",
+                              lambda: self._maglev_stat("set_groups_total"))
+        self.registry.gauge_f(
+            "vproxy_maglev_set_table_builds_total",
+            lambda: self._maglev_stat("set_table_builds_total"))
+        for where in ("device", "host"):
+            self.registry.gauge_f(
+                "vproxy_classify_group_picks_total",
+                lambda where=where: self._classify_stat("group_picks",
+                                                        where),
+                where=where)
         # fused-dispatch accounting (rules/engine.py note_launch): total
         # device launches on the dispatch path and how many batches rode
         # the fused one-launch program — the scrape-verifiable form of
@@ -641,6 +657,12 @@ class GlobalInspection:
         eng = sys.modules.get("vproxy_tpu.rules.engine")
         return 0.0 if eng is None \
             else float(eng.cidr_set_tables().get(family, 0))
+
+    @staticmethod
+    def _maglev_stat(name: str) -> float:
+        import sys  # scrape must not force a jax import
+        mg = sys.modules.get("vproxy_tpu.rules.maglev")
+        return 0.0 if mg is None else float(getattr(mg, name)())
 
     @staticmethod
     def _engine_stat(name: str) -> float:

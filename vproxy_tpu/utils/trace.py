@@ -274,7 +274,8 @@ SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
          ("engine", "dispatch"), ("engine", "encode"), ("engine", "launch"),
          ("engine", "d2h_sync"), ("engine", "deliver"),
          ("engine", "queue_wait"), ("engine", "submit_lock_wait"),
-         ("engine", "table_set"), ("runtime", "gc_pause"))
+         ("engine", "table_set"), ("engine", "group_pick"),
+         ("runtime", "gc_pause"))
 # bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
 TOTAL_BUCKETS = 27
 
