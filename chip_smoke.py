@@ -772,6 +772,10 @@ def width_leg(jlog: JaxLog, n_rules: int = 100_000,
              f"(device_queries={st['device_queries']}/{total}, "
              f"oracle_queries={st['oracle_queries']}, failovers="
              f"{st['failovers']}, last={svc.stats.last_failover!r})")
+        say(f"width: {st['readback_prefetch']} of {st['dispatches']} "
+            f"device batches had their readback started at launch, "
+            f"{st['readback_kernel_waits']} read before their kernel "
+            f"was done")
         say(f"width: service counters {st}")
 
         # ---- a sample against the linear oracle (rules/oracle.py)

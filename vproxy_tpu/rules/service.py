@@ -175,9 +175,10 @@ class _Inflight:
     (the dispatcher's double buffer slot)."""
 
     __slots__ = ("kind", "matcher", "reqs", "snap", "arr", "t0",
-                 "lone_big", "tid")
+                 "lone_big", "tid", "prefetched")
 
-    def __init__(self, kind, matcher, reqs, snap, arr, t0, lone_big, tid):
+    def __init__(self, kind, matcher, reqs, snap, arr, t0, lone_big, tid,
+                 prefetched):
         self.kind = kind
         self.matcher = matcher
         self.reqs = reqs
@@ -186,6 +187,30 @@ class _Inflight:
         self.t0 = t0
         self.lone_big = lone_big
         self.tid = tid    # the batch's first sampled request, 0 = none
+        self.prefetched = prefetched  # its readback started at launch
+
+
+def _start_readback(arr) -> bool:
+    """Start the device->host copy of a batch's result now that its
+    launch has returned, so the copy runs in the runtime's threads
+    behind the next batch's encode + launch and _finish_inflight's
+    np.asarray finds the host value there. Decided from the result's
+    type: a numpy return or a host-pick wrapper has no such method and
+    is read as before. A copy that cannot be started loses nothing —
+    the batch stays in flight and the blocking read fetches it (its
+    failure is the one that degrades the batch). -> started."""
+    start = getattr(arr, "copy_to_host_async", None)
+    if start is None:
+        return False
+    try:
+        start()
+    except MemoryError:
+        raise
+    except Exception:
+        _log.error("readback prefetch failed; the batch is read "
+                   "blocking", exc=True)
+        return False
+    return True
 
 
 class _Cycle:
@@ -249,6 +274,14 @@ class ClassifyStats:
         # where the pick was made; one increment a batch (on /metrics
         # as vproxy_classify_group_picks_total{where})
         self.group_picks = {"device": 0, "host": 0}
+        # device batches whose device->host copy was started when their
+        # launch returned, and device batches whose result was not yet
+        # ready when _finish_inflight came for it (the sync then waits
+        # for the kernel, which no early copy hides); one increment a
+        # batch (on /metrics as vproxy_engine_readback_prefetch_total
+        # and vproxy_engine_readback_kernel_waits_total)
+        self.readback_prefetch = 0
+        self.readback_kernel_waits = 0
         # counter read-modify-writes go through `lock` (writers are the
         # dispatcher thread AND every inline-answering submit thread)
         self.lock = threading.Lock()
@@ -301,7 +334,8 @@ class ClassifyStats:
     def snapshot(self) -> dict:
         d = {k: getattr(self, k) for k in (
             "queries", "dispatches", "device_queries", "oracle_queries",
-            "failovers", "max_batch", "budget_reroutes", "inline_fast")}
+            "failovers", "max_batch", "budget_reroutes", "inline_fast",
+            "readback_prefetch", "readback_kernel_waits")}
         lat = self.latency_percentiles()
         if lat is not None:
             d["latency_p50_us"] = round(lat["p50_us"], 1)
@@ -733,7 +767,7 @@ class ClassifyService:
                                                  batch=n):
                     arr = self._device_submit(kind, matcher, snap, reqs)
                 return _Inflight(kind, matcher, reqs, snap, arr, t0,
-                                 lone_big, tid)
+                                 lone_big, tid, _start_readback(arr))
             except MemoryError:
                 raise
             except Exception as e:
@@ -799,6 +833,8 @@ class ClassifyService:
         n = len(inf.reqs)
         idxs = None
         try:
+            ready = getattr(inf.arr, "is_ready", None)
+            kernel_wait = ready is not None and not ready()
             with trace.span("engine", "d2h_sync", tid=inf.tid,
                             kind=inf.kind, batch=n):
                 idxs = np.asarray(inf.arr)[:n]
@@ -810,6 +846,8 @@ class ClassifyService:
                 st.device_queries += n
                 st.batches[inf.kind] += 1
                 st.batch_queries[inf.kind] += n
+                st.readback_prefetch += inf.prefetched
+                st.readback_kernel_waits += kernel_wait
         except MemoryError:
             raise
         except Exception as e:

@@ -386,6 +386,16 @@ class GlobalInspection:
             self.registry.gauge_f(
                 "vproxy_classify_batch_queries_total",
                 lambda k=k: self._classify_stat("batch_queries", k), kind=k)
+        # asynchronous readback (rules/service.py _start_readback): device
+        # batches whose device->host copy was started at launch, and
+        # device batches whose kernel had not finished when the
+        # dispatcher came for the result; over
+        # vproxy_classify_batches_total the share the early copy covers
+        # and the share it cannot help
+        for k in ("prefetch", "kernel_waits"):
+            self.registry.gauge_f(
+                f"vproxy_engine_readback_{k}_total",
+                lambda k=k: self._classify_stat(f"readback_{k}"))
         # native splice-pump counters (net/native/vtl.cpp, the hot-byte
         # black box): bytes spliced, write syscalls, short writes, TLS
         # handshakes — read through the C-ABI getter in net/vtl.py
