@@ -420,6 +420,134 @@ def test_stitched_trace_lane_to_python(stack):
     assert 0 < t1 - t0 < 60 * 10**9  # one sane end-to-end window
 
 
+# ------------------------- the operator's drive (the grammar-built app)
+
+@pytest.fixture
+def grammar_app():
+    """An app built the operator's way: Command grammar, lanes on, an
+    HTTP controller beside it, every request sampled."""
+    from vproxy_tpu.control.app import Application
+    from vproxy_tpu.control.command import Command
+    from vproxy_tpu.control.http_controller import HttpController
+    from vproxy_tpu.utils import lifecycle
+    from tests.test_tcplb import IdServer
+    lifecycle.reset()
+    trace.configure(1)
+    app = Application.create(workers=2)
+    ctl = HttpController(app, "127.0.0.1", 0)
+    ctl.start()
+    srv = IdServer("A")
+    try:
+        for cmd in (
+                "add upstream u0",
+                "add server-group g0 timeout 500 period 100 up 1 down 1",
+                "add server-group g0 to upstream u0 weight 10",
+                f"add server sA to server-group g0 address "
+                f"127.0.0.1:{srv.port} weight 10"):
+            assert Command.execute(app, cmd) == "OK", cmd
+        g = app.server_groups["g0"]
+        assert _wait(lambda: any(s.healthy for s in g.servers), 10)
+        assert Command.execute(
+            app, "add tcp-lb lb0 address 127.0.0.1:0 upstream u0 "
+            "protocol tcp lanes 2") == "OK"
+        yield app, ctl, app.tcp_lbs["lb0"]
+    finally:
+        ctl.stop()
+        app.close()
+        srv.close()
+        lifecycle.reset()
+
+
+def _http_json(port, path):
+    import json
+    import urllib.request
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}",
+                                timeout=5) as r:
+        return json.loads(r.read())
+
+
+@needs_lanes
+def test_operator_surfaces_serve_a_lane_trace(grammar_app):
+    """Lane-served connections of a grammar-built tcp-lb: a
+    whole-lifetime C-plane trace, and `list trace`, `trace <id>`,
+    `GET /trace?id=` on the HTTP controller, /metrics and the C
+    counters all show it (the round-14 scenario drive)."""
+    from vproxy_tpu.control.command import Command
+    from vproxy_tpu.utils.metrics import GlobalInspection
+    from tests.test_tcplb import tcp_get_id
+    app, ctl, lb = grammar_app
+    assert lb.lanes is not None
+    spans0, drops0 = vtl.trace_counters()
+    for _ in range(5):
+        assert tcp_get_id(lb.bind_port) == "A"
+    assert lb.accepted == 0, "python accept path fired"
+
+    def whole():
+        for t in trace.summaries(last=0):
+            names = {s["span"] for s in trace.get_trace(t["trace"])
+                     if s["plane"] == "lane"}
+            if {"accept", "route_pick", "connect", "splice",
+                    "close"} <= names:
+                return t["trace"]
+        return None
+
+    assert _wait(lambda: whole() is not None, 10), \
+        "no whole-lifetime lane trace drained"
+    tid = whole()
+    spans = trace.get_trace(tid)
+    for a, b in zip(spans, spans[1:]):
+        assert a["t_ns"] + a["dur_ns"] <= b["t_ns"] + 1000, (a, b)
+    assert any(f"[{tid}]" in line
+               for line in Command.execute(app, "list trace"))
+    assert any("splice" in line
+               for line in Command.execute(app, f"trace {tid}"))
+    doc = _http_json(ctl.bind_port, f"/trace?id={tid}")
+    assert doc["trace"] == tid and len(doc["spans"]) == len(spans) >= 5
+    text = GlobalInspection.get().prometheus_string()
+    line = next(l for l in text.splitlines() if l.startswith(
+        'vproxy_trace_spans_total{plane="lane"}'))
+    assert float(line.split()[-1]) >= 5
+    spans_c, drops_c = vtl.trace_counters()
+    assert spans_c - spans0 >= 25 and drops_c == drops0, (spans_c, drops_c)
+
+
+@needs_lanes
+def test_grammar_built_acl_stitches_lane_accept_and_engine(grammar_app):
+    """A security group added through the grammar empties the lane
+    entry: the sampled punt's trace runs on through the python accept
+    path AND the engine plane (the ACL classify), the controller serves
+    the same spans, and the flight recorder's events join it."""
+    from vproxy_tpu.control.command import Command
+    from vproxy_tpu.utils.events import FlightRecorder
+    from tests.test_tcplb import tcp_get_id
+    app, ctl, lb = grammar_app
+    for cmd in ("add security-group acl0 default deny",
+                "add security-group-rule lo to security-group acl0 "
+                "network 127.0.0.0/8 protocol tcp port-range 1,65535 "
+                "default allow",
+                "update tcp-lb lb0 security-group acl0"):
+        assert Command.execute(app, cmd) == "OK", cmd
+    assert _wait(lambda: lb.lanes.stat().get("pick") == "empty", 10)
+    assert tcp_get_id(lb.bind_port) == "A"     # punted, served by python
+
+    def stitched():
+        for t in trace.summaries(last=0):
+            if {"lane", "accept"} <= set(t["planes"]) and any(
+                    s["span"] == "close"
+                    for s in trace.get_trace(t["trace"])):
+                return t
+        return None
+
+    assert _wait(lambda: stitched() is not None, 10), "no stitched trace"
+    st = stitched()
+    spans = trace.get_trace(st["trace"])
+    assert {"lane", "accept", "engine"} <= {s["plane"] for s in spans}
+    doc = _http_json(ctl.bind_port, f"/trace?id={st['trace']}")
+    assert len(doc["spans"]) == len(spans)
+    assert FlightRecorder.get().snapshot(trace=st["trace"]), \
+        "no recorder event carries the trace id"
+
+
 # ------------------------------------------------------ install traces
 
 def test_install_trace_brackets_unstalled_dispatch():

@@ -249,17 +249,44 @@ def note_launch(n: int = 1) -> None:
     _LAUNCHES[0] += n
 
 
-def launch_span(kind: str, bucket: int, fused: bool = False):
+def _host_arrays(args) -> tuple:
+    """-> (how many, bytes of) the numpy values among a jitted call's
+    arguments, dicts and sequences walked: what the call uploads
+    implicitly. Device arrays (the resident tables, queries a sharded
+    backend has placed itself) count nothing."""
+    n = nbytes = 0
+    todo = [args]
+    while todo:
+        a = todo.pop()
+        if isinstance(a, (np.ndarray, np.generic)):
+            n += 1
+            nbytes += a.nbytes
+        elif isinstance(a, dict):
+            todo.extend(a.values())
+        elif isinstance(a, (tuple, list)):
+            todo.extend(a)
+    return n, nbytes
+
+
+def launch_span(kind: str, bucket: int, fused: bool = False, args=()):
     """Count one device launch (note_launch) and time it: the `launch`
     span (utils/trace) around the jitted call itself. That call
     enqueues the program AND uploads its numpy arguments — the served
-    path makes no explicit device_put, so the two are one number here.
+    path makes no explicit device_put, so the two are one number here;
+    what tells them apart is what the call was handed: args, the
+    call's arguments, of which the span's `items` are the numpy ones
+    and `h2d_bytes` their bytes. (No `cpu_ns`: the thread CPU clock of
+    the chip's host moves in 10 ms ticks and read 0.21 of the wall over
+    busy stretches of 1 ms — PERF.md §7.)
     fused vs unfused is distinguishable per launch, so a sampled
     request's trace shows how many programs its batch really cost.
     bucket: the padded batch the program was compiled for."""
     note_launch()
-    return trace.span("engine", "launch", kind=kind, fused=fused,
-                      bucket=bucket, parent="dispatch")
+    n = nbytes = 0
+    if trace.SAMPLE:
+        n, nbytes = _host_arrays(args)
+    return trace.span("engine", "launch", items=n, kind=kind, fused=fused,
+                      bucket=bucket, h2d_bytes=nbytes, parent="dispatch")
 
 
 def encode_span(items: int):
@@ -535,7 +562,8 @@ def fused_dispatch(hm, hsnap: tuple, mm, msnap: tuple, hints,
         return None
     q, slots = _fused_encode(hsnap, len(mtab), hints, ips, ports, pad_to)
     from ..ops import fused as F
-    with launch_span("cpick", len(slots), fused=True):
+    with launch_span("cpick", len(slots), fused=True,
+                     args=(fd, q, mdev, slots)):
         return F.fused_jit(fd, q, mdev, slots)
 
 
@@ -560,7 +588,8 @@ def grouped_dispatch(hsnap: tuple, ssnap, m: int, hints,
         return None
     q, slots = _fused_encode(hsnap, m, hints, ips, ports, pad_to)
     from ..ops import fused as F
-    with launch_span("cpick", len(slots), fused=True):
+    with launch_span("cpick", len(slots), fused=True,
+                     args=(fd, q, col[1], ssnap.dev, slots)):
         return F.group_jit(fd, q, col[1], ssnap.dev[1], ssnap.dev[0], slots)
 
 
@@ -803,7 +832,7 @@ class HintMatcher:
 
     def submit(self, q: dict):
         """Dispatch an encoded batch; returns the device array (async)."""
-        with launch_span("hint", q["hostb"].shape[0]):
+        with launch_span("hint", q["hostb"].shape[0], args=(self._dev, q)):
             idx, _ = H.hint_hash_jit(self._dev, q)
         return idx
 
@@ -906,7 +935,7 @@ class HintMatcher:
             # entry: the encoder hashes the real rows only and writes
             # them into the padded bucket, pad rows invalid probes
             q = _fused_hint_q(tab, hints, pad_to)
-            with launch_span("hint", q["hostb"].shape[0]):
+            with launch_span("hint", q["hostb"].shape[0], args=(dev, q)):
                 idx, _ = H.hint_hash_jit(dev, q)
             return idx
         if self.backend == "jax-fp":
@@ -919,7 +948,7 @@ class HintMatcher:
             # keys on the static mode arg, so passing None would bake
             # the first dispatch's VPROXY_TPU_FP_MEMBER into the cache
             # and silently ignore later changes (stale lowering)
-            with launch_span("hint", max(n, pad_to or 0)):
+            with launch_span("hint", max(n, pad_to or 0), args=(dev, q)):
                 idx, _ = F.hint_fp_jit(dev, q,
                                        mode=F.default_member_mode())
             return idx
@@ -946,8 +975,9 @@ class HintMatcher:
                 self._fn = M.make_sharded_hint_fn(
                     self._mesh, {k: v.ndim for k, v in tab.arrays.items()},
                     {k: v.ndim for k, v in q.items()}, kernel=kernel)
-            with launch_span("hint", cap):
-                out = self._fn(dev, qd, np.int32(tab.shard_size))
+            size = np.int32(tab.shard_size)
+            with launch_span("hint", cap, args=(dev, qd, size)):
+                out = self._fn(dev, qd, size)
             if not sync:
                 import jax
                 if jax.process_count() <= 1:
@@ -959,7 +989,7 @@ class HintMatcher:
             if pad_to and pad_to > n:
                 hints = list(hints) + [Hint()] * (pad_to - n)
             q = T.encode_hints(hints)
-        with launch_span("hint", len(hints)):
+        with launch_span("hint", len(hints), args=(dev, q)):
             idx, _ = hint_match_jit(
                 dev, q["host"], q["has_host"], unpack_bits(q["uri"]),
                 q["has_uri"], q["port"])
@@ -1208,7 +1238,7 @@ class CidrMatcher:
         if self.backend in ("jax-sharded", "jax-fp-sharded"):
             return self._dispatch_sharded(snap, a16, fam, p, sync=sync)
         # every branch is one dispatch
-        with launch_span("cidr", a16.shape[0]):
+        with launch_span("cidr", a16.shape[0], args=(dev, a16, fam, p)):
             if self.backend == "jax":
                 return H.cidr_hash_jit(dev, a16, fam, p)
             if self.backend == "jax-fp":
@@ -1242,7 +1272,7 @@ class CidrMatcher:
                 self._mesh, {k: v.ndim for k, v in tab.arrays.items()},
                 with_port, kernel=kernel)
         size = np.int32(tab.shard_size)
-        with launch_span("cidr", cap):
+        with launch_span("cidr", cap, args=(dev, a16d, famd, pd, size)):
             out = fn(dev, a16d, famd, pd, size) if with_port \
                 else fn(dev, a16d, famd, size)
         if not sync:
@@ -1544,9 +1574,12 @@ class CidrTableSet:
         np.maximum(col, 0, out=tid[:n])
         fam[:n][col < 0] = -1
         if t0:
+            # counted inside the span: the sort hands the GIL over, and
+            # a submitter's turn then belongs to what caused it
+            named = len(np.unique(col[col >= 0]))
             trace.note_span(trace.current_id(), "engine", "table_set", t0,
-                            time.monotonic_ns() - t0,
-                            items=len(np.unique(col[col >= 0])),
+                            time.monotonic_ns() - t0, items=named,
                             parent="dispatch")
-        with launch_span("cidr", a16.shape[0]):
+        with launch_span("cidr", a16.shape[0],
+                         args=(snap.dev, a16, fam, tid, p)):
             return H.cidr_set_jit(snap.dev, a16, fam, tid, p)

@@ -366,7 +366,7 @@ class MaglevMatcher:
             return np.full(len(ips), -1, np.int32)
         slots = flow_slots(len(tab), ips, ports)
         from . import engine as E
-        with E.launch_span("pick", len(slots)):
+        with E.launch_span("pick", len(slots), args=(dev, slots)):
             return _device_take(dev, slots)
 
     def match(self, ips: Sequence[bytes],

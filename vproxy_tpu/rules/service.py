@@ -175,10 +175,10 @@ class _Inflight:
     (the dispatcher's double buffer slot)."""
 
     __slots__ = ("kind", "matcher", "reqs", "snap", "arr", "t0",
-                 "lone_big", "tid", "prefetched")
+                 "lone_big", "tid", "prefetched", "t_ret")
 
     def __init__(self, kind, matcher, reqs, snap, arr, t0, lone_big, tid,
-                 prefetched):
+                 prefetched, t_ret):
         self.kind = kind
         self.matcher = matcher
         self.reqs = reqs
@@ -188,6 +188,9 @@ class _Inflight:
         self.lone_big = lone_big
         self.tid = tid    # the batch's first sampled request, 0 = none
         self.prefetched = prefetched  # its readback started at launch
+        # tracing on: monotonic ns at which its launch had returned (the
+        # start of its `engine/inflight`), else 0
+        self.t_ret = t_ret
 
 
 def _start_readback(arr) -> bool:
@@ -213,9 +216,49 @@ def _start_readback(arr) -> bool:
     return True
 
 
+class _Swap:
+    """Tracing on: one iteration of the dispatcher's loop up to its
+    cycle, as the top-level span `engine/swap` — from the iteration's
+    top to the start of its `engine/cycle` (to its own end where it took
+    nothing), less the park inside it, which is `engine/wait`'s: the
+    acquire of `_cv` (noted again as `engine/swap_lock`, not a leaf),
+    the swap of the pending queue — which lets go of the last wake's
+    lists, and with them of every request finished inside that wake —
+    and the split into uniform parts. Its profiler annotation holds the
+    park's, its total does not: wait, swap, cycle and drain tile the
+    thread."""
+
+    __slots__ = ("ann", "t0", "t_lock", "park_ns")
+
+    def __init__(self):
+        self.ann = trace.annotation("vproxy/engine/swap", {})
+        self.t0 = self.t_lock = time.monotonic_ns()
+        self.park_ns = 0
+
+    def locked(self) -> None:
+        """`_cv` is held."""
+        self.t_lock = time.monotonic_ns()
+
+    def parked(self) -> None:
+        """Back from a park that began right after the acquire."""
+        self.park_ns = time.monotonic_ns() - self.t_lock
+
+    def close(self, items: int) -> None:
+        """The iteration's cycle begins, or it took nothing: `items`
+        queries taken."""
+        dur_ns = time.monotonic_ns() - self.t0 - self.park_ns
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        trace.note_span(0, "engine", "swap", self.t0, dur_ns, items=items,
+                        park_ns=self.park_ns)
+        trace.note_span(0, "engine", "swap_lock", self.t0,
+                        self.t_lock - self.t0)
+
+
 class _Cycle:
     """Tracing on: one dispatcher wake that found work, as spans.
-    `engine/cycle` runs from the swap of the pending queue to the end of
+    `engine/cycle` runs from the end of the wake's `engine/swap` (the
+    pending queue taken and split into uniform parts) to the end of
     the wake's last turn (its dispatch and the delivery of the batch
     before it). One `engine/turn_wait` a uniform part runs from the
     cycle's start to that part's own dispatch: the share of its queries'
@@ -628,19 +671,34 @@ class ClassifyService:
         # before its delivery (one host round trip per batch)
         inflight: Optional[_Inflight] = None
         while True:
+            # tracing on: wait, swap, cycle and drain tile this thread
+            swap = _Swap() if trace.SAMPLE else None
             with self._cv:
+                if swap is not None:
+                    swap.locked()
                 if not self._pending and not self._closed \
                         and inflight is None:
                     with trace.span("engine", "wait", tid=0):
                         while not self._pending and not self._closed:
                             self._cv.wait()
+                    if swap is not None:
+                        swap.parked()
                 batches = list(self._pending.values())
                 self._pending.clear()
                 closed = self._closed
             if not batches:
+                if swap is not None:
+                    swap.close(0)
                 if inflight is not None:
-                    self._finish_guarded(inflight)
-                    inflight = None
+                    # nothing came while the batch was in flight: it is
+                    # read right behind its own launch, outside any cycle
+                    with trace.span("engine", "drain", tid=inflight.tid,
+                                    kind=inflight.kind,
+                                    batch=len(inflight.reqs)):
+                        self._finish_guarded(inflight)
+                        with trace.span("engine", "release", tid=0,
+                                        items=len(inflight.reqs)):
+                            inflight = None
                     continue
                 if closed:
                     return
@@ -648,6 +706,8 @@ class ClassifyService:
             parts = [(kind, matcher, part)
                      for kind, matcher, reqs in batches
                      for part in self._split_uniform(kind, reqs)]
+            if swap is not None:
+                swap.close(sum(len(p) for _k, _m, p in parts))
             # tracing on: the wake and each part's wait for its turn
             cycle = _Cycle(parts) if trace.SAMPLE else None
             for kind, matcher, part in parts:
@@ -675,7 +735,11 @@ class ClassifyService:
                     # deliver the PREVIOUS batch now that the next one
                     # is already on the device
                     self._finish_guarded(inflight)
-                    inflight = None
+                    # the last reference to a batch of an earlier wake:
+                    # its result and every request of it are freed here
+                    with trace.span("engine", "release", tid=0,
+                                    items=len(inflight.reqs)):
+                        inflight = None
                 inflight = nxt
             if cycle is not None:
                 cycle.close()
@@ -730,44 +794,51 @@ class ClassifyService:
         return an _Inflight for _finish_inflight to sync+deliver; host
         batches deliver here and return None."""
         n = len(reqs)
-        with self.stats.lock:  # inline submit threads write stats too
-            self.stats.max_batch = max(self.stats.max_batch, n)
-        snap = matcher.snapshot()  # ONE generation for device/oracle/payload
-        lone_big = n == 1 and matcher.size() > SMALL_TABLE
-        if sketch.ON:
-            # device-plane attribution: which upstream's classify load
-            # is filling the batches (routes dim, `upstream:<alias>`
-            # keys, weight = batch occupancy)
-            own = getattr(matcher, "owner_alias", None)
-            if own:
-                sketch.update("routes", f"upstream:{own}", n,
-                              plane="engine")
-        # sampled requests in the batch: the batch's phases (dispatch
-        # and the engine's encode + launch under it, d2h sync, deliver,
-        # host_index) are buffered on the FIRST one's trace — one span,
-        # not one per request — and totalled for every batch while
-        # tracing is on; queue wait is recorded for every sampled
-        # request on BOTH serving branches
-        tid = 0
-        if trace.SAMPLE:
-            t_q = time.monotonic_ns()
-            for r in reqs:
-                if r.tid:
-                    tid = tid or r.tid
-                    t_sub = int(r.t0 * 1e9)
-                    trace.note_span(r.tid, "engine", "queue_wait", t_sub,
-                                    t_q - t_sub, kind=kind, batch=n)
+        # tracing on: what comes before the dispatch is a phase of its own
+        with trace.span("engine", "begin", tid=0, items=n, kind=kind):
+            with self.stats.lock:  # inline submit threads write stats too
+                self.stats.max_batch = max(self.stats.max_batch, n)
+            # ONE generation for device/oracle/payload
+            snap = matcher.snapshot()
+            lone_big = n == 1 and matcher.size() > SMALL_TABLE
+            if sketch.ON:
+                # device-plane attribution: which upstream's classify load
+                # is filling the batches (routes dim, `upstream:<alias>`
+                # keys, weight = batch occupancy)
+                own = getattr(matcher, "owner_alias", None)
+                if own:
+                    sketch.update("routes", f"upstream:{own}", n,
+                                  plane="engine")
+            # sampled requests in the batch: the batch's phases (dispatch
+            # and the engine's encode + launch under it, d2h sync, deliver,
+            # host_index) are buffered on the FIRST one's trace — one span,
+            # not one per request — and totalled for every batch while
+            # tracing is on; queue wait is recorded for every sampled
+            # request on BOTH serving branches
+            tid = 0
+            if trace.SAMPLE:
+                t_q = time.monotonic_ns()
+                for r in reqs:
+                    if r.tid:
+                        tid = tid or r.tid
+                        t_sub = int(r.t0 * 1e9)
+                        trace.note_span(r.tid, "engine", "queue_wait", t_sub,
+                                        t_q - t_sub, kind=kind, batch=n)
         if self._use_device(matcher, n):
             try:
                 t0 = time.monotonic()
                 # the bind hands the engine's encode and launch spans
                 # the sampled request's trace
                 with trace.bind(tid), trace.span("engine", "dispatch",
-                                                 tid=tid, kind=kind,
-                                                 batch=n):
+                                                 tid=tid, items=n,
+                                                 kind=kind, batch=n):
                     arr = self._device_submit(kind, matcher, snap, reqs)
+                with trace.span("engine", "readback_start", tid=tid,
+                                kind=kind):
+                    prefetched = _start_readback(arr)
                 return _Inflight(kind, matcher, reqs, snap, arr, t0,
-                                 lone_big, tid, _start_readback(arr))
+                                 lone_big, tid, prefetched,
+                                 time.monotonic_ns() if trace.SAMPLE else 0)
             except MemoryError:
                 raise
             except Exception as e:
@@ -831,11 +902,20 @@ class ClassifyService:
         and deliver; a device error here degrades THIS batch to the
         oracle and marks the device down, same as a submit failure."""
         n = len(inf.reqs)
+        if inf.t_ret:
+            # how long the result had to become ready: not a leaf, it
+            # lies over whatever the dispatcher did meanwhile
+            trace.note_span(inf.tid, "engine", "inflight", inf.t_ret,
+                            time.monotonic_ns() - inf.t_ret,
+                            kind=inf.kind, batch=n)
         idxs = None
         try:
             ready = getattr(inf.arr, "is_ready", None)
             kernel_wait = ready is not None and not ready()
+            # one decision, two sinks: the counter below and, tracing
+            # on, the sync's interval noted again as `engine/kernel_wait`
             with trace.span("engine", "d2h_sync", tid=inf.tid,
+                            also="kernel_wait" if kernel_wait else None,
                             kind=inf.kind, batch=n):
                 idxs = np.asarray(inf.arr)[:n]
             if inf.lone_big:

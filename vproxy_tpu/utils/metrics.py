@@ -357,8 +357,6 @@ class GlobalInspection:
         self._lock = threading.Lock()
         # (name, sorted-label-items) -> Metric for get-or-create users
         self._named: Dict[tuple, Metric] = {}
-        self.direct_memory_bytes = self.registry.gauge(
-            "vproxy_direct_memory_bytes_current")
         self.registry.gauge_f("vproxy_event_loop_count",
                               lambda: len(self._loops))
         self.registry.gauge_f("vproxy_open_fd_count",

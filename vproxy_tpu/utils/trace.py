@@ -19,23 +19,52 @@ process-wide bounded buffer:
 * **accept**  — the python accept path (components/tcplb.py): acl,
   backend_pick, connect, splice, close, total.
 * **engine**  — classify dispatch (rules/service.py + rules/engine.py).
-  The dispatcher thread's BATCH CYCLE, one span each per batch: wait
-  (parked in the condition variable with nothing pending and nothing
-  in flight), cycle (one per wake that found work: from the swap of
-  the pending queue to the end of the wake's last turn; `items`
-  queries taken, `batches` uniform parts begun), turn_wait (one per
-  uniform part: from the cycle's start to the part's own dispatch —
-  the share of queue_wait spent behind the other matchers of the
-  wake; `items`, `kind`, `batch`), dispatch (the `_device_submit`
-  call; parent of the next two), encode (host encode + padding of
-  one batch: `items` real queries, `cpu_ns`), launch (the jitted call: enqueue AND the
-  implicit upload of its numpy arguments; `kind`, `fused`, `bucket`),
-  d2h_sync (the blocking `np.asarray` of the result), deliver (the
-  callback loop: `items`, `cpu_ns`), table_set (a batch of a
-  CidrTableSet: forming its table-id column; `items` distinct tables
-  the batch names). Per sampled request: queue_wait
-  (`batch`), submit_lock_wait (a submitter waiting for the service's
-  lock), classify_inline / host_index fallbacks.
+  The dispatcher thread is TILED by four top-level spans — no two
+  overlap, and from the first one's start to the last one's end only
+  the spans' own entry and exit lie in none of them: wait (parked in
+  the condition variable with nothing pending and nothing in flight),
+  swap (one per iteration of the loop: from its top to the start of
+  its cycle — to its own end where it took nothing — LESS the wait
+  inside it: the acquire of the service's lock, the swap of the
+  pending queue, which frees the requests finished inside the last
+  wake, the split into uniform parts; `items` queries taken), cycle
+  (one per
+  wake that found work: from the end of its swap — the pending queue
+  already taken and split — to the end of the wake's last turn;
+  `items` queries, `batches` uniform parts begun) and drain (an
+  iteration that found nothing pending with a batch in flight: that
+  batch's d2h_sync + deliver, read right behind its own launch with no
+  next batch to overlap). Inside a cycle, one span each per batch:
+  begin (`_begin_uniform` up to its dispatch: the snapshot, the stats
+  lock and, tracing on, the scan for sampled requests; `items`),
+  dispatch (the `_device_submit` call, `items` queries; parent of
+  encode, table_set and launch), encode (host encode + padding of one
+  batch: `items` real queries, `cpu_ns`), launch (the jitted call:
+  enqueue AND the implicit upload of its numpy arguments; `items` =
+  how many numpy arrays it was handed, `h2d_bytes` their bytes;
+  `kind`, `fused`, `bucket`), table_set (a batch of a CidrTableSet: forming
+  its table-id column; `items` distinct tables the batch names),
+  readback_start (the `copy_to_host_async()` of the result, right
+  after the launch), d2h_sync (the blocking `np.asarray` of the
+  result; in a cycle or a drain), group_pick (a grouped pair's batch:
+  counting its device picks), deliver (the callback loop: `items`,
+  `cpu_ns`), release (letting go of the finished batch, in a cycle or
+  a drain: a batch of an earlier wake is freed there — its result,
+  its `items` requests, their payloads and callbacks). NOT leaves —
+  they lie over the others and are left out of the tiling: swap_lock
+  (the acquire of the service's lock alone, the first thing inside
+  every swap), turn_wait (one per uniform part: from the cycle's
+  start to the part's own dispatch — the share of queue_wait spent
+  behind the other matchers of the wake; `items`, `kind`, `batch`),
+  inflight (one per device batch: from its launch's return to the
+  dispatcher's coming for its result — how long the result had to
+  become ready), kernel_wait (the interval of a batch's d2h_sync,
+  noted a second time ONLY when the result was not ready when the
+  dispatcher came for it — the decision that feeds
+  vproxy_engine_readback_kernel_waits_total; d2h_sync less
+  kernel_wait is the sync of results that were ready). Per sampled
+  request: queue_wait (`batch`), submit_lock_wait (a submitter waiting
+  for the service's lock), classify_inline / host_index fallbacks.
 * **install** — the TableInstaller (rules/engine.py): every standby
   generation install traced as compile / upload / swap spans.
 * **cluster** — the step-synchronized submit loop (cluster/submit.py):
@@ -59,10 +88,14 @@ so the spans sit in a profiler trace on its own clock, beside the device.
 
 Sampling: `VPROXY_TPU_TRACE_SAMPLE` = N samples 1-in-N (0 = off, the
 default). Knob-off cost is one branch per site — per batch on the
-dispatcher, never per query. On, a batch costs its seven `span()`s and
-a wake one more (two clock reads, a profiler annotation and one locked
-add each; encode and deliver two `thread_time_ns()` more — a syscall of
-~6 us on some hosts) and a submit two clock reads. Two deciders:
+dispatcher, never per query. On, a batch costs its nine `span()`s
+(ten where a second encoder runs), a wake two more and a drain one
+(two clock reads, a profiler annotation and one locked add each;
+encode and deliver two `thread_time_ns()` more — a syscall of ~6 us
+on some hosts), a `note_span()` each for inflight, swap_lock and,
+where they apply, kernel_wait, table_set and group_pick (a locked
+add) and the walk over the launch's arguments; a submit two clock
+reads. Two deciders:
 
 * `maybe_sample()` — deterministic counter-based 1-in-N (the accept
   paths; every Nth request).
@@ -275,6 +308,10 @@ SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
          ("engine", "d2h_sync"), ("engine", "deliver"),
          ("engine", "queue_wait"), ("engine", "submit_lock_wait"),
          ("engine", "table_set"), ("engine", "group_pick"),
+         ("engine", "swap"), ("engine", "drain"),
+         ("engine", "readback_start"), ("engine", "inflight"),
+         ("engine", "kernel_wait"), ("engine", "swap_lock"),
+         ("engine", "begin"), ("engine", "release"),
          ("runtime", "gc_pause"))
 # bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
 TOTAL_BUCKETS = 27
@@ -347,7 +384,7 @@ def span_totals() -> dict:
 _ann_cls = None     # jax.profiler.TraceAnnotation, once this process has JAX
 
 
-def _annotation(name: str, fields: dict):
+def annotation(name: str, fields: dict):
     """An entered profiler annotation carrying `fields`, or None in a
     process that has not imported JAX (the accept planes: no profiler
     can be running there). Looked up, never imported: the gc hook can
@@ -364,16 +401,17 @@ def _annotation(name: str, fields: dict):
 
 
 class _Span:
-    __slots__ = ("plane", "name", "tid", "cpu", "items", "fields", "t0",
-                 "cpu0", "ann")
+    __slots__ = ("plane", "name", "tid", "cpu", "items", "also", "fields",
+                 "t0", "cpu0", "ann")
 
-    def __init__(self, plane, name, tid, cpu, items, fields):
+    def __init__(self, plane, name, tid, cpu, items, also, fields):
         self.plane, self.name, self.tid = plane, name, tid
-        self.cpu, self.items, self.fields = cpu, items, fields
+        self.cpu, self.items, self.also = cpu, items, also
+        self.fields = fields
 
     def __enter__(self):
-        self.ann = _annotation("vproxy/" + self.plane + "/" + self.name,
-                               self.fields)
+        self.ann = annotation("vproxy/" + self.plane + "/" + self.name,
+                              self.fields)
         self.t0 = time.monotonic_ns()
         if self.cpu:
             self.cpu0 = time.thread_time_ns()
@@ -386,6 +424,9 @@ class _Span:
             self.ann.__exit__(None, None, None)
         note_span(self.tid, self.plane, self.name, self.t0, dur_ns,
                   cpu_ns, self.items, **self.fields)
+        if self.also:
+            note_span(self.tid, self.plane, self.also, self.t0, dur_ns,
+                      **self.fields)
         return False
 
 
@@ -403,16 +444,19 @@ _NO_SPAN = _NoSpan()
 
 
 def span(plane: str, name: str, tid: Optional[int] = None,
-         cpu: bool = False, items: int = 0, **fields):
+         cpu: bool = False, items: int = 0, also: Optional[str] = None,
+         **fields):
     """Context manager around one phase of a batch (SPANS vocabulary):
     on exit the span goes to the totals, and to the trace buffer under
     `tid` (default: the thread's bound trace context) when that is
-    nonzero. cpu: also take the thread's CPU time. With tracing off
-    this is one branch and a shared no-op."""
+    nonzero. cpu: also take the thread's CPU time. also: a second span
+    name the same interval is noted under (one decision of the caller's,
+    two sinks; no annotation of its own). With tracing off this is one
+    branch and a shared no-op."""
     if SAMPLE <= 0:
         return _NO_SPAN
     return _Span(plane, name, current_id() if tid is None else tid, cpu,
-                 items, fields)
+                 items, also, fields)
 
 
 # gc_pause: collections stop every thread, so the hook's start/stop pair
@@ -424,8 +468,8 @@ _gc_open = [0, None]    # start ns, profiler annotation
 
 def _gc_hook(phase: str, info: dict) -> None:
     if phase == "start":
-        _gc_open[1] = _annotation("vproxy/runtime/gc_pause",
-                                  {"gen": info["generation"]})
+        _gc_open[1] = annotation("vproxy/runtime/gc_pause",
+                                 {"gen": info["generation"]})
         _gc_open[0] = time.monotonic_ns()
     elif _gc_open[0]:
         t0, ann = _gc_open
@@ -522,7 +566,7 @@ def slowest(n: int = 8) -> list:
     return [dict(t, spans=get_trace(t["trace"])) for t in worst]
 
 
-def stage_table(span_filter=None) -> dict:
+def stage_table() -> dict:
     """Per-(plane, span) duration percentiles over every buffered
     trace — the bench attribution table's source. -> {"plane/span":
     {"n", "p50_us", "p99_us"}}."""
@@ -530,10 +574,8 @@ def stage_table(span_filter=None) -> dict:
     with _lock:
         all_spans = [s for spans in _traces.values() for s in spans]
     for s in all_spans:
-        key = f"{s['plane']}/{s['span']}"
-        if span_filter is not None and not span_filter(s):
-            continue
-        by.setdefault(key, []).append(s["dur_ns"] / 1000.0)
+        by.setdefault(f"{s['plane']}/{s['span']}",
+                      []).append(s["dur_ns"] / 1000.0)
     out = {}
     for key, durs in sorted(by.items()):
         durs.sort()
