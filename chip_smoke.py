@@ -44,6 +44,13 @@ Legs, all through the entry points a user reaches:
   fused batch costs exactly one launch, and a generation install under
   queries serves the new rule.
 
+In the served, grouped and width legs, on the "jax" backend, every
+launch was handed ONE numpy array — its batch's packed arena
+(vproxy_engine_launch_host_arrays_total over
+vproxy_engine_dispatch_launches_total is 1.0): the programs slice byte
+columns out of int32 words, so the answers above are also the proof of
+the chip's byte order.
+
 Printed but not gated (bring-up evidence, not benchmark numbers): table
 build / upload seconds, every compile with its seconds, persistent
 compile-cache hits and misses, first and steady dispatch at batch
@@ -77,6 +84,30 @@ def gate(ok: bool, what: str) -> None:
     if not ok:
         FAILURES.append(what)
         say(f"FAIL: {what}")
+
+
+class LaunchArrays:
+    """numpy arrays handed to the jitted calls of a stretch, over its
+    launches (vproxy_engine_launch_host_arrays_total over
+    vproxy_engine_dispatch_launches_total): 1.0 where every launch took
+    its batch as one packed arena, which the "jax" backend always does."""
+
+    def __init__(self):
+        from vproxy_tpu.rules import engine as E
+        self._e = E
+        self._l0 = E.dispatch_launches_total()
+        self._a0 = E.launch_host_arrays_total()
+
+    def check(self, tag: str, backend: str) -> None:
+        dl = self._e.dispatch_launches_total() - self._l0
+        da = self._e.launch_host_arrays_total() - self._a0
+        say(f"{tag}: vproxy_engine_launch_host_arrays_total / "
+            f"vproxy_engine_dispatch_launches_total = {da} / {dl}"
+            + (f" = {da / dl:.3f}" if dl else ""))
+        if backend == "jax":
+            gate(dl > 0 and da == dl,
+                 f"{tag}: {da} numpy arrays over {dl} launches on backend "
+                 f"jax, want one packed arena a launch")
 
 
 # ------------------------------------------------------------ jax evidence
@@ -355,7 +386,9 @@ def served_leg(n_groups: int = 256, bursts: int = 3,
             return st
 
         n_req = bursts * burst
+        arrays = LaunchArrays()
         dev = run_policy("device")
+        arrays.check("served[device]", m.backend)
         say(f"served[device]: {n_req} requests in {bursts} bursts of "
             f"{burst}: {dev}")
         gate(dev["wrong"] == 0,
@@ -485,6 +518,7 @@ def grouped_leg(n_groups: int = 8, per_group: int = 3,
                  f"{d['dispatches']} batches")
             return d
 
+        arrays = LaunchArrays()
         ev["before"] = one_burst("before the edge")
         # the backend this client reaches in group 3 goes away: its
         # clients must move, nobody else's
@@ -502,6 +536,7 @@ def grouped_leg(n_groups: int = 8, per_group: int = 3,
         gate(ev["row_builds"] == 1,
              f"grouped: one group's edge rebuilt {ev['row_builds']} rows")
         ev["after"] = one_burst("after the edge")
+        arrays.check("grouped", m.backend)
         gate(str(id_of[victim.port]) not in ev["after"]["bodies"],
              "grouped: a request reached the dead backend")
         say(f"grouped: edge {ev['edge_s']}s from stop to published row, "
@@ -753,6 +788,7 @@ def width_leg(jlog: JaxLog, n_rules: int = 100_000,
                  lambda v, p, _pl: cb(i, (v, p)))),
         )
         total = 0
+        arrays = LaunchArrays()
         for kind, n, want, submit in runs:
             mark_k = jlog.mark()
             d0 = svc.stats.dispatches
@@ -764,6 +800,7 @@ def width_leg(jlog: JaxLog, n_rules: int = 100_000,
                 f"wall incl. {jlog.since(mark_k)}; wrong={wrong}")
             gate(wrong == 0, f"width: {wrong}/{n} {kind} verdicts differ "
                              f"from the host index")
+        arrays.check("width", hm.backend)
         st = svc.stats.snapshot()
         ev["service"] = st
         gate(st["device_queries"] == total and st["oracle_queries"] == 0

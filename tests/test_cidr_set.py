@@ -367,21 +367,25 @@ def test_table_set_span_counts_the_tables_a_batch_names():
 
 
 def test_plain_matcher_program_has_no_table_id():
-    """One table: the jitted call takes (tables, addr16, fam, port) and
-    its lowered text gathers no group row by table id; the set's program
-    is that text plus those gathers."""
+    """One table: the jitted call takes (tables, the batch's arena) and
+    an arena with no table-id column, and its lowered text gathers no
+    group row by table id; the set's program is that text plus those
+    gathers."""
     nets = [network(e) for e in plain_tables(17, sizes=[120])[0]]
     tab = H.compile_cidr_hash(nets)
-    a16, fam = np.zeros((32, 16), np.uint8), np.zeros(32, np.int32)
-    plain = H.cidr_hash_jit.lower(tab.arrays, a16, fam, None)
-    assert len(plain.args_info[0]) == 4 and plain.args_info[0][3] is None
+    q = H.cidr_queries(32)
+    assert set(q) == {"a16", "fam"}
+    plain = H.cidr_hash_jit.lower(tab.arrays, q.arena, q.layout)
+    assert len(plain.args_info[0]) == 2     # the layout is static
     arrays, _caps, _b = H.stack_cidr_tables([tab, None, tab])
-    tid = np.zeros(32, np.int32)
-    stacked = H.cidr_set_jit.lower(arrays, a16, fam, tid, None)
+    qs = H.cidr_queries(32, tid=True)
+    assert set(qs) == {"a16", "fam", "tid"}
+    stacked = H.cidr_set_jit.lower(arrays, qs.arena, qs.layout)
     n_plain = plain.as_text().count("stablehlo.gather")
     n_set = stacked.as_text().count("stablehlo.gather")
     assert 0 < n_plain < n_set
-    # the matcher's own dispatch goes through that four-argument call
+    # the matcher's own dispatch goes through that call: tables, arena,
+    # layout
     cm = CidrMatcher(nets, backend="jax")
     seen = []
     orig = H.cidr_hash_jit
@@ -391,7 +395,7 @@ def test_plain_matcher_program_has_no_table_id():
         cm.match([bytes([10, 0, 0, 1])] * 200)
     finally:
         H.cidr_hash_jit = orig
-    assert seen == [(4, {})]
+    assert seen == [(3, {})]
 
 
 def test_view_quacks_like_a_matcher_for_a_vpc_network():

@@ -593,9 +593,9 @@ def test_plain_fused_program_is_untouched():
     lowered = []
     for hm in (plain_hm, grouped_hm):
         hsnap = hm.snapshot()
-        q = E._fused_hint_q(hsnap[0], hints, 4)
-        lowered.append(F.fused_jit.lower(hsnap[5], q, mm.snapshot()[1],
-                                         np.zeros(4, np.int64)))
+        q = E._fused_hint_q(hsnap[0], hints, 4, slots=True)
+        lowered.append(F.fused_jit.lower(hsnap[5], mm.snapshot()[1],
+                                         q.arena, q.layout))
     assert lowered[0].as_text() == lowered[1].as_text()
     scopes = lowered[1].as_text(debug_info=True)
     assert "/maglev_pick/" in scopes and "/group_pick/" not in scopes
@@ -604,9 +604,10 @@ def test_plain_fused_program_is_untouched():
     set_entries(ts, ts.alloc(), [("x", 10)])
     dev = ts.snapshot().dev
     hsnap = grouped_hm.snapshot()
+    q = E._fused_hint_q(hsnap[0], hints, 4, slots=True)
     grouped = F.group_jit.lower(
-        hsnap[5], E._fused_hint_q(hsnap[0], hints, 4), hsnap[6][1], dev[1],
-        dev[0], np.zeros(4, np.int64)).as_text(debug_info=True)
+        hsnap[5], hsnap[6][1], dev[1], dev[0], q.arena,
+        q.layout).as_text(debug_info=True)
     assert "jit(fused_group_pick)/group_pick/" in grouped
 
 

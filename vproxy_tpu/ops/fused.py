@@ -27,10 +27,11 @@ Two layers live here:
   else.
 
 * **The fused kernel** (`fused_classify_pick` / `fused_jit`): one
-  jitted program taking the encoded query batch plus the published
-  snapshot's packed hint table and Maglev column and returning
-  (verdict, pick) stacked [B, 2] — one XLA launch, one d2h transfer
-  per batch. Verdicts are bit-identical to
+  jitted program taking the encoded query batch — as served, one
+  arena holding its columns and the Maglev slots (hashmatch
+  QueryArena) — plus the published snapshot's packed hint table and
+  Maglev column and returning (verdict, pick) stacked [B, 2] — one
+  XLA launch, one h2d argument, one d2h transfer per batch. Verdicts are bit-identical to
   `hashmatch.hint_hash_match` (same formulas, same i32 packing
   reduction; only the gather layout changed) and picks bit-identical
   to `maglev._device_take` (same host-side FNV slots, same clipped
@@ -44,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import cuckoo as CK
-from .hashmatch import DOT, HOST_SHIFT
+from .hashmatch import DOT, HOST_SHIFT, jit_packed, unpack_hint_arena
 
 
 # ------------------------------------------------------------- packing
@@ -235,7 +236,13 @@ def fused_classify_pick(ht: dict, q: dict, mtab, slots):
     return jnp.stack([v, p], axis=1)
 
 
-fused_jit = jax.jit(fused_classify_pick)
+def _fused_packed(ht: dict, mtab, buf, layout):
+    q, slots = unpack_hint_arena(buf, layout)
+    return fused_classify_pick(ht, q, mtab, slots)
+
+
+# the served entry: (packed hint table, Maglev table, arena, layout)
+fused_jit = jit_packed("fused_classify_pick", _fused_packed)
 
 
 def fused_group_pick(ht: dict, q: dict, rule_group, owner, set_tab, slots):
@@ -265,4 +272,11 @@ def fused_group_pick(ht: dict, q: dict, rule_group, owner, set_tab, slots):
     return jnp.stack([v, p], axis=1)
 
 
-group_jit = jax.jit(fused_group_pick)
+def _group_packed(ht: dict, rule_group, owner, set_tab, buf, layout):
+    q, slots = unpack_hint_arena(buf, layout)
+    return fused_group_pick(ht, q, rule_group, owner, set_tab, slots)
+
+
+# the served entry: (packed hint table, rule -> group column, owner
+# tokens, pick-table set, arena, layout)
+group_jit = jit_packed("fused_group_pick", _group_packed)

@@ -45,9 +45,11 @@ addresses on-device with FNV-32 (u32 wraparound matches numpy).
 """
 from __future__ import annotations
 
+import math
 import os
 from dataclasses import dataclass, field
-from typing import Optional, Sequence
+from functools import lru_cache
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -255,6 +257,198 @@ def compile_hint_hash(rules: Sequence[HintRule],
               "hb_items": hbc, "ub_items": ubc, "lset": lset_cap})
 
 
+# ------------------------------------------------------ the query arena
+#
+# A launch is handed ONE numpy array: the implicit upload of a jitted
+# call costs the calling thread 60-90 us an argument and hands the GIL
+# back once an argument, whatever the bytes (PERF.md §7), so a batch's
+# query columns are views of one int32 buffer — its arena — and the
+# served programs' first lines slice the buffer back into the columns
+# their bodies read (unpack_arena). The layout is a pure function of the
+# shapes that key the compile cache anyway (the bucket, the compare
+# windows, the probe tier, the table's uri-length cap; ports and a
+# table-id column for cidr): it adds no program shape.
+
+
+class ArenaLayout(NamedTuple):
+    """Where each column of a batch lies in its arena. Hashable: the
+    served programs take it as a static argument."""
+    words: int      # the arena's length, int32 words
+    fields: tuple   # (name, word offset, dtype name, shape), in order
+
+
+def arena_layout(cols: Sequence[tuple]) -> ArenaLayout:
+    """cols: (name, dtype name, shape) a column, in the order they lie.
+    Every column starts on a word: an int32 column takes its elements,
+    a one-byte column (uint8, bool) its bytes rounded up to whole words,
+    so each view is aligned whatever the order."""
+    fields, off = [], 0
+    for name, dt, shape in cols:
+        fields.append((name, off, dt, shape))
+        n = math.prod(shape)
+        off += n if dt == "int32" else -(-n // 4)
+    return ArenaLayout(off, tuple(fields))
+
+
+def _widest_tier(hw: int) -> int:
+    """The probe tier that covers any row of an hw-byte host window."""
+    return next((t for t in MAXP_TIERS if t >= hw), MAXP_TIERS[-1])
+
+
+@lru_cache(maxsize=None)     # a layout a program shape: few
+def hint_layout(cap: int, hw: int, uw: int, maxp: int, lw: int,
+                ns: int = 2, slots: bool = False) -> ArenaLayout:
+    """A hint batch's arena at bucket `cap`: compare windows hw / uw,
+    probe tier maxp, uri-length cap lw, slot blocks under ns salts (a
+    table's two; S shards' 2 S), and the Maglev slot column of the two
+    fused programs. The host-probe block lies last: its tier is known
+    only when the batch has been walked, and everything before it lies
+    where it does at every tier."""
+    cols = [("hlen", "int32", (cap,)), ("ulen", "int32", (cap,)),
+            ("port", "int32", (cap,)), ("up_len", "int32", (cap, lw)),
+            ("up_slots", "int32", (ns, cap, lw))]
+    if slots:
+        cols.append(("slots", "int32", (cap,)))
+    cols += [("has_host", "bool", (cap,)), ("has_uri", "bool", (cap,)),
+             ("hostb", "uint8", (cap, hw)), ("urib", "uint8", (cap, uw)),
+             ("hp_len", "int32", (cap, maxp)),
+             ("hp_slots", "int32", (ns, cap, maxp))]
+    return arena_layout(cols)
+
+
+@lru_cache(maxsize=None)
+def cidr_layout(cap: int, gated: bool = False,
+                tid: bool = False) -> ArenaLayout:
+    """A cidr batch's arena at bucket `cap`: family and address, the
+    port column where the table compares ports (an ACL), the table-id
+    column where the lookups name their tables (a table set)."""
+    cols = [("fam", "int32", (cap,))]
+    if gated:
+        cols.append(("port", "int32", (cap,)))
+    if tid:
+        cols.append(("tid", "int32", (cap,)))
+    cols.append(("a16", "uint8", (cap, 16)))
+    return arena_layout(cols)
+
+
+def arena_views(arena: np.ndarray, fields: Sequence[tuple]) -> dict:
+    """The columns `fields` (a layout's, or some of them) of `arena` as
+    numpy views, by name."""
+    out = {}
+    for name, off, dt, shape in fields:
+        n = math.prod(shape)
+        if dt == "int32":
+            out[name] = arena[off: off + n].reshape(shape)
+        else:
+            out[name] = arena[off: off + -(-n // 4)].view(np.uint8)[:n] \
+                .view(dt).reshape(shape)
+    return out
+
+
+def unpack_arena(buf: jnp.ndarray, layout: ArenaLayout) -> dict:
+    """arena_views on the device: the first lines of a served program.
+    Static slices of the one int32 buffer; a byte column comes out of
+    its words by bitcast (the low byte first: the host's order, which
+    chip_smoke.py holds on the chip)."""
+    out = {}
+    with jax.named_scope("unpack"):
+        for name, off, dt, shape in layout.fields:
+            n = math.prod(shape)
+            if dt == "int32":
+                out[name] = buf[off: off + n].reshape(shape)
+                continue
+            by = jax.lax.bitcast_convert_type(
+                buf[off: off + -(-n // 4)], jnp.uint8)
+            by = by.reshape(-1)[:n].reshape(shape)
+            out[name] = by != 0 if dt == "bool" else by
+    return out
+
+
+class QueryArena(dict):
+    """One encoded batch: the dict of query columns that every backend
+    and the plain kernels take, each a numpy view of `arena` — the one
+    buffer a served launch is handed, with `layout` — and `slots`, the
+    Maglev slot column where the batch has one. Fresh every batch: the
+    runtime may alias host memory (the CPU backend does), so an arena
+    is never written again once a launch has taken it."""
+
+    __slots__ = ("arena", "layout", "slots")
+
+    def __init__(self, cols: dict, arena: np.ndarray, layout: ArenaLayout,
+                 slots: Optional[np.ndarray] = None):
+        super().__init__(cols)
+        self.arena, self.layout, self.slots = arena, layout, slots
+
+
+# to jax a QueryArena is the dict it holds (the plain kernels are traced
+# and jitted on it as on any dict of columns)
+jax.tree_util.register_pytree_node(
+    QueryArena,
+    lambda q: (tuple(q[k] for k in sorted(q)), tuple(sorted(q))),
+    lambda keys, cols: dict(zip(keys, cols)))
+
+
+# the columns of a hint batch that every salt shares
+_HINT_COLS = ("hostb", "hlen", "has_host", "urib", "ulen", "has_uri", "port",
+              "hp_len", "up_len")
+
+
+class _HintArena:
+    """A hint batch's arena while it is encoded: allocated once at the
+    widest probe tier — the tier is known only after the walk — with
+    every column before the host-probe block in place (windows, lengths
+    and flags zero, uri probes -1: a pad row is the arena's fill), then
+    cut to the batch's tier by probes()."""
+
+    def __init__(self, cap: int, hw: int, uw: int, lw: int, ns: int = 2,
+                 slots: bool = False):
+        self._shape, self._tail = (cap, hw, uw), (lw, ns, slots)
+        widest = hint_layout(cap, hw, uw, _widest_tier(hw), *self._tail)
+        self.buf = np.empty(widest.words, np.int32)
+        self.buf[: widest.fields[-2][1]] = 0    # up to the probe block
+        self.cols = arena_views(self.buf, widest.fields[:-2])
+        self.cols["up_len"].fill(-1)
+        self.cols["up_slots"].fill(-1)
+
+    def probes(self, maxp: int) -> tuple:
+        """Cut the arena to tier maxp -> (hp_len [cap, maxp], hp_slots
+        [ns, cap, maxp]), -1 filled."""
+        self.layout = hint_layout(*self._shape, maxp, *self._tail)
+        self.buf = self.buf[: self.layout.words]
+        self.buf[self.layout.fields[-2][1]:] = -1
+        self.cols.update(arena_views(self.buf, self.layout.fields[-2:]))
+        return self.cols["hp_len"], self.cols["hp_slots"]
+
+    def queries(self) -> QueryArena:
+        """The encoded batch (after probes()): a table's two salts' slot
+        blocks under the names the kernels read."""
+        c = self.cols
+        q = {k: c[k] for k in _HINT_COLS}
+        q["hp_slot1"], q["hp_slot2"] = c["hp_slots"]
+        q["up_slot1"], q["up_slot2"] = c["up_slots"]
+        return QueryArena(q, self.buf, self.layout, c.get("slots"))
+
+
+def unpack_hint_arena(buf: jnp.ndarray, layout: ArenaLayout) -> dict:
+    """unpack_arena for a hint batch -> (the query dict the hint bodies
+    read, the Maglev slot column or None)."""
+    q = unpack_arena(buf, layout)
+    q["hp_slot1"], q["hp_slot2"] = q.pop("hp_slots")
+    q["up_slot1"], q["up_slot2"] = q.pop("up_slots")
+    return q, q.pop("slots", None)
+
+
+def cidr_queries(cap: int, gated: bool = False,
+                 tid: bool = False) -> QueryArena:
+    """An empty cidr batch at bucket `cap` for the encoder to fill: every
+    row a pad row (family -1: it matches no group; the rest zero)."""
+    layout = cidr_layout(cap, gated, tid)
+    arena = np.zeros(layout.words, np.int32)
+    q = QueryArena(arena_views(arena, layout.fields), arena, layout)
+    q["fam"].fill(-1)
+    return q
+
+
 def _scatter_strings(strs: list, win: np.ndarray, qlen: np.ndarray,
                      has: np.ndarray, reverse: bool) -> None:
     """One column of a batch (hosts or uris, None = absent) into its
@@ -286,46 +480,42 @@ def _scatter_strings(strs: list, win: np.ndarray, qlen: np.ndarray,
     win.reshape(-1)[flat] = blob
 
 
-def _fill_query_windows(hints: Sequence, hw: int, uw: int, cap: int):
-    """Shared query-byte-window fill for the vectorized encoders:
-    -> (hostb [cap,hw] u8 reversed, hlen, has_host, urib [cap,uw] u8,
-    ulen, has_uri, port). Rows past len(hints) stay zero (pad rows).
+def _fill_query_windows(hints: Sequence, c: dict) -> None:
+    """Shared query-byte-window fill for the vectorized encoders, into
+    the arena's columns `c` (zero so far): hostb [cap,hw] u8 reversed,
+    hlen, has_host, urib [cap,uw] u8, ulen, has_uri, port. Rows past
+    len(hints) stay zero (pad rows).
     The batch is taken apart into its three columns once; nothing
     walks it hint by hint after that. The small-batch encoder fuses its
     walk with its per-hint hashing and intentionally does not share
     this."""
-    b = len(hints)
-    q_hostb = np.zeros((cap, hw), np.uint8)
-    q_hlen = np.zeros(cap, np.int32)
-    q_has_host = np.zeros(cap, bool)
-    q_urib = np.zeros((cap, uw), np.uint8)
-    q_ulen = np.zeros(cap, np.int32)
-    q_has_uri = np.zeros(cap, bool)
-    q_port = np.zeros(cap, np.int32)
-    _scatter_strings([h.host for h in hints], q_hostb, q_hlen, q_has_host,
-                     reverse=True)
-    _scatter_strings([h.uri for h in hints], q_urib, q_ulen, q_has_uri,
-                     reverse=False)
-    q_port[:b] = [h.port for h in hints]
-    return (q_hostb, q_hlen, q_has_host, q_urib, q_ulen, q_has_uri,
-            q_port)
+    _scatter_strings([h.host for h in hints], c["hostb"], c["hlen"],
+                     c["has_host"], reverse=True)
+    _scatter_strings([h.uri for h in hints], c["urib"], c["ulen"],
+                     c["has_uri"], reverse=False)
+    c["port"][:len(hints)] = [h.port for h in hints]
 
 
 def _encode_hint_arrays(hints: Sequence, cap: int, hw: int, uw: int,
                         host_salts: Sequence[int], host_cap: int,
                         uri_salts: Sequence[int], uri_cap: int,
-                        lset: Sequence[int], lset_w: int) -> tuple:
-    """The vectorized encoders' one body -> (q, hp_slots, up_slots): q
+                        lset: Sequence[int], lset_w: int,
+                        slots: bool = False) -> "_HintArena":
+    """The vectorized encoders' one body -> the batch's arena, filled:
     the byte windows and the probe positions (shared by every salt),
     and the probe slots under each of `host_salts` / `uri_salts` — a
-    table's two, or S shards' 2 S — as hp_slots [NS, cap, P] /
-    up_slots [NS, cap, lset_w].
+    table's two, or S shards' 2 S — as its columns hp_slots [NS, cap,
+    P] / up_slots [NS, cap, lset_w]. slots: leave room for the Maglev
+    slot column.
 
-    Arrays come out at `cap` rows; rows past len(hints) are pad rows
+    Columns come out at `cap` rows; rows past len(hints) are pad rows
     (zero windows, -1 probes) that no hash pass ever saw."""
     b = len(hints)
-    (q_hostb, q_hlen, q_has_host, q_urib, q_ulen, q_has_uri,
-     q_port) = _fill_query_windows(hints, hw, uw, cap)
+    arena = _HintArena(cap, hw, uw, lset_w, len(host_salts), slots)
+    c = arena.cols
+    _fill_query_windows(hints, c)
+    q_hostb, q_hlen, q_has_host = c["hostb"], c["hlen"], c["has_host"]
+    q_urib, q_ulen, q_has_uri = c["urib"], c["ulen"], c["has_uri"]
 
     # --- host probes: every dot position p (suffix), then exact
     # (p = hlen), ascending. Valid probe lengths are <= hw-1 (no rule
@@ -357,8 +547,7 @@ def _encode_hint_arrays(hints: Sequence, cap: int, hw: int, uw: int,
     at = pr * maxp + np.concatenate([      # and its cell in [cap, maxp]
         np.arange(dr.size) - np.repeat(np.cumsum(nd) - nd, nd), nd[er]])
     ns = len(host_salts)
-    hp_len = np.full((cap, maxp), -1, np.int32)
-    hp_slots = np.full((ns, cap, maxp), -1, np.int32)
+    hp_len, hp_slots = arena.probes(maxp)
     hp_len.reshape(-1)[at] = pl
     slot = (hh.reshape(lim + 1, ns * b)[pl, np.arange(ns)[:, None] * b + pr]
             & np.uint64(host_cap - 1)).astype(np.int32)  # [ns, probes]
@@ -373,19 +562,13 @@ def _encode_hint_arrays(hints: Sequence, cap: int, hw: int, uw: int,
     uh = CK.rolling_fnv64(q_urib[:b, :ulim], uri_salts)  # [ulim+1,NS,b]
     lv = (lens[None, :] >= 0) & (lens[None, :] <= q_ulen[:b, None]) & \
         q_has_uri[:b, None]
-    up_len = np.full((cap, lset_w), -1, np.int32)
-    up_len[:b] = np.where(lv, lens[None, :], -1)
-    up_slots = np.full((len(uri_salts), cap, lset_w), -1, np.int32)
+    c["up_len"][:b] = np.where(lv, lens[None, :], -1)
     # a length past ulim is valid for no row; clip keeps the gather in
-    up_slots[:, :b] = np.where(
+    c["up_slots"][:, :b] = np.where(
         lv[None],
         (uh[np.clip(lens, 0, ulim)].transpose(1, 2, 0)
          & np.uint64(uri_cap - 1)).astype(np.int32), -1)
-    return {
-        "hostb": q_hostb, "hlen": q_hlen, "has_host": q_has_host,
-        "urib": q_urib, "ulen": q_ulen, "has_uri": q_has_uri,
-        "port": q_port, "hp_len": hp_len, "up_len": up_len,
-    }, hp_slots, up_slots
+    return arena
 
 
 # the python-int FNV form lives in ops/cuckoo (single source for the
@@ -413,21 +596,20 @@ SMALL_ENCODE = int(os.environ.get("VPROXY_TPU_SMALL_ENCODE", "28"))
 
 
 def _encode_hint_queries_small(hints: Sequence, tab: HashHintTable,
-                               pad_to: int) -> dict:
+                               pad_to: int,
+                               slots: bool = False) -> QueryArena:
     """Per-hint python encoder, bit-identical outputs to the vectorized
     path (same probe order: dot suffixes ascending, exact slot last;
-    same shapes: MAXP tier + lset_cap widths), O(bytes) python ints
-    instead of O(W) numpy dispatches."""
+    same shapes: MAXP tier + lset_cap widths; the same arena, byte for
+    byte), O(bytes) python ints instead of O(W) numpy dispatches."""
     b = len(hints)
     cap = max(b, pad_to)
     W = tab.hw
-    q_hostb = np.zeros((cap, W), np.uint8)
-    q_hlen = np.zeros(cap, np.int32)
-    q_has_host = np.zeros(cap, bool)
-    q_urib = np.zeros((cap, tab.uw), np.uint8)
-    q_ulen = np.zeros(cap, np.int32)
-    q_has_uri = np.zeros(cap, bool)
-    q_port = np.zeros(cap, np.int32)
+    arena = _HintArena(cap, W, tab.uw, tab.caps["lset"], slots=slots)
+    c = arena.cols
+    q_hostb, q_hlen, q_has_host = c["hostb"], c["hlen"], c["has_host"]
+    q_urib, q_ulen, q_has_uri = c["urib"], c["ulen"], c["has_uri"]
+    q_port = c["port"]
 
     s1, s2 = int(tab.host_salts[0]), int(tab.host_salts[1])
     us1, us2 = int(tab.uri_salts[0]), int(tab.uri_salts[1])
@@ -488,36 +670,27 @@ def _encode_hint_queries_small(hints: Sequence, tab: HashHintTable,
         q_port[i] = h.port
 
     maxp = next((t for t in MAXP_TIERS if t >= need), MAXP_TIERS[-1])
-    hp_len = np.full((cap, maxp), -1, np.int32)
-    hp_slot1 = np.full((cap, maxp), -1, np.int32)
-    hp_slot2 = np.full((cap, maxp), -1, np.int32)
+    hp_len, (hp_slot1, hp_slot2) = arena.probes(maxp)
     for i, pr in enumerate(probes):
         for j, (plen, sl1, sl2) in enumerate(pr[:maxp]):
             hp_len[i, j] = plen
             hp_slot1[i, j] = sl1
             hp_slot2[i, j] = sl2
-    lset_cap = tab.caps["lset"]
-    up_len = np.full((cap, lset_cap), -1, np.int32)
-    up_slot1 = np.full((cap, lset_cap), -1, np.int32)
-    up_slot2 = np.full((cap, lset_cap), -1, np.int32)
+    up_len, (up_slot1, up_slot2) = c["up_len"], c["up_slots"]
     for i, upr in enumerate(uprobes):
         for (li, l, sl1, sl2) in upr:
             up_len[i, li] = l
             up_slot1[i, li] = sl1
             up_slot2[i, li] = sl2
-
-    return {
-        "hostb": q_hostb, "hlen": q_hlen, "has_host": q_has_host,
-        "urib": q_urib, "ulen": q_ulen, "has_uri": q_has_uri,
-        "port": q_port,
-        "hp_len": hp_len, "hp_slot1": hp_slot1, "hp_slot2": hp_slot2,
-        "up_len": up_len, "up_slot1": up_slot1, "up_slot2": up_slot2,
-    }
+    return arena.queries()
 
 
 def encode_hint_queries(hints: Sequence, tab: HashHintTable,
-                        pad_to: int = 0) -> dict:
-    """Hints -> device-ready query dict incl. precomputed probe slots.
+                        pad_to: int = 0, slots: bool = False) -> QueryArena:
+    """Hints -> device-ready query dict incl. precomputed probe slots,
+    its columns the views of one arena (QueryArena: what a served launch
+    is handed). slots: leave room in it for the Maglev slot column of
+    the fused programs (the result's `slots`, zero: the caller fills it).
 
     Host-side work is vectorized numpy (_encode_hint_arrays): the batch
     becomes arrays at the first step, then one rolling-FNV walk over the
@@ -531,13 +704,11 @@ def encode_hint_queries(hints: Sequence, tab: HashHintTable,
     """
     if len(hints) <= SMALL_ENCODE:
         return _encode_hint_queries_small(hints, tab,
-                                          max(pad_to, len(hints)))
-    q, hs, us = _encode_hint_arrays(
+                                          max(pad_to, len(hints)), slots)
+    return _encode_hint_arrays(
         hints, max(len(hints), pad_to), tab.hw, tab.uw,
         tab.host_salts, tab.host_cap, tab.uri_salts, tab.uri_cap,
-        tab.lset, tab.caps["lset"])
-    return {**q, "hp_slot1": hs[0], "hp_slot2": hs[1],
-            "up_slot1": us[0], "up_slot2": us[1]}
+        tab.lset, tab.caps["lset"], slots).queries()
 
 
 def _probe_buckets(slots, plen, used, klen, kbytes, bs, bc, qbytes, iota):
@@ -1063,9 +1234,31 @@ def cidr_set_match(t: dict, addr16: jnp.ndarray, fam: jnp.ndarray,
     return cidr_hash_match(t, addr16, fam, port, tid)
 
 
-hint_hash_jit = jax.jit(hint_hash_match)
-cidr_hash_jit = jax.jit(cidr_hash_match)
-cidr_set_jit = jax.jit(cidr_set_match)
+def jit_packed(name: str, body):
+    """jit a served program, body(*tables, buf, layout), whose query is
+    one arena (unpack_arena its first lines), under `name`: the compiled
+    program is `jit_<name>` in a device trace, and the benchmark's
+    kernel metrics find it by that."""
+    body.__name__ = body.__qualname__ = name
+    return jax.jit(body, static_argnames="layout")
+
+
+def _hint_packed(t: dict, buf, layout: ArenaLayout):
+    return hint_hash_match(t, unpack_hint_arena(buf, layout)[0])
+
+
+def _cidr_packed(match):
+    def packed(t: dict, buf, layout: ArenaLayout):
+        q = unpack_arena(buf, layout)
+        return match(t, q["a16"], q["fam"], port=q.get("port"),
+                     tid=q.get("tid"))
+    return packed
+
+
+# the "jax" backend's served entries: (tables, arena, layout)
+hint_hash_jit = jit_packed("hint_hash_match", _hint_packed)
+cidr_hash_jit = jit_packed("cidr_hash_match", _cidr_packed(cidr_hash_match))
+cidr_set_jit = jit_packed("cidr_set_match", _cidr_packed(cidr_set_match))
 classify_hash_jit = jax.jit(classify_hash_all)
 
 
@@ -1246,13 +1439,15 @@ def encode_hint_queries_sharded(hints: Sequence, stab: ShardedHashTable,
     lw = t0.caps.get("lset_u") or _pow2(max(len(lset_u), 1), 4)
     # salt s of every shard, then salt s+1 of every shard: rows [:S] of
     # the slot arrays are the shards' first-salt slots, [S:] the second
-    q, hs, us = _encode_hint_arrays(
+    c = _encode_hint_arrays(
         hints, max(len(hints), pad_to or 0), t0.hw, t0.uw,
         [t.host_salts[k] for k in (0, 1) for t in shards], t0.host_cap,
         [t.uri_salts[k] for k in (0, 1) for t in shards], t0.uri_cap,
-        lset_u, lw)
+        lset_u, lw).cols
+    hs, us = c["hp_slots"], c["up_slots"]
     # shard-invariant keys: a zero-stride broadcast view on the shard
     # axis (device_put materializes each device's slice)
-    return {**{k: np.broadcast_to(v, (S,) + v.shape) for k, v in q.items()},
+    return {**{k: np.broadcast_to(c[k], (S,) + c[k].shape)
+               for k in _HINT_COLS},
             "hp_slot1": hs[:S], "hp_slot2": hs[S:],
             "up_slot1": us[:S], "up_slot2": us[S:]}

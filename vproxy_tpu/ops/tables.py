@@ -258,20 +258,23 @@ def encode_hints(hints: Sequence) -> dict:
             "has_uri": has_uri, "port": port}
 
 
-def encode_ips(addrs: Sequence[bytes]) -> tuple[np.ndarray, np.ndarray]:
-    """-> (addr16 [B,16] uint8, family [B] i32)."""
+def encode_ips(addrs: Sequence[bytes],
+               out: Optional[tuple] = None) -> tuple[np.ndarray, np.ndarray]:
+    """-> (addr16 [B,16] uint8, family [B] i32). out: that pair at a
+    batch bucket's rows (>= B, the address rows zero) to fill and hand
+    back instead — rows past B are left as they are."""
     b = len(addrs)
+    a16, fam = out if out is not None else (
+        np.zeros((b, 16), dtype=np.uint8), np.zeros(b, dtype=np.int32))
     # all-v4 fast path (the switch burst, LB accept batches): one buffer
     # reshape instead of a python loop — per-batch encode showed up in
     # the data-plane profile
     if b and all(len(a) == 4 for a in addrs):
-        out = np.zeros((b, 16), dtype=np.uint8)
-        out[:, 12:] = np.frombuffer(b"".join(addrs),
-                                    dtype=np.uint8).reshape(b, 4)
-        return out, np.full(b, V4, dtype=np.int32)
-    out = np.zeros((b, 16), dtype=np.uint8)
-    fam = np.zeros(b, dtype=np.int32)
+        a16[:b, 12:] = np.frombuffer(b"".join(addrs),
+                                     dtype=np.uint8).reshape(b, 4)
+        fam[:b] = V4
+        return a16, fam
     for i, a in enumerate(addrs):
-        out[i] = np.frombuffer(to16(a), dtype=np.uint8)
+        a16[i] = np.frombuffer(to16(a), dtype=np.uint8)
         fam[i] = V4 if len(a) == 4 else V6
-    return out, fam
+    return a16, fam

@@ -218,6 +218,7 @@ _GENERATION = [0]
 _MATCHERS: "weakref.WeakSet" = weakref.WeakSet()
 _LAST_SERVE = [0.0]  # monotonic ts of the last serving-path read
 _LAUNCHES = [0]      # device launches on the dispatch path (per batch)
+_HOST_ARRAYS = [0]   # numpy arguments those launches were handed
 _FUSED_DISP = [0]    # of which: fused one-launch dispatches
 
 
@@ -268,20 +269,26 @@ def _host_arrays(args) -> tuple:
     return n, nbytes
 
 
-def launch_span(kind: str, bucket: int, fused: bool = False, args=()):
+def launch_span(kind: str, bucket: int, fused: bool = False, args=(),
+                host_arrays: int = 1):
     """Count one device launch (note_launch) and time it: the `launch`
     span (utils/trace) around the jitted call itself. That call
     enqueues the program AND uploads its numpy arguments — the served
     path makes no explicit device_put, so the two are one number here;
     what tells them apart is what the call was handed: args, the
     call's arguments, of which the span's `items` are the numpy ones
-    and `h2d_bytes` their bytes. (No `cpu_ns`: the thread CPU clock of
+    and `h2d_bytes` their bytes. host_arrays: how many of them are
+    numpy, as the call site knows it — always counted
+    (vproxy_engine_launch_host_arrays_total: over the launches, 1.0
+    where every launch is handed one packed arena), the walk over args
+    only while tracing. (No `cpu_ns`: the thread CPU clock of
     the chip's host moves in 10 ms ticks and read 0.21 of the wall over
     busy stretches of 1 ms — PERF.md §7.)
     fused vs unfused is distinguishable per launch, so a sampled
     request's trace shows how many programs its batch really cost.
     bucket: the padded batch the program was compiled for."""
     note_launch()
+    _HOST_ARRAYS[0] += host_arrays
     n = nbytes = 0
     if trace.SAMPLE:
         n, nbytes = _host_arrays(args)
@@ -299,6 +306,10 @@ def encode_span(items: int):
 
 def dispatch_launches_total() -> int:
     return _LAUNCHES[0]
+
+
+def launch_host_arrays_total() -> int:
+    return _HOST_ARRAYS[0]
 
 
 def fused_dispatches_total() -> int:
@@ -560,11 +571,11 @@ def fused_dispatch(hm, hsnap: tuple, mm, msnap: tuple, hints,
     mtab, mdev = msnap[0], msnap[1]
     if mtab is None or mdev is None:
         return None
-    q, slots = _fused_encode(hsnap, len(mtab), hints, ips, ports, pad_to)
+    q = _fused_encode(hsnap, len(mtab), hints, ips, ports, pad_to)
     from ..ops import fused as F
-    with launch_span("cpick", len(slots), fused=True,
-                     args=(fd, q, mdev, slots)):
-        return F.fused_jit(fd, q, mdev, slots)
+    with launch_span("cpick", len(q.slots), fused=True,
+                     args=(fd, mdev, q.arena)):
+        return F.fused_jit(fd, mdev, q.arena, q.layout)
 
 
 def grouped_dispatch(hsnap: tuple, ssnap, m: int, hints,
@@ -586,58 +597,56 @@ def grouped_dispatch(hsnap: tuple, ssnap, m: int, hints,
     if fd is None or not hsnap[2] or col is None or col[1] is None \
             or ssnap.dev is None:
         return None
-    q, slots = _fused_encode(hsnap, m, hints, ips, ports, pad_to)
+    q = _fused_encode(hsnap, m, hints, ips, ports, pad_to)
     from ..ops import fused as F
-    with launch_span("cpick", len(slots), fused=True,
-                     args=(fd, q, col[1], ssnap.dev, slots)):
-        return F.group_jit(fd, q, col[1], ssnap.dev[1], ssnap.dev[0], slots)
+    with launch_span("cpick", len(q.slots), fused=True,
+                     args=(fd, col[1], ssnap.dev, q.arena)):
+        return F.group_jit(fd, col[1], ssnap.dev[1], ssnap.dev[0], q.arena,
+                           q.layout)
 
 
 def _fused_encode(hsnap: tuple, m: int, hints, ips, ports,
-                  pad_to: Optional[int]) -> tuple:
+                  pad_to: Optional[int]) -> H.QueryArena:
     """The host half of one fused batch, either program's: the encoded
-    hint queries and the Maglev slots of a table of m slots, both
-    padded to the batch's bucket; counts the fused dispatch."""
+    hint queries and, in the same arena, the Maglev slots of a table of
+    m slots, at the batch's bucket; counts the fused dispatch."""
     note_serving()
-    q = _fused_hint_q(hsnap[0], hints, pad_to)
+    q = _fused_hint_q(hsnap[0], hints, pad_to, slots=True)
     _FUSED_DISP[0] += 1
-    return q, _fused_slots(m, ips, ports, q["hostb"].shape[0])
+    _fused_slots(m, ips, ports, q.slots)
+    return q
 
 
-def _fused_hint_q(tab, hints, pad_to: Optional[int]) -> dict:
+def _fused_hint_q(tab, hints, pad_to: Optional[int],
+                  slots: bool = False) -> H.QueryArena:
     with encode_span(len(hints)):
-        return H.encode_hint_queries(hints, tab, pad_to=pad_to or 0)
+        return H.encode_hint_queries(hints, tab, pad_to=pad_to or 0,
+                                     slots=slots)
 
 
-def _encode_addrs(addrs, ports, pad_to: Optional[int],
-                  items: int) -> tuple:
-    """(a16, fam, p) of one cidr batch padded to the bucket: family -1
-    marks pad rows — they match no group and walk no trie."""
+def _encode_addrs(addrs, ports, pad_to: Optional[int], items: int,
+                  tid: bool = False) -> H.QueryArena:
+    """One cidr batch at its bucket, the columns a16, fam and (ports
+    given) port of one arena, with room for a table-id column where
+    `tid`: family -1 marks pad rows — they match no group and walk no
+    trie."""
     with encode_span(items):
-        a16, fam = T.encode_ips(addrs)
-        p = None if ports is None else np.asarray(ports, np.int32)
-        if pad_to and pad_to > a16.shape[0]:
-            k = pad_to - a16.shape[0]
-            a16 = np.concatenate([a16, np.zeros((k,) + a16.shape[1:],
-                                                a16.dtype)])
-            fam = np.concatenate([fam, np.full(k, -1, fam.dtype)])
-            if p is not None:
-                p = np.concatenate([p, np.zeros(k, p.dtype)])
-    return a16, fam, p
+        q = H.cidr_queries(max(len(addrs), pad_to or 0),
+                           gated=ports is not None, tid=tid)
+        T.encode_ips(addrs, out=(q["a16"], q["fam"]))
+        if ports is not None:
+            q["port"][:len(addrs)] = ports
+    return q
 
 
-def _fused_slots(m: int, ips, ports, cap: int) -> np.ndarray:
+def _fused_slots(m: int, ips, ports, col: np.ndarray) -> None:
     """Host-side Maglev slots of a table of m slots (maglev.flow_slots
     — THE one copy of the slot-hash contract, so fused picks are
-    bit-identical to every other pick plane); pad rows ride slot 0 and
-    are sliced off by the caller."""
+    bit-identical to every other pick plane) into the arena's slot
+    column; pad rows ride slot 0 and are sliced off by the caller."""
     from .maglev import flow_slots
     with encode_span(0):    # the batch's queries count at the hint encode
-        slots = flow_slots(m, ips, ports)
-        if cap > len(slots):
-            slots = np.concatenate([slots, np.zeros(cap - len(slots),
-                                                    np.int64)])
-    return slots
+        col[:len(ips)] = flow_slots(m, ips, ports)
 
 
 class HintMatcher:
@@ -824,16 +833,17 @@ class HintMatcher:
         _install_phase(itid, "swap", t_ph, matcher="hint",
                        generation=self.generation)
 
-    def encode(self, hints: Sequence[Hint]) -> dict:
+    def encode(self, hints: Sequence[Hint]) -> H.QueryArena:
         """Pre-encode a query batch for submit() (hash backend only).
         Bound to the current table version — re-encode after set_rules."""
         assert self.backend == "jax"
         return H.encode_hint_queries(hints, self._tab)
 
-    def submit(self, q: dict):
+    def submit(self, q: H.QueryArena):
         """Dispatch an encoded batch; returns the device array (async)."""
-        with launch_span("hint", q["hostb"].shape[0], args=(self._dev, q)):
-            idx, _ = H.hint_hash_jit(self._dev, q)
+        with launch_span("hint", q["hostb"].shape[0],
+                         args=(self._dev, q.arena)):
+            idx, _ = H.hint_hash_jit(self._dev, q.arena, q.layout)
         return idx
 
     def fused_stat(self) -> dict:
@@ -935,8 +945,9 @@ class HintMatcher:
             # entry: the encoder hashes the real rows only and writes
             # them into the padded bucket, pad rows invalid probes
             q = _fused_hint_q(tab, hints, pad_to)
-            with launch_span("hint", q["hostb"].shape[0], args=(dev, q)):
-                idx, _ = H.hint_hash_jit(dev, q)
+            with launch_span("hint", q["hostb"].shape[0],
+                             args=(dev, q.arena)):
+                idx, _ = H.hint_hash_jit(dev, q.arena, q.layout)
             return idx
         if self.backend == "jax-fp":
             from ..ops import fphash as F
@@ -948,7 +959,8 @@ class HintMatcher:
             # keys on the static mode arg, so passing None would bake
             # the first dispatch's VPROXY_TPU_FP_MEMBER into the cache
             # and silently ignore later changes (stale lowering)
-            with launch_span("hint", max(n, pad_to or 0), args=(dev, q)):
+            with launch_span("hint", max(n, pad_to or 0), args=(dev, q),
+                             host_arrays=len(q)):
                 idx, _ = F.hint_fp_jit(dev, q,
                                        mode=F.default_member_mode())
             return idx
@@ -989,7 +1001,7 @@ class HintMatcher:
             if pad_to and pad_to > n:
                 hints = list(hints) + [Hint()] * (pad_to - n)
             q = T.encode_hints(hints)
-        with launch_span("hint", len(hints), args=(dev, q)):
+        with launch_span("hint", len(hints), args=(dev, q), host_arrays=5):
             idx, _ = hint_match_jit(
                 dev, q["host"], q["has_host"], unpack_bits(q["uri"]),
                 q["has_uri"], q["port"])
@@ -1232,15 +1244,17 @@ class CidrMatcher:
             return np.full(len(addrs), -1, np.int32)
         # route tables (acl=None) have zeroed port-range columns: the port
         # gate must be skipped entirely or every port>0 query misses
-        a16, fam, p = _encode_addrs(
-            addrs, None if acl is None else ports, pad_to,
-            items=len(addrs))
+        q = _encode_addrs(addrs, None if acl is None else ports, pad_to,
+                          items=len(addrs))
+        a16, fam, p = q["a16"], q["fam"], q.get("port")
         if self.backend in ("jax-sharded", "jax-fp-sharded"):
             return self._dispatch_sharded(snap, a16, fam, p, sync=sync)
         # every branch is one dispatch
-        with launch_span("cidr", a16.shape[0], args=(dev, a16, fam, p)):
-            if self.backend == "jax":
-                return H.cidr_hash_jit(dev, a16, fam, p)
+        if self.backend == "jax":
+            with launch_span("cidr", a16.shape[0], args=(dev, q.arena)):
+                return H.cidr_hash_jit(dev, q.arena, q.layout)
+        with launch_span("cidr", a16.shape[0], args=(dev, a16, fam, p),
+                         host_arrays=len(q)):
             if self.backend == "jax-fp":
                 from ..ops import fphash as F
                 return F.cidr_fp_jit(dev, a16, fam, p)
@@ -1565,14 +1579,13 @@ class CidrTableSet:
         n = len(addrs)
         if snap.dev is None or not n:
             return np.full(n, -1, np.int32)
-        a16, fam, p = _encode_addrs(
-            addrs, ports if snap.gated else None, pad_to, items=n)
+        q = _encode_addrs(addrs, ports if snap.gated else None, pad_to,
+                          items=n, tid=True)
         t0 = time.monotonic_ns() if trace.SAMPLE else 0
         col = np.fromiter(map(snap.tid_of.get, keys, _NO_TABLE),
                           np.int32, n)
-        tid = np.zeros(a16.shape[0], np.int32)
-        np.maximum(col, 0, out=tid[:n])
-        fam[:n][col < 0] = -1
+        np.maximum(col, 0, out=q["tid"][:n])
+        q["fam"][:n][col < 0] = -1
         if t0:
             # counted inside the span: the sort hands the GIL over, and
             # a submitter's turn then belongs to what caused it
@@ -1580,6 +1593,6 @@ class CidrTableSet:
             trace.note_span(trace.current_id(), "engine", "table_set", t0,
                             time.monotonic_ns() - t0, items=named,
                             parent="dispatch")
-        with launch_span("cidr", a16.shape[0],
-                         args=(snap.dev, a16, fam, tid, p)):
-            return H.cidr_set_jit(snap.dev, a16, fam, tid, p)
+        with launch_span("cidr", q["fam"].shape[0],
+                         args=(snap.dev, q.arena)):
+            return H.cidr_set_jit(snap.dev, q.arena, q.layout)

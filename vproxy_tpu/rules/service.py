@@ -94,6 +94,7 @@ without a loop get the callback on the dispatcher thread.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from functools import partial
@@ -670,10 +671,32 @@ class ClassifyService:
         # next one encodes/submits; the in-flight result syncs just
         # before its delivery (one host round trip per batch)
         inflight: Optional[_Inflight] = None
+        queued = 0.0    # seconds the last acquire of `_cv` took
+        convoy = sys.getswitchinterval() / 2
         while True:
+            if queued > convoy:
+                # the dispatcher queued for its own lock for more than
+                # half an interpreter slice: a holder lost the
+                # interpreter inside `_submit`, which one submitter's
+                # few microseconds under the lock almost never do and
+                # several submitters do all the time. They convoy on
+                # the lock and on the interpreter, and a dispatcher
+                # that fights them for both comes round to ever smaller
+                # batches (each one a launch, an encode at the small
+                # sizes' cost). It steps aside for as long again as it
+                # queued before it takes their queue (PERF.md §6, PR 38:
+                # the jitted call used to hand the interpreter back once
+                # an argument, which were the submitters' turns) — for
+                # four slices at most: a longer wait was a stall (a
+                # machine stop, a collection under the lock), and
+                # sleeping it again would double a pause
+                with trace.span("engine", "wait", tid=0):
+                    time.sleep(min(queued, 8 * convoy))
             # tracing on: wait, swap, cycle and drain tile this thread
             swap = _Swap() if trace.SAMPLE else None
+            t_lock = time.monotonic()
             with self._cv:
+                queued = time.monotonic() - t_lock
                 if swap is not None:
                     swap.locked()
                 if not self._pending and not self._closed \

@@ -491,6 +491,12 @@ class GlobalInspection:
         self.registry.gauge_f("vproxy_engine_fused_dispatches_total",
                               lambda: self._engine_stat(
                                   "fused_dispatches_total"))
+        # numpy arguments those launches were handed (each one an
+        # implicit upload on the calling thread): over the launches,
+        # 1.0 where every launch takes one packed query arena
+        self.registry.gauge_f("vproxy_engine_launch_host_arrays_total",
+                              lambda: self._engine_stat(
+                                  "launch_host_arrays_total"))
         # the collector and the rule heap (utils/heap): objects frozen
         # out of the collector's reach, the freezes by the event that
         # made them, and the full re-examinations the growth rule asked
