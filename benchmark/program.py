@@ -1,7 +1,8 @@
 """The one place the benchmark touches the program.
 
 What is taken from `vproxy_tpu`: the system under test (ClassifyService,
-the matchers and their TableInstaller), its counters, and its
+the matchers and their TableInstaller; for a burst driver the switch's
+`VpcNetwork`s and `route_lookup_burst`), its counters, and its
 `engine/queue_wait` spans. Everything that decides a number — traffic,
 reference, reduction, peaks — lives beside this file and imports none
 of it. Import this module only after `apply_operator_settings`: the
@@ -227,6 +228,102 @@ def new_service():
     return ClassifyService()
 
 
+CROSSED = -2    # a verdict no reference gives: a rule of another table
+
+
+class SwitchRoutes:
+    """A switch's routing state without its sockets: one `VpcNetwork` a
+    VNI whose routes are one table each of the switch's (v4, v6)
+    `CidrTableSet`, made as `Switch.add_network` / `Switch.route_sets`
+    make them, and `route_lookup_burst`, the program's own function
+    that `vswitch/stack.py _route_flush` hands a drained burst to: ONE
+    synchronous `CidrTableSet.match` on the caller's thread, no
+    ClassifyService. `tables[v]`: VPC v's routes (value_u32, masklen)
+    in RouteTable order, installed the largest VPC first.
+
+    The tables go in as `RouteTable(rules_v4=...)` + `sync_routes()`,
+    not `set_routes()`: `RouteTable.add` scans the table twice a route
+    (10 s for 3,000 routes, minutes for the 10,540 of the largest VPC
+    — PERF.md §7); the order is the same list (tests/test_burst_cell.py).
+
+    The set's host paths (`index_snap`: the small-set scan, the `host`
+    backend, the failover; `oracle_snap`) are counted where they are
+    entered: a burst the device served never reaches them."""
+
+    def __init__(self, tables: list):
+        from vproxy_tpu.rules.engine import CidrTableSet
+        from vproxy_tpu.rules.ir import RouteRule, RouteTable
+        from vproxy_tpu.utils.ip import Network, mask_bytes
+        from vproxy_tpu.vswitch import network as N
+        self.route_lookup_burst = N.route_lookup_burst
+        self.sets = (CidrTableSet("v4"), CidrTableSet("v6"))
+        self.route_set = self.sets[0]   # the cell's routes are v4
+        self.host_lookups = 0
+        for attr in ("index_snap", "oracle_snap"):
+            setattr(self.route_set, attr,
+                    self._counted(getattr(self.route_set, attr)))
+        space = Network(bytes([10, 0, 0, 0]), mask_bytes(8))
+        self.networks = [N.VpcNetwork(v + 1, space, route_sets=self.sets)
+                         for v in range(len(tables))]
+        for v, nets in enumerate(tables):
+            net = self.networks[v]
+            net.routes = RouteTable(rules_v4=[
+                RouteRule(f"v{v}r{i}", Network(int(a).to_bytes(4, "big"),
+                                               mask_bytes(m)), to_vni=v + 1)
+                for i, (a, m) in enumerate(nets)])
+            net.sync_routes()
+
+    def _counted(self, fn):
+        def counted(*a, **kw):
+            self.host_lookups += 1
+            return fn(*a, **kw)
+        return counted
+
+    def views(self) -> list:
+        """Each VPC's v4 table of the set, as `SwitchVpc.views`."""
+        return [n._matcher_v4 for n in self.networks]
+
+    def lookups(self, pool: list) -> list:
+        """Pool rank -> the (VpcNetwork, dst) a burst carries for it."""
+        return [(self.networks[q[2]], q[0]) for _k, q in pool]
+
+    def verdicts(self, results: list, vpcs: np.ndarray) -> np.ndarray:
+        """The RouteRules (or None) the bursts returned, flat -> int32
+        [n]: each rule's index in the table of the VPC the lookup named,
+        -1 for None, CROSSED for a rule of another VPC's table or an
+        object that is no rule of this switch. After the window only."""
+        where = {id(None): (-1, -1)}
+        for v, net in enumerate(self.networks):
+            for i, r in enumerate(net.routes.rules_v4):
+                where[id(r)] = (v, i)
+        ids = np.fromiter(map(id, results), np.int64, len(results))
+        uniq, inv = np.unique(ids, return_inverse=True)
+        tab = np.array([where.get(u, (-2, CROSSED))
+                        for u in uniq.tolist()], np.int32).reshape(-1, 2)
+        vpc, idx = tab[inv, 0], tab[inv, 1].copy()
+        idx[(vpc >= 0) & (vpc != vpcs)] = CROSSED
+        return idx
+
+    def bench_spans(self) -> list:
+        """What a traced run wraps (Instrument): the whole burst call,
+        the set's match (dispatch + the blocking read) and its
+        dispatch_snap (encode + launch), innermost booked first."""
+        return [(self, "route_lookup_burst", "bench/burst"),
+                (self.route_set, "match", "bench/match"),
+                (self.route_set, "dispatch_snap", "bench/submit")]
+
+    def counters(self) -> dict:
+        from vproxy_tpu.rules import engine as E
+        return {"launches": E.dispatch_launches_total(),
+                "host_arrays": E.launch_host_arrays_total(),
+                "host_lookups": self.host_lookups,
+                "backend": self.route_set.backend,
+                "generation": self.route_set.generation}
+
+    def close(self) -> None:
+        self.networks = []
+
+
 def pad_buckets(outstanding: int) -> list:
     from vproxy_tpu.rules.engine import pad_batch
     from vproxy_tpu.rules.service import PAD_LO
@@ -278,6 +375,10 @@ class Instrument:
     and a total of their own for the per-layer readers."""
 
     def __init__(self, svc, sample_every: int):
+        """svc: whatever the driver drives. Of a ClassifyService the
+        three calls below are wrapped where they exist; a service of
+        another kind names its own in `bench_spans()` -> [(owner,
+        attribute, span)]."""
         import jax
         from vproxy_tpu.ops import hashmatch as H
         from vproxy_tpu.ops import tables as T
@@ -291,9 +392,13 @@ class Instrument:
         self._wrap(H, "encode_hint_queries", "bench/encode", items=True)
         self._wrap(T, "encode_ips", "bench/encode", items=True)
         self._wrap(MG, "flow_slots", "bench/encode")
-        self._wrap(svc, "_device_submit", "bench/submit")
-        self._wrap(svc, "_finish_inflight", "bench/readback_deliver")
-        self._wrap(svc, "_deliver", "bench/deliver")
+        for attr, span in (("_device_submit", "bench/submit"),
+                           ("_finish_inflight", "bench/readback_deliver"),
+                           ("_deliver", "bench/deliver")):
+            if hasattr(svc, attr):
+                self._wrap(svc, attr, span)
+        for owner, attr, span in getattr(svc, "bench_spans", list)():
+            self._wrap(owner, attr, span)
         self._prev_sample = trace.sample_every()
         trace.reset()
         trace.configure(sample_every)

@@ -7,8 +7,11 @@ One process, on the machine it is started on; it refuses any platform
 but the TPU (a CPU rehearsal at toy size is selftest.py's business and
 prints no device metric). The harness is driven by data: the cell names
 a configuration and a traffic mix, `configs/<config>.json` names its
-builder, `traffic/<traffic>.json` its driver, `metrics/<metric>.json`
-the reader of one per-layer metric (README.md). The last line of
+builder, `traffic/<traffic>.json` its driver (and, where the driver
+needs the deployment installed another way, a builder of its own),
+`metrics/<metric>.json` the reader of one per-layer metric; a driver
+may bring its own service, control, counters and device-side checks
+(README.md). The last line of
 standard output is the result; the last lines of standard error are the
 numbers `correct` was decided from, each beside its limit.
 """
@@ -192,6 +195,64 @@ def compare(plan, win, want) -> tuple:
     return int(bad.sum()), first
 
 
+# ---- what a driver may bring in place of run.py's own (README.md)
+
+# run.py's own device-side checks, for a driver that brings none: the
+# ClassifyService's counters. name -> what it is for: "device" = the
+# proof that the device served, "host" = answered on the host
+SERVICE_CHECKS = {"answered_by_host_oracle": "host", "failovers": "other",
+                  "not_answered_by_device": "device"}
+KEPT = ("wrong_verdicts", "undelivered")    # run.py's, never a driver's
+
+
+def service_checks(totals: dict, submitted: int) -> dict:
+    return {
+        "answered_by_host_oracle": [totals["oracle_queries"], 0],
+        "failovers": [totals["failovers"], 0],
+        "not_answered_by_device": [submitted - totals["device_queries"], 0],
+    }
+
+
+def declared_checks(driver) -> dict:
+    """name -> role of the device-side checks this driver's runs are
+    held to. Refuses, before anything is driven, a driver whose own
+    checks carry no proof that the device served, or that names one of
+    run.py's: "the device served it" is a guarantee of every
+    configuration file."""
+    if not hasattr(driver, "checks"):
+        return SERVICE_CHECKS
+    roles = dict(getattr(driver, "CHECKS", {}))
+    if "device" not in roles.values():
+        raise SystemExit(
+            f"run.py: driver {driver.__name__} brings its own checks "
+            f"{sorted(roles)} and none of them is declared the proof that "
+            f"the device served (CHECKS[name] = \"device\") — nothing run")
+    taken = [k for k in roles if k in KEPT]
+    bad = sorted(set(roles.values()) - {"device", "host", "other"})
+    if taken or bad:
+        raise SystemExit(f"run.py: driver {driver.__name__} CHECKS: {taken} "
+                         f"are run.py's own; unknown roles {bad}")
+    return roles
+
+
+def device_side(driver, roles: dict, dep, svc, plan, win, totals) -> dict:
+    """The device-side checks of this run, each [value, limit]: the
+    driver's own, held to what it declared (the same names, a limit of
+    0 on every proof that the device served), else the service's."""
+    if not hasattr(driver, "checks"):
+        return service_checks(totals, win.n)
+    got = driver.checks(dep, svc, plan, win)
+    if list(got) != list(roles):
+        raise SystemExit(f"run.py: driver {driver.__name__} declared the "
+                         f"checks {list(roles)} and returned {list(got)}")
+    loose = [k for k, (_v, lim) in got.items()
+             if roles[k] != "other" and lim != 0]
+    if loose:
+        raise SystemExit(f"run.py: {loose} count lookups the device did "
+                         f"not serve: their limit is 0")
+    return got
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              require_tpu: bool = True, overrides: dict | None = None,
              control: bool = False, before_window=None) -> dict:
@@ -217,15 +278,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     say(f"cell {workload} seed {seed} seconds {seconds} trace {int(trace)} "
         f"device {dev_info} compile-cache {cache}")
 
-    builder = importlib.import_module("builders." + config["builder"])
+    builder = importlib.import_module(
+        "builders." + traffic.get("builder", config["builder"]))
     driver = importlib.import_module("drivers." + traffic["driver"])
+    roles = declared_checks(driver)
+    read_counters = getattr(driver, "counters", program.counters)
     t0 = time.monotonic()
     dep = builder.build(config, seed)
     plan = driver.Plan(dep, traffic, seed, seconds)
     t_gen = time.monotonic() - t0
     if control:
         import control as control_mod
-        svc = control_mod.ControlService(dep, plan, seed)
+        svc = getattr(driver, "control_service",
+                      control_mod.ControlService)(dep, plan, seed)
         say(f"CONTROL in the program's place: {svc.what}")
     else:
         t0 = time.monotonic()
@@ -243,7 +308,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             f"{len(warm)} compile requests "
             f"{sum(s for _t, _n, s in warm):.1f}s "
             f"(slowest {max((s for _t, _n, s in warm), default=0):.1f}s)")
-        svc = program.new_service()
+        svc = driver.service(dep, plan) if hasattr(driver, "service") \
+            else program.new_service()
     if before_window is not None:
         before_window(svc)
 
@@ -272,13 +338,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     try:
         win = driver.drive(dep, svc, plan, seconds,
-                           lambda: program.counters(svc), on_open, on_tick,
+                           lambda: read_counters(svc), on_open, on_tick,
                            on_close, instrument=inst)
     finally:
         if inst is not None:
             inst.close()
     setup_s = (win.t_open - T_START_NS) / 1e9
-    totals = program.counters(svc)
+    totals = read_counters(svc)
     in_window = clog.between(t_mono_open[0], t_mono_open[1])
     svc.close()
     peak = memory_peak(devs)
@@ -286,7 +352,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     # ---- the earlier lines: everything a wrong verdict is read from
     say(f"service counters (ramp+window+drain): {totals}")
-    say(f"last_failover: {totals['last_failover']!r}")
+    say(f"last_failover: {totals.get('last_failover', '')!r}")
+    if getattr(win, "error", ""):
+        say(f"the driver's loop died: {win.error}")
     say(f"compiles before the window: {len(clog.compiles) - len(in_window)}; "
         f"in the window: {len(in_window)} {in_window[:8]}")
     say(f"garbage collections in the window [count, total ms, longest ms]: "
@@ -306,16 +374,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     for f in first:
         say(f"WRONG: {f}")
     submitted = win.n
-    checks = {
-        "wrong_verdicts": [wrong, 0],
-        "undelivered": [win.undelivered, 0],
-        "answered_by_host_oracle": [totals["oracle_queries"], 0],
-        "failovers": [totals["failovers"], 0],
-        "not_answered_by_device": [submitted - totals["device_queries"], 0],
-    }
+    checks = {"wrong_verdicts": [wrong, 0],
+              "undelivered": [win.undelivered, 0]}
+    checks.update(device_side(driver, roles, dep, svc, plan, win, totals))
     correct = all(0 <= v <= lim for v, lim in checks.values())
-    failed = min(submitted, wrong + win.undelivered
-                 + totals["oracle_queries"])
+    failed = min(submitted, wrong + win.undelivered + sum(
+        checks[k][0] for k, role in roles.items() if role == "host"))
     e2e = driver.end_to_end(win)
     say(f"window {e2e['_window_s']:.3f}s: submitted {submitted} "
         f"(window+ramp), delivered in window {e2e['_delivered_in_window']}, "

@@ -5,7 +5,10 @@
 
 Checks, each printed as one line: every cell of BENCHMARK.json runs end
 to end through run.py's own path (builder, driver, readers) with
-`correct` true; one seed draws the same pool and query sequence twice;
+`correct` true; a cell whose driver brings its own service and checks
+prints those checks, its control comes out not correct through the
+driver's own entry, and without a device-side proof it is refused; one
+seed draws the same pool and query sequence twice;
 the plain reference agrees with its own linear scan and with the
 program's oracle (`rules/oracle.py`, the matchers' `oracle_snap`) on a
 sample; and the trace reduction gives the expected busy time, program
@@ -27,7 +30,7 @@ import run  # noqa: E402  (sets sys.path for the repo root too)
 
 TOY = {"sizes": {"hint_rules": 1000, "routes": 500, "acls": 50,
                  "groups": 16, "backends": 64, "maglev_m": 251},
-       "traffic": {"outstanding": 64, "pool": 512}}
+       "traffic": {"outstanding": 64, "pool": 512, "burst": 64}}
 SEED = 2**31 + 12345
 
 
@@ -56,6 +59,43 @@ def cells_end_to_end(bench: dict) -> None:
                     "  no device metric from a CPU run")
 
 
+def driver_hooks(bench: dict) -> None:
+    """The cells whose driver brings its own service, control and
+    checks (README.md, "A driver")."""
+    import importlib
+    for w in bench["workloads"]:
+        traffic = run.load_json(HERE, "traffic", w["traffic"] + ".json")
+        driver = importlib.import_module("drivers." + traffic["driver"])
+        if not hasattr(driver, "checks"):
+            continue
+        r = run.run_cell(w["name"], SEED, 1.0, False, require_tpu=False,
+                         overrides=TOY, control=True)
+        own = list(r["compared"])[len(run.KEPT):]
+        check(list(r["compared"])[:2] == list(run.KEPT)
+              and own == list(driver.CHECKS)
+              and "device" in driver.CHECKS.values(),
+              f"{w['name']}: compared by run.py's {list(run.KEPT)} and the "
+              f"driver's own {own}")
+        check(not r["correct"]
+              and r["compared"]["wrong_verdicts"]["value"] > 0
+              and all(r["compared"][k]["value"] == 0 for k in own),
+              f"{w['name']}: its control, through the driver's own entry, "
+              f"is not correct ({r['compared']['wrong_verdicts']['value']} "
+              f"wrong verdicts, the device-side checks 0)")
+        keep = driver.CHECKS
+        driver.CHECKS = {k: "other" for k in keep}
+        try:
+            run.run_cell(w["name"], SEED, 1.0, False, require_tpu=False,
+                         overrides=TOY)
+            refused = False
+        except SystemExit as e:
+            refused = "proof that the device served" in str(e)
+        finally:
+            driver.CHECKS = keep
+        check(refused, f"{w['name']}: without a device-side proof among "
+                       f"its checks the driver is refused")
+
+
 def same_seed_same_inputs() -> None:
     import importlib
     bench = run.load_json(run.ROOT, "BENCHMARK.json")
@@ -65,7 +105,8 @@ def same_seed_same_inputs() -> None:
         traffic = run.load_json(HERE, "traffic", w["traffic"] + ".json")
         config["sizes"].update(TOY["sizes"])
         traffic.update(TOY["traffic"])
-        builder = importlib.import_module("builders." + config["builder"])
+        builder = importlib.import_module(
+            "builders." + traffic.get("builder", config["builder"]))
         driver = importlib.import_module("drivers." + traffic["driver"])
         plans = []
         for seed in (SEED, SEED, SEED + 1):
@@ -222,6 +263,7 @@ def main() -> int:
     reference_agrees()
     same_seed_same_inputs()
     cells_end_to_end(bench)
+    driver_hooks(bench)
     print("selftest passed (CPU rehearsal: no device metric printed)")
     return 0
 

@@ -29,6 +29,12 @@ from selftest import TOY  # noqa: E402  (the toy sizes of the rehearsal)
 
 CELLS = [w["name"] for w in
          run.load_json(run.ROOT, "BENCHMARK.json")["workloads"]]
+# the faults below are planted in a ClassifyService; a cell whose driver
+# brings its own service has its own (test_burst_cell.py)
+SERVED = [w["name"] for w in
+          run.load_json(run.ROOT, "BENCHMARK.json")["workloads"]
+          if run.load_json(run.HERE, "traffic", w["traffic"] + ".json")
+          ["driver"] == "classify_closed_loop"]
 
 
 def toy(cell: str, seed: int, **kw) -> dict:
@@ -52,7 +58,7 @@ def test_control_is_not_correct(cell, seed):
     assert r["failed"] > 0
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", SERVED)
 def test_altered_answer_is_not_correct(cell):
     import numpy as np
 
@@ -72,7 +78,7 @@ def test_altered_answer_is_not_correct(cell):
     assert r["compared"]["wrong_verdicts"]["value"] >= 1
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", SERVED)
 def test_host_failover_is_not_correct(cell):
     from vproxy_tpu.utils import failpoint
 

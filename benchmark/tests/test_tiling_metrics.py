@@ -67,6 +67,9 @@ WANT = {
 }
 BENCH = run.load_json(run.ROOT, "BENCHMARK.json")
 NEW = [m["name"] for m in BENCH["per_layer"] if m["name"] in WANT]
+SERVED = [w["name"] for w in BENCH["workloads"]
+          if run.load_json(HERE, "traffic", w["traffic"] + ".json")
+          ["driver"] == "classify_closed_loop"]
 
 
 def spec_of(name: str) -> dict:
@@ -89,13 +92,17 @@ def test_the_new_metrics_are_declared_for_every_cell():
     for name in NEW:
         m = by_name[name]
         assert m["source"] == "program_span" and m["moves"] in e2e
-        assert "workloads" not in m         # every cell, later ones too
+        # every cell, or (ISSUE 39) every cell that drives a
+        # ClassifyService: a burst through the switch has no dispatcher
+        assert m.get("workloads") in (None, SERVED)
         spec = spec_of(name)
         assert os.path.exists(os.path.join(
             HERE, "readers", spec["reader"] + ".py"))
         assert spec["reads"] and spec["covers"]
-    # appended: what the benchmark had stands before them, in its order
-    assert [m["name"] for m in BENCH["per_layer"]][-len(NEW):] == NEW
+    # what the benchmark had stands before them, in its order
+    names = [m["name"] for m in BENCH["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
@@ -207,8 +214,8 @@ def test_a_toy_run_reports_every_new_metric():
     def rise(span, field):
         return after[span][field] - before.get(span, {field: 0})[field]
 
-    # 13 arrays of the encoded query + the slots, every launch
+    # one packed arena every launch (14 arrays until PR 38)
     assert rise("engine/launch", "sum_items") == \
-        14 * rise("engine/launch", "n") > 0
+        rise("engine/launch", "n") > 0
     assert rise("engine/inflight", "n") == rise("engine/d2h_sync", "n") \
         == rise("engine/readback_start", "n") == rise("engine/dispatch", "n")
