@@ -1,6 +1,7 @@
 """Tier-1 copy of benchmark/tests/test_dns_cell.py: the DNS cell of the
 benchmark (`dns-100k.udp-w256`) at toy size through run.py's own path —
-its control and each planted fault turn `correct` false."""
+its control and each planted fault turn `correct` false; and the traced
+line carries the drain's hold (`dns_lookups_per_flush`)."""
 import importlib.util
 import os
 
@@ -19,3 +20,11 @@ test_a_lookup_the_host_answers_trips_the_device_checks = \
     _mod.test_a_lookup_the_host_answers_trips_the_device_checks
 test_an_address_no_group_owns_is_a_wrong_answer = \
     _mod.test_an_address_no_group_owns_is_a_wrong_answer
+
+
+def test_traced_line_reads_the_drains_hold():
+    """`dns_lookups_per_flush` (the `dns/flush` span) is in the traced
+    line, a flush handing over at least one lookup."""
+    t = _mod.toy(45, trace=True)
+    m = t["metrics"]
+    assert t["correct"] and m["dns_lookups_per_flush"]["value"] >= 1

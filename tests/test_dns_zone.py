@@ -60,12 +60,24 @@ def serve(dep, **kw):
     return elg, srv
 
 
+def read_reply(data: bytes, name: str, t: int, owner: dict = None):
+    """A reply to (name, t) as the reference reads it: NXDOMAIN, EMPTY,
+    the group `owner` names for the answered (family, address) — -8 for
+    one it does not — or that pair itself without `owner`; MALFORMED for
+    a reply of another question, rcode or family."""
+    rc, fam, addr = wire.read_answer(data, wire.question(name, t))
+    if rc == 3 and fam == 0:
+        return R.NXDOMAIN
+    if rc == 0 and fam == 0:
+        return R.EMPTY
+    if rc == 0 and fam == (4 if t == R.A else 6):
+        return (fam, addr) if owner is None else owner.get((fam, addr), -8)
+    return wire.MALFORMED
+
+
 def ask(port: int, queries: list, owner: dict = None,
         burst: int = 64) -> list:
-    """-> each reply as the reference reads it: NXDOMAIN, EMPTY, the
-    group `owner` names for the answered (family, address) — -8 for one
-    it does not — or that pair itself without `owner`; MALFORMED for a
-    reply of another question, rcode or family."""
+    """-> each reply as `read_reply` reads it."""
     s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     s.settimeout(5)
     out = []
@@ -79,18 +91,8 @@ def ask(port: int, queries: list, owner: dict = None,
             while len(got) < len(part):
                 data = s.recv(4096)
                 got[int.from_bytes(data[:2], "big")] = data
-            for i, (name, t) in enumerate(part):
-                rc, fam, addr = wire.read_answer(got[i],
-                                                 wire.question(name, t))
-                if rc == 3 and fam == 0:
-                    out.append(R.NXDOMAIN)
-                elif rc == 0 and fam == 0:
-                    out.append(R.EMPTY)
-                elif rc == 0 and fam == (4 if t == R.A else 6):
-                    out.append((fam, addr) if owner is None
-                               else owner.get((fam, addr), -8))
-                else:
-                    out.append(wire.MALFORMED)
+            out += [read_reply(got[i], name, t, owner)
+                    for i, (name, t) in enumerate(part)]
     finally:
         s.close()
     return out

@@ -11,18 +11,31 @@ backend via the matched group's nextIPv4/nextIPv6 (DNS answers
 load-balance); SRV lists all healthy server handles with weights.
 Queries are gated by a SecurityGroup (UDP protocol).
 
+A drain holds its rrsets lookups: while it reads the socket, the
+classify submit of each query that missed the answer cache is kept in a
+list, and the list is handed to the ClassifyService in one tight loop
+when `recvfrom` finds the socket empty (or the list reaches HOLD_CAP).
+A submit between two reads would let the dispatcher take the GIL at the
+read's ctypes call and launch a batch of the few lookups pending then;
+held, the drain's misses reach it as one pending list.
+
 Observability (docs/observability.md): the `dns` plane of
 utils/trace — `dns/drain`, one span a readable wakeup, items = the
 datagrams it read (their parse, the answer-cache hits answered inline
-and the classify submits are inside it); `dns/answer`, one span a
-classified query answered on the loop (the group's `next`, the encode
-and the sendto), items = responses sent. With tracing off each site is
-one branch; on, a span costs two clock reads, a profiler annotation and
-one locked add (3.1 us on the builder's sandbox CPU, PR 40): a wakeup
-one, a query that missed the cache one more. Counters, always on: vproxy_dns_queries_total,
-vproxy_dns_cache_hits_total, vproxy_dns_cache_clears_total (the whole
-answer cache dropped when full) and vproxy_dns_answers_total{rcode},
-one a response the kernel took.
+and the flushes of held lookups are inside it); `dns/flush`, one span a
+hand-over of held lookups, items = the lookups submitted; `dns/answer`,
+one span a classified query answered on the loop (the group's `next`,
+the encode and the sendto), items = responses sent. With tracing off
+each site is one branch; on, a span costs two clock reads, a profiler
+annotation and one locked add (3.1 us on an Intel Xeon host CPU): a
+wakeup one or two, a query that missed the cache one more. Counters,
+always on: vproxy_dns_queries_total, vproxy_dns_cache_hits_total,
+vproxy_dns_cache_clears_total (the whole answer cache dropped when
+full), vproxy_dns_answers_total{rcode}, one a response the kernel took,
+vproxy_dns_held_lookups_total (lookups that went through a hold) and
+vproxy_dns_hold_flushes_total{why="empty"|"cap"} (holds handed over at
+the drain's end / because the hold was full; over the held lookups,
+the burst the service is handed).
 
 Resource introspection extension: `resource_resolver` (installed by the
 control plane, control/command.py) maps the sub-domain left of
@@ -51,22 +64,32 @@ from .client import DNSClient
 
 _log = Logger("dns-server")
 
+# a drain hands its held rrsets lookups over at the latest at this many:
+# the burst a vproxy loop drains in one wakeup (vswitch/switch.py
+# RECV_BURST), so a socket that never empties still gets answers
+HOLD_CAP = 1024
+
 _counters: Optional[tuple] = None
 
 
 def _metrics() -> tuple:
-    """(queries, cache hits, cache clears, answers by rcode) — the
-    process's counters, looked up once (utils/metrics registers them
-    eagerly, so a scrape shows the zeros)."""
+    """(queries, cache hits, cache clears, answers by rcode, held
+    lookups, hold flushes by why) — the process's counters, looked up
+    once (utils/metrics registers them eagerly, so a scrape shows the
+    zeros)."""
     global _counters
     if _counters is None:
-        from ..utils.metrics import DNS_RCODES, GlobalInspection
+        from ..utils.metrics import (DNS_FLUSH_WHYS, DNS_RCODES,
+                                     GlobalInspection)
         gi = GlobalInspection.get()
         _counters = (gi.get_counter("vproxy_dns_queries_total"),
                      gi.get_counter("vproxy_dns_cache_hits_total"),
                      gi.get_counter("vproxy_dns_cache_clears_total"),
                      [gi.get_counter("vproxy_dns_answers_total", rcode=r)
-                      for r in DNS_RCODES])
+                      for r in DNS_RCODES],
+                     gi.get_counter("vproxy_dns_held_lookups_total"),
+                     {w: gi.get_counter("vproxy_dns_hold_flushes_total",
+                                        why=w) for w in DNS_FLUSH_WHYS})
     return _counters
 
 
@@ -108,8 +131,11 @@ class DNSServer:
         self.cache_hits = 0
         self.drops = 0  # responses the kernel refused (EAGAIN) — counted
         self.responses = 0  # responses the kernel took
-        (self._m_queries, self._m_hits, self._m_clears,
-         self._m_answers) = _metrics()
+        (self._m_queries, self._m_hits, self._m_clears, self._m_answers,
+         self._m_held, self._m_flushes) = _metrics()
+        # the rrsets lookups a drain holds until it has read the socket
+        # empty, as (hint, callback); None outside a drain
+        self._hold: Optional[list] = None
         # qname quarantine (vproxy_tpu/policing): a quarantined qname
         # answers REFUSED from this packed-response cache — the flood
         # never re-walks the group or re-packs records
@@ -215,32 +241,57 @@ class DNSServer:
         self._drain(fd)
 
     def _drain(self, fd: int) -> int:
-        """Drain the socket -> datagrams read. Every datagram's ACL gate
-        is submitted to the ClassifyService immediately, so a burst of
-        queries coalesces into one device batch (the DNS arm of the
-        north-star queue)."""
+        """Drain the socket -> datagrams read. Cache hits are answered
+        as they are read; the rrsets lookups of the misses are held and
+        handed to the ClassifyService together once `recvfrom` finds
+        the socket empty, or whenever HOLD_CAP of them are held, so a
+        burst of queries coalesces into one device batch (the DNS arm
+        of the north-star queue). A datagram whose handling raises
+        still lets the lookups held before it go out."""
         n = 0
-        while self._fd is not None:
-            r = vtl.recvfrom(fd)
-            if r is None:
-                return n
-            data, ip, port = r
-            n += 1
-            self.queries += 1
-            self._m_queries.incr()
+        self._hold = []
+        try:
+            while self._fd is not None:
+                r = vtl.recvfrom(fd)
+                if r is None:
+                    break
+                data, ip, port = r
+                n += 1
+                self.queries += 1
+                self._m_queries.incr()
 
-            def gated(ok: bool, data=data, ip=ip, port=port) -> None:
-                if not ok or self._fd is None:
-                    return
-                try:
-                    req = P.parse(data)
-                except P.DNSFormatError:
-                    return
-                self._handle(req, ip, port)
+                def gated(ok: bool, data=data, ip=ip, port=port) -> None:
+                    if not ok or self._fd is None:
+                        return
+                    try:
+                        req = P.parse(data)
+                    except P.DNSFormatError:
+                        return
+                    self._handle(req, ip, port)
 
-            self.security_group.allow_async(Proto.UDP, parse_ip(ip),
-                                            self.bind_port, gated, self.loop)
+                self.security_group.allow_async(Proto.UDP, parse_ip(ip),
+                                                self.bind_port, gated,
+                                                self.loop)
+                if len(self._hold) >= HOLD_CAP:
+                    self._flush("cap")
+                    self._hold = []
+        finally:
+            self._flush("empty")
         return n
+
+    def _flush(self, why: str) -> None:
+        """Close the hold and submit what it held, in arrival order,
+        with no read between two submits. A lookup that starts while
+        the held ones are submitted is submitted at once."""
+        held, self._hold = self._hold, None
+        if not held:
+            return
+        self._m_held.incr(len(held))
+        self._m_flushes[why].incr()
+        search, loop = self.rrsets.search_for_group_async, self.loop
+        with trace.span("dns", "flush", items=len(held)):
+            for hint, cb in held:
+                search(hint, cb, loop)
 
     def _respond(self, req: P.Packet, ip: str, port: int,
                  answers: list, rcode: int = 0) -> None:
@@ -305,7 +356,7 @@ class DNSServer:
                 return
         # continuation pipeline over the questions: each rrsets lookup
         # rides the ClassifyService queue (DNSServer.java:136's scan),
-        # coalescing with other in-flight queries across datagrams
+        # the first one held with the rest of its drain's (`_flush`)
         self._handle_q(req, ip, port, qs, 0, [])
 
     def _quarantine_refuse(self, req: P.Packet, ip: str, port: int,
@@ -395,10 +446,12 @@ class DNSServer:
                 self._answer_group(q, gh, ip, answers)
                 self._handle_q(req, ip, port, qs, i + 1, answers)
 
-            self.rrsets.search_for_group_async(
-                Hint.of_host(domain),
-                partial(self._answer, found) if trace.SAMPLE else found,
-                self.loop)
+            hint = Hint.of_host(domain)
+            cb = partial(self._answer, found) if trace.SAMPLE else found
+            if self._hold is not None:
+                self._hold.append((hint, cb))
+            else:
+                self.rrsets.search_for_group_async(hint, cb, self.loop)
             return
         self._respond(req, ip, port, answers)
 

@@ -25,6 +25,10 @@ CLASSIFY_KINDS = ("hint", "cidr", "cpick")
 # label vocabulary of vproxy_dns_answers_total{rcode} (dns/server.py)
 DNS_RCODES = ("noerror", "formerr", "servfail", "nxdomain", "notimp",
               "refused", "other")
+# why a DNS drain handed its held rrsets lookups over (dns/server.py
+# `_flush`): the drain's end, or the hold full — the closed label
+# vocabulary of vproxy_dns_hold_flushes_total{why}
+DNS_FLUSH_WHYS = ("empty", "cap")
 
 
 def _fmt_labels(labels: Dict[str, str]) -> str:
@@ -609,10 +613,13 @@ class GlobalInspection:
         self.get_counter("vproxy_udp_drop_total")
         # the DNS server's plane (dns/server.py), answers by rcode over
         # its closed vocabulary
-        for name in ("queries", "cache_hits", "cache_clears"):
+        for name in ("queries", "cache_hits", "cache_clears",
+                     "held_lookups"):
             self.get_counter(f"vproxy_dns_{name}_total")
         for rc in DNS_RCODES:
             self.get_counter("vproxy_dns_answers_total", rcode=rc)
+        for why in DNS_FLUSH_WHYS:
+            self.get_counter("vproxy_dns_hold_flushes_total", why=why)
         # maglev table-compiler accounting (rules/maglev.py): eager for
         # the same reason — a scrape shows the zeros before any build
         self.get_counter("vproxy_maglev_table_builds_total")

@@ -72,9 +72,11 @@ process-wide bounded buffer:
   trace shows WHICH phase ate the time on the node that served it.
 * **dns** — the DNS server's loop (dns/server.py): drain (one per
   readable wakeup; `items` datagrams read — their parse, the answers
-  the cache gives inline and the classify submits lie inside it) and
-  answer (one per classified query answered on the loop: the group's
-  `next`, the encode and the sendto; `items` responses sent).
+  the cache gives inline and the flushes lie inside it), flush (one per
+  hand-over of the rrsets lookups a drain held; `items` lookups
+  submitted) and answer (one per classified query answered on the
+  loop: the group's `next`, the encode and the sendto; `items`
+  responses sent).
 * **runtime** — the interpreter: gc_pause (`gen`), from a `gc.callbacks`
   hook that lives exactly as long as tracing is on.
 
@@ -318,7 +320,7 @@ SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
          ("engine", "readback_start"), ("engine", "inflight"),
          ("engine", "kernel_wait"), ("engine", "swap_lock"),
          ("engine", "begin"), ("engine", "release"),
-         ("dns", "drain"), ("dns", "answer"),
+         ("dns", "drain"), ("dns", "flush"), ("dns", "answer"),
          ("runtime", "gc_pause"))
 # bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
 TOTAL_BUCKETS = 27
