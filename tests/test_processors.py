@@ -132,6 +132,7 @@ class H2IdServer:
         self.port = self.sock.getsockname()[1]
         self.alive = True
         self.streams_served = 0
+        self.requests = []      # each stream's request headers
         threading.Thread(target=self._serve, daemon=True).start()
 
     def _serve(self):
@@ -155,7 +156,7 @@ class H2IdServer:
                 elif ftype == PING and not flags & F_ACK:
                     c.sendall(frame(PING, F_ACK, 0, payload))
                 elif ftype == HEADERS:
-                    end.dec.decode(payload)  # keep hpack state in sync
+                    self.requests.append(end.dec.decode(payload))
                     bodies[sid] = b""
                     if flags & F_END_STREAM:
                         self._respond(end, sid, bodies.pop(sid))
@@ -280,6 +281,21 @@ def test_h2_ping_and_window_update_stay_local(stack):
     ftype, flags, sid, payload = end.read_frame()
     assert ftype == PING and flags & F_ACK and payload == b"12345678"
     c.close()
+
+
+def test_h2_request_carries_x_forwarded_for(stack):
+    sa = H2IdServer("A")
+    stack["servers"].append(sa)
+    lb = _mk_lb(stack, "h2", [(sa, HintRule(host="a.example.com"))])
+    c = socket.create_connection(("127.0.0.1", lb.bind_port), timeout=5)
+    c.settimeout(5)
+    src = c.getsockname()[0]
+    end = H2TestEnd(c, server=False)
+    end.handshake()
+    h, d = h2_request(lb.bind_port, "a.example.com", end=end, sid=1)
+    assert d == b"A:"
+    c.close()
+    assert (b"x-forwarded-for", src.encode()) in sa.requests[-1]
 
 
 # ---------------------------------------------------------------- http1

@@ -3,21 +3,32 @@
 Parity: core component/proxy/ProcessorConnectionHandler.java:16 (the L7
 data pump behind every `protocol=<processor>` TcpLB): owns the frontend
 connection plus up to MAX_BACKENDS backend connections, funnels bytes
-into the ProtoSession, executes its backend selections through
-`Upstream.next` (the classify engine), and applies byte/connection
-accounting and backpressure. The reference pumps through ring buffers
-with TODO instructions; here the session pushes into Connection out
-buffers and the engine pauses reading a source whenever a sink's out
-buffer passes the high-water mark (the writable-ET analog).
+into the ProtoSession, executes its backend selections through the
+upstream (the classify engine), and applies byte/connection accounting
+and backpressure. The reference pumps through ring buffers with TODO
+instructions; here the session pushes into Connection out buffers and
+the engine pauses reading a source whenever a sink's out buffer passes
+the high-water mark (the writable-ET analog).
+
+Two selections. `select_async` (the h2 processor's, a stream at a time)
+rides `Upstream.next_async`: the classify and the matched group's pick
+are one ClassifyService submit, on backend "jax" one device launch of a
+micro-batch; the answer comes back on the session's loop (counted in
+vproxy_upstream_selects_total{where}, as every next_async's). `select`
+(http1, framed, the TLS relay) is the synchronous `Upstream.next`,
+served from the host index.
+A connect retry re-selects on the host index (`_next_retry`) either way.
 """
 from __future__ import annotations
 
 import itertools
+import time
 from typing import Optional
 
 from ..net.connection import Connection, Handler
 from ..processors.base import Processor, ProcessorEngine
 from ..rules.ir import Hint
+from ..utils import trace
 from ..utils.ip import parse_ip
 
 MAX_BACKENDS = 1024  # ProcessorConnectionHandler.java:27
@@ -82,6 +93,28 @@ class L7Engine(ProcessorEngine):
         if c is None:
             raise OSError("no healthy backend for hint")
         return _Sel(c, hint)
+
+    def select_async(self, hint: Optional[Hint], cb) -> None:
+        """`select` through `Upstream.next_async`: cb(_Sel | None) on the
+        session's loop — at once where the answer is inline (mode
+        `auto`), later where it rides a device batch, and possibly after
+        the session closed. None: no backend (`select`'s OSError)."""
+        t0 = time.monotonic_ns() if trace.SAMPLE else 0
+        # 1 in N selects a trace of its own: the classify plane's spans
+        # (engine/queue_wait, the batch's phases) attach to it
+        tid = trace.maybe_sample() if t0 else 0
+
+        def done(c) -> None:
+            if t0:
+                trace.note_span(tid, "l7", "select", t0,
+                                time.monotonic_ns() - t0)
+            cb(None if c is None else _Sel(c, hint))
+        try:
+            with trace.bind(tid):
+                self.lb.backend.next_async(self.client_ip, hint, done,
+                                           loop=self.loop)
+        except OSError:     # the classify service is closed
+            cb(None)
 
     def open(self, sel: _Sel) -> int:
         if self.closed:
@@ -266,7 +299,14 @@ class _FrontHandler(Handler):
         self.eng = eng
 
     def on_data(self, conn: Connection, data: bytes) -> None:
-        self.eng.session.on_front_data(data)
+        sess = self.eng.session
+        if trace.SAMPLE:
+            with trace.span("l7", "front_read") as sp:
+                n0 = sess.streams_opened
+                sess.on_front_data(data)
+                sp.items = sess.streams_opened - n0
+            return
+        sess.on_front_data(data)
 
     def on_eof(self, conn: Connection) -> None:
         self.eng.session.on_front_eof()
@@ -295,7 +335,14 @@ class _BackHandler(Handler):
         eng.session.on_back_connected(self.conn_id)
 
     def on_data(self, conn: Connection, data: bytes) -> None:
-        self.eng.session.on_back_data(self.conn_id, data)
+        sess = self.eng.session
+        if trace.SAMPLE:
+            with trace.span("l7", "back_read") as sp:
+                n0 = sess.streams_done
+                sess.on_back_data(self.conn_id, data)
+                sp.items = sess.streams_done - n0
+            return
+        sess.on_back_data(self.conn_id, data)
 
     def on_eof(self, conn: Connection) -> None:
         self.eng.session.on_back_eof(self.conn_id)

@@ -21,17 +21,28 @@ from typing import Optional, Sequence
 from ..rules.engine import HintMatcher
 from ..rules.ir import Hint, HintRule
 from ..rules.maglev import GroupedPair, MaglevTableSet
-from ..utils.metrics import accept_stage_observe
+from ..utils.metrics import accept_stage_observe, upstream_select_incr
 from .servergroup import Connector, ServerGroup
+
+
+def as_rules(annotations) -> list:
+    """A handle's annotations as its list of rules: one HintRule (None:
+    an empty one) or a list of them (an empty list: no rule)."""
+    if isinstance(annotations, (list, tuple)):
+        return list(annotations)
+    return [annotations or HintRule()]
 
 
 class GroupHandle:
     def __init__(self, group: ServerGroup, weight: int,
-                 annotations: Optional[HintRule] = None):
+                 annotations=None):
+        """annotations: one HintRule (None: an empty one), or a list of
+        them — several rules that all name this group (an empty list:
+        none, the group is reached through the WRR fallback alone)."""
         self.alias = group.alias
         self.group = group
         self.weight = weight
-        self.annotations = annotations or HintRule()
+        self.rules = as_rules(annotations)
         # set by Upstream.add: the group's row of the upstream's
         # pick-table set (-1 until the group is `source` at a
         # `_recalc`), and the change listener that re-installs it
@@ -52,14 +63,22 @@ class GroupHandle:
             return None
         return table, [g.maglev_identity(s) for s in servers], (hv, servers)
 
-    def merged_rule(self) -> HintRule:
-        """Handle annotations take precedence over the group's own
-        (Hint.matchLevel merges in that order, Hint.java:104-117)."""
+    @property
+    def annotations(self) -> HintRule:
+        """The first rule, read-only (an empty one where there is none);
+        `rules` holds all of them, in order."""
+        return self.rules[0] if self.rules else HintRule()
+
+    def merged_rule(self, rule: Optional[HintRule] = None) -> HintRule:
+        """Handle annotations (`rule`, default the first) take
+        precedence over the group's own (Hint.matchLevel merges in that
+        order, Hint.java:104-117)."""
+        a = self.annotations if rule is None else rule
         g = self.group.annotations
         return HintRule(
-            host=self.annotations.host if self.annotations.host is not None else g.host,
-            port=self.annotations.port if self.annotations.port != 0 else g.port,
-            uri=self.annotations.uri if self.annotations.uri is not None else g.uri,
+            host=a.host if a.host is not None else g.host,
+            port=a.port if a.port != 0 else g.port,
+            uri=a.uri if a.uri is not None else g.uri,
         )
 
 
@@ -112,7 +131,9 @@ class Upstream:
         `_fire`: the same handles in the same order, the same rules,
         payload and WRR sequence as n single adds, without re-publishing
         every earlier rule at every add (a 100k-record DNS zone is one
-        Upstream: n adds would publish n tables of up to n rules)."""
+        Upstream: n adds would publish n tables of up to n rules).
+        `annotations` may be a list of HintRules: several rules to one
+        group (GroupHandle)."""
         with self._lock:
             have = {id(h.group) for h in self.handles}
             out = []
@@ -166,11 +187,12 @@ class Upstream:
                 if h.ref >= 0:
                     self._picks.release(h.ref)
 
-    def set_annotations(self, group: ServerGroup, annotations: HintRule) -> None:
+    def set_annotations(self, group: ServerGroup, annotations) -> None:
+        """Replace a handle's rules (`annotations` as `add` takes it)."""
         with self._lock:
             for h in self.handles:
                 if h.group is group:
-                    h.annotations = annotations
+                    h.rules = as_rules(annotations)
                     self._recalc()
                     break
             else:
@@ -194,12 +216,17 @@ class Upstream:
             if h.ref < 0 and h.group.method == "source":
                 h.ref = self._picks.alloc()
                 h.on_edge()
-        # the handle list is the rules' payload: published atomically
-        # with the compiled table so async classify results map their
-        # index through the SAME generation (see HintMatcher._pub)
-        self._pair.set_rules([h.merged_rule() for h in self.handles],
-                             payload=list(self.handles),
-                             groups=[h.ref for h in self.handles])
+        # one rule per (handle, rule), in handle order; the handle of
+        # each rule is the rules' payload: published atomically with
+        # the compiled table so async classify results map their index
+        # through the SAME generation (see HintMatcher._pub)
+        rules, payload, refs = [], [], []
+        for h in self.handles:
+            for r in h.rules:
+                rules.append(h.merged_rule(r))
+                payload.append(h)
+                refs.append(h.ref)
+        self._pair.set_rules(rules, payload=payload, groups=refs)
         groups = [h for h in self.handles if h.weight > 0]
         self._wrr_groups = groups
         self._wrr_seq = ServerGroup._wrr_compute(groups) if groups else []
@@ -333,6 +360,13 @@ class Upstream:
         This is the replacement for the reference's per-connection scan
         in Upstream.searchForGroup (Upstream.java:187-198).
 
+        Each answer counts in vproxy_upstream_selects_total{where}:
+        "device_pick" (the pair's pick inside the matched group),
+        "group_next" (the matched group's own `next`: a stale row, or no
+        pick), "fallback" (the WRR fallback: no match, or a matched
+        group with no healthy member), "refused" (nothing, or the
+        classify service is closed: the OSError is raised on).
+
         Span timers: the hint classify (submit->index) lands in the
         `classify` accept-stage histogram, the group/WRR selection in
         `backend_pick` (utils/metrics accept_stage_observe)."""
@@ -340,6 +374,8 @@ class Upstream:
             t0 = time.monotonic()
             c = self._wrr_next(source_ip, fam)
             accept_stage_observe("backend_pick", time.monotonic() - t0)
+            upstream_select_incr("fallback" if c is not None
+                                 else "refused")
             cb(c)
             return
         t_sub = time.monotonic()
@@ -348,18 +384,28 @@ class Upstream:
             t_idx = time.monotonic()
             accept_stage_observe("classify", t_idx - t_sub)
             if handles and 0 <= idx < len(handles):
-                c = _picked(handles[idx], pick, rows) \
-                    or handles[idx].group.next(source_ip, fam)
+                where = "device_pick"
+                c = _picked(handles[idx], pick, rows)
+                if c is None:
+                    where = "group_next"
+                    c = handles[idx].group.next(source_ip, fam)
                 if c is not None:
                     accept_stage_observe("backend_pick",
                                          time.monotonic() - t_idx)
+                    upstream_select_incr(where)
                     cb(c)
                     return
             c = self._wrr_next(source_ip, fam)
             accept_stage_observe("backend_pick", time.monotonic() - t_idx)
+            upstream_select_incr("fallback" if c is not None
+                                 else "refused")
             cb(c)
 
-        self._submit(source_ip, hint, fam, on_idx, loop)
+        try:
+            self._submit(source_ip, hint, fam, on_idx, loop)
+        except OSError:
+            upstream_select_incr("refused")
+            raise
 
     def seek_async(self, source_ip: bytes, hint: Hint, cb,
                    fam: Optional[str] = None, loop=None) -> None:

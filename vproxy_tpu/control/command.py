@@ -22,7 +22,7 @@ from ..components.secgroup import SecurityGroup
 from ..components.servergroup import HealthCheckConfig, ServerGroup
 from ..components.socks5 import Socks5Server
 from ..components.tcplb import TcpLB
-from ..components.upstream import Upstream
+from ..components.upstream import Upstream, as_rules
 from ..components.elgroup import EventLoopGroup
 from ..dns.server import DNSServer
 from ..rules.ir import AclRule, HintRule, Proto
@@ -172,10 +172,12 @@ class Command:
                     raise CmdError(f"param {t} requires a value")
                 key = PARAM_KEYS[t]
                 val = toks[i + 1]
-                # annotations value is json and may contain spaces: re-join
-                if key == "annotations" and val.startswith("{") and not val.endswith("}"):
+                # annotations value is json (an object, or a list of them
+                # for an upstream's group) and may contain spaces: re-join
+                close = {"{": "}", "[": "]"}.get(val[:1])
+                if key == "annotations" and close and not val.endswith(close):
                     j = i + 2
-                    while j < len(toks) and not toks[j - 1].endswith("}"):
+                    while j < len(toks) and not toks[j - 1].endswith(close):
                         val += " " + toks[j]
                         j += 1
                     i = j - 2
@@ -358,6 +360,20 @@ def _anno_to_rule(anno_json: str) -> HintRule:
                     uri=d.get(ANNO_URI))
 
 
+def _anno_to_rules(anno_json: str):
+    """An upstream group's annotations: one json object (one rule), or a
+    json list of them — several rules to one group, `[]` none."""
+    if not anno_json.lstrip().startswith("["):
+        return _anno_to_rule(anno_json)
+    try:
+        rules = json.loads(anno_json)
+    except json.JSONDecodeError as e:
+        raise CmdError(f"annotations must be json: {e}")
+    if not all(isinstance(d, dict) for d in rules):
+        raise CmdError("annotations must be a json object or a list of them")
+    return [_anno_to_rule(json.dumps(d)) for d in rules]
+
+
 def _anno_dict(raw: str) -> dict:
     """Generic annotations param (json object) for vpc/tap resources."""
     try:
@@ -367,6 +383,14 @@ def _anno_dict(raw: str) -> dict:
     if not isinstance(d, dict):
         raise CmdError("annotations must be a json object")
     return d
+
+
+def _rules_to_anno(rules: list) -> str:
+    """What `_anno_to_rules` reads back: a json object for one rule, a
+    json list for none or several."""
+    if len(rules) == 1:
+        return _rule_to_anno(rules[0])
+    return "[" + ",".join(_rule_to_anno(r) for r in rules) + "]"
 
 
 def _rule_to_anno(rule: HintRule) -> str:
@@ -464,7 +488,7 @@ def _h_sg(app: Application, c: Command):
         sg = _need(app.server_groups, c.alias, "server-group")
         ups = _need(app.upstreams, c.target[1], "upstream")
         weight = int(c.params.get("weight", 10))
-        anno = _anno_to_rule(c.params["annotations"]) if "annotations" in c.params else None
+        anno = _anno_to_rules(c.params["annotations"]) if "annotations" in c.params else None
         ups.add(sg, weight, anno)
         return "OK"
     if c.action == "add":
@@ -486,7 +510,8 @@ def _h_sg(app: Application, c: Command):
             ups = _need(app.upstreams, c.contexts[0][1], "upstream")
             if c.action == "list":
                 return [h.alias for h in ups.handles]
-            return [f"{h.alias} -> weight {h.weight} annotations {_rule_to_anno(h.merged_rule())}"
+            return [f"{h.alias} -> weight {h.weight} annotations "
+                    f"{_rules_to_anno([h.merged_rule(r) for r in h.rules])}"
                     for h in ups.handles]
         if c.action == "list":
             return list(app.server_groups.keys())
@@ -506,7 +531,8 @@ def _h_sg(app: Application, c: Command):
                     if "weight" in c.params:
                         h.weight = int(c.params["weight"])
                     if "annotations" in c.params:
-                        h.annotations = _anno_to_rule(c.params["annotations"])
+                        h.rules = as_rules(
+                            _anno_to_rules(c.params["annotations"]))
                     ups._recalc()
                     return "OK"
             raise CmdError(f"server-group {c.alias} not attached to {c.contexts[0][1]}")

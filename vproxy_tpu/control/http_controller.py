@@ -82,7 +82,8 @@ def _details(app, rtype: str) -> list:
         return [{
             "name": a, "serverGroupList": [{
                 "name": h.alias, "weight": h.weight,
-                "annotations": _anno(h.annotations),
+                "annotations": (_anno(h.rules[0]) if len(h.rules) == 1
+                                else [_anno(r) for r in h.rules]),
             } for h in u.handles],
             # classify-engine state (docs/perf.md sharded engine):
             # generation bumps on every atomic standby-table swap
@@ -290,7 +291,7 @@ class HttpController:
                     toks.append(k)
             elif k == "annotations":
                 toks += [k, json.dumps(v, separators=(",", ":"))
-                         if isinstance(v, dict) else str(v)]
+                         if isinstance(v, (dict, list)) else str(v)]
             else:
                 toks += [k, str(v)]
         return " ".join(toks)

@@ -13,7 +13,7 @@ from typing import Optional
 
 from .app import (Application, DEFAULT_ACCEPTOR_ELG, DEFAULT_CONTROL_ELG,
                   DEFAULT_WORKER_ELG)
-from .command import Command, _rule_to_anno
+from .command import Command, _rule_to_anno, _rules_to_anno
 
 DEFAULT_DIR = os.environ.get("VPROXY_TPU_HOME", os.path.expanduser("~/.vproxy_tpu"))
 LAST_CONFIG = os.path.join(DEFAULT_DIR, "vproxy.last")
@@ -54,7 +54,7 @@ def current_config(app: Application) -> str:
     for u in app.upstreams.values():
         lines.append(f"add upstream {u.alias}")
         for h in u.handles:
-            anno = _rule_to_anno(h.annotations)
+            anno = _rules_to_anno(h.rules)     # every rule, or `[]`
             anno_part = f" annotations {anno}" if anno != "{}" else ""
             lines.append(f"add server-group {h.alias} to upstream {u.alias} "
                          f"weight {h.weight}{anno_part}")

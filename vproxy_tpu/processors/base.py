@@ -18,7 +18,7 @@ handler callbacks instead of TODO objects:
     process().mode=handle + feed()    on_front_data / on_back_data
     HandleTODO.send + connTODO        engine.send_back(conn_id, data)
     HandleTODO.produce                engine.send_front(data)
-    ConnectionTODO{-1, hint, chosen}  engine.connect(hint) -> conn_id
+    ConnectionTODO{-1, hint, chosen}  engine.open(engine.select(hint))
     proxy mode (bulk)                 the same callbacks (python relays
                                       in large chunks; the native splice
                                       pump covers protocol="tcp")
@@ -40,10 +40,19 @@ class ProcessorEngine:
     def send_back(self, conn_id: int, data: bytes) -> None:
         raise NotImplementedError
 
-    def connect(self, hint: Optional[Hint]) -> int:
-        """Open a backend connection selected via the upstream (hint goes
-        through the classify engine). Returns a conn_id > 0. Raises
-        OSError if no backend matches. The connection is established
+    def select(self, hint: Optional[Hint]):
+        """The backend for `hint`, chosen now. Raises OSError if none."""
+        raise NotImplementedError
+
+    def select_async(self, hint: Optional[Hint], cb) -> None:
+        """The backend for `hint`, handed to cb(selection | None) on the
+        session's loop: at once or later, and possibly after the session
+        closed. None where `select` would raise."""
+        raise NotImplementedError
+
+    def open(self, selection) -> int:
+        """A backend connection to what `select`/`select_async` chose:
+        a conn_id > 0. Raises OSError. The connection is established
         asynchronously; on_back_connected(conn_id) fires when writable.
         Data may be queued with send_back before that."""
         raise NotImplementedError
@@ -66,6 +75,11 @@ class ProcessorEngine:
 
 class ProtoSession:
     """Per-frontend-connection protocol state machine."""
+
+    # streams this session opened / completed (the h2 processor keeps
+    # them): the `items` of the l7/front_read and l7/back_read spans
+    streams_opened = 0
+    streams_done = 0
 
     def on_front_data(self, data: bytes) -> None:
         raise NotImplementedError

@@ -14,10 +14,20 @@ PING/SETTINGS/WINDOW_UPDATE stay hop-local.
 grpc and h2c (connection-preface cleartext, as used by h2load/grpc) work
 through this processor; our encoder is static-table-only (never adds
 dynamic entries), which keeps hop HPACK state trivially consistent.
+
+A new stream's backend is chosen asynchronously (engine.select_async:
+`Upstream.next_async`, one ClassifyService submit). Until the answer the
+stream is parked: its request headers wait in the stream, and what the
+client sends for it meanwhile (DATA — its window granted at once, as
+for any stream —, END_STREAM, trailers) waits behind them; an
+RST_STREAM drops the stream and the late answer with it. Each parked
+frame counts in vproxy_l7_parked_frames_total. Toward the backend the
+request carries X-Forwarded-For with the client's address.
 """
 from __future__ import annotations
 
 import struct
+from functools import partial
 from typing import Optional
 
 from ..rules.ir import Hint
@@ -46,6 +56,32 @@ ERR_NO, ERR_PROTOCOL, ERR_INTERNAL, ERR_FLOW, ERR_REFUSED = 0, 1, 2, 3, 7
 
 DEFAULT_WINDOW = 65535
 MAX_PEND = 4 * 1024 * 1024  # per-stream relay buffer cap
+
+
+_parked_ctr = None
+
+
+def _count_parked() -> None:
+    """One more frame held for a parked stream."""
+    global _parked_ctr
+    if _parked_ctr is None:
+        from ..utils.metrics import GlobalInspection
+        _parked_ctr = GlobalInspection.get().get_counter(
+            "vproxy_l7_parked_frames_total")
+    _parked_ctr.incr()
+
+
+def with_forwarded_for(headers: list, client_ip: bytes) -> list:
+    """The request headers toward the backend: the client's address
+    appended to X-Forwarded-For (added where the client sent none)."""
+    out, done = [], False
+    for k, v in headers:
+        if k == b"x-forwarded-for" and not done:
+            v, done = v + b", " + client_ip, True
+        out.append((k, v))
+    if not done:
+        out.append((b"x-forwarded-for", client_ip))
+    return out
 
 
 class H2Error(Exception):
@@ -201,7 +237,7 @@ def strip_padding(flags: int, payload: bytes, has_priority: bool) -> bytes:
 class _Stream:
     __slots__ = ("fsid", "conn_id", "bsid", "to_back", "to_front",
                  "end_to_back", "end_to_front", "front_closed", "back_closed",
-                 "got_response", "trailers", "front_trailers")
+                 "got_response", "trailers", "front_trailers", "parked")
 
     def __init__(self, fsid: int, conn_id: int, bsid: int):
         self.fsid = fsid
@@ -216,12 +252,14 @@ class _Stream:
         self.got_response = False
         self.trailers = None         # client trailers waiting behind to_back
         self.front_trailers = None   # backend trailers waiting behind to_front
+        self.parked = None           # request headers, while selecting
 
 
 class H2Session(ProtoSession):
     def __init__(self, engine: ProcessorEngine, client_addr,
                  first_data: bytes = b""):
         self.engine = engine
+        self.client_ip = (client_addr[0] if client_addr else "").encode()
         self.front = _Side(server=True, send=engine.send_front)
         self.backs: dict[int, _Side] = {}
         self.by_key: dict = {}  # connector key -> conn_id
@@ -317,6 +355,8 @@ class H2Session(ProtoSession):
                               struct.pack(">I", max(len(payload), 1))))
                 return
             fr.grant(sid, len(payload))
+            if st.parked is not None:
+                _count_parked()
             st.to_back += body
             if len(st.to_back) > MAX_PEND:
                 self._reset_both(st, ERR_FLOW)
@@ -327,7 +367,10 @@ class H2Session(ProtoSession):
             return
         if ftype == RST_STREAM:
             st = self.streams.pop(sid, None)
-            if st is not None:
+            if st is not None and st.parked is not None:
+                _count_parked()     # its answer finds it gone
+                self.front.stream_window.pop(sid, None)
+            elif st is not None:
                 self.bstreams.pop((st.conn_id, st.bsid), None)
                 back = self.backs.get(st.conn_id)
                 if back is not None and not st.back_closed:
@@ -341,6 +384,12 @@ class H2Session(ProtoSession):
         headers = self._decode(self.front, block)
         end = bool(flags & F_END_STREAM)
         st = self.streams.get(sid)
+        if st is not None and st.parked is not None:
+            # trailers of a stream still waiting for its backend
+            _count_parked()
+            st.end_to_back = True
+            st.trailers = headers
+            return
         if st is not None:
             # trailers toward the backend
             back = self.backs.get(st.conn_id)
@@ -367,32 +416,50 @@ class H2Session(ProtoSession):
             hint = Hint.of_host_uri(authority, path)
         elif authority is not None:
             hint = Hint.of_host(authority)
-        try:
-            sel = self.engine.select(hint)
-        except OSError:
-            self.front.send(
-                frame(RST_STREAM, 0, sid, struct.pack(">I", ERR_REFUSED)))
-            return
-        conn_id = self.by_key.get(sel.key, -1)
-        back = self.backs.get(conn_id)
-        if back is None or back.goaway:
-            conn_id = self.engine.open(sel)
-            back = _Side(server=False, send=lambda b, c=conn_id:
-                         self.engine.send_back(c, b), sid_start=1)
-            back.send(PREFACE + frame(SETTINGS, 0, 0, settings_payload([
-                (S_ENABLE_PUSH, 0), (S_MAX_CONCURRENT, 1024),
-            ])))
-            self.backs[conn_id] = back
-            self.by_key[sel.key] = conn_id
-        bsid = back.alloc_sid()
-        st = _Stream(sid, conn_id, bsid)
+        st = _Stream(sid, -1, -1)
+        st.parked = with_forwarded_for(headers, self.client_ip)
+        st.end_to_back = end
         self.streams[sid] = st
-        self.bstreams[(conn_id, bsid)] = st
+        self.streams_opened += 1
         self.front.stream_window.setdefault(sid, self.front.initial_window)
-        back.stream_window[bsid] = back.initial_window
-        back.send_headers(bsid, headers, end_stream=end)
-        if end:
-            st.end_to_back = True
+        self.engine.select_async(hint, partial(self._selected, st))
+
+    def _selected(self, st: _Stream, sel) -> None:
+        """A parked stream's backend is chosen (None: none): open or
+        reuse the connection to it, send the request, then what arrived
+        for the stream while it waited. An answer for a stream reset
+        meanwhile, or for a session that is gone, is dropped."""
+        if self.dead or getattr(self.engine, "closed", False) \
+                or self.streams.get(st.fsid) is not st:
+            return
+        headers, st.parked = st.parked, None
+        try:
+            if sel is None:
+                raise OSError("no healthy backend for hint")
+            conn_id = self.by_key.get(sel.key, -1)
+            back = self.backs.get(conn_id)
+            if back is None or back.goaway:
+                conn_id = self.engine.open(sel)
+                back = _Side(server=False, send=lambda b, c=conn_id:
+                             self.engine.send_back(c, b), sid_start=1)
+                back.send(PREFACE + frame(SETTINGS, 0, 0, settings_payload([
+                    (S_ENABLE_PUSH, 0), (S_MAX_CONCURRENT, 1024),
+                ])))
+                self.backs[conn_id] = back
+                self.by_key[sel.key] = conn_id
+        except OSError:
+            self.streams.pop(st.fsid, None)
+            self.front.stream_window.pop(st.fsid, None)
+            self.front.send(
+                frame(RST_STREAM, 0, st.fsid, struct.pack(">I", ERR_REFUSED)))
+            return
+        st.conn_id, st.bsid = conn_id, back.alloc_sid()
+        self.bstreams[(conn_id, st.bsid)] = st
+        back.stream_window[st.bsid] = back.initial_window
+        end = st.end_to_back and not st.to_back and st.trailers is None
+        back.send_headers(st.bsid, headers, end_stream=end)
+        if not end:
+            self._pump_back(st)
 
     # ------------------------------------------------------------ backend
 
@@ -550,7 +617,8 @@ class H2Session(ProtoSession):
 
     def _maybe_done(self, st: _Stream) -> None:
         if st.front_closed and st.end_to_back and not st.to_back:
-            self.streams.pop(st.fsid, None)
+            if self.streams.pop(st.fsid, None) is st:
+                self.streams_done += 1
             self.bstreams.pop((st.conn_id, st.bsid), None)
             self.front.stream_window.pop(st.fsid, None)
             back = self.backs.get(st.conn_id)

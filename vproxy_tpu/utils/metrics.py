@@ -29,6 +29,10 @@ DNS_RCODES = ("noerror", "formerr", "servfail", "nxdomain", "notimp",
 # `_flush`): the drain's end, or the hold full — the closed label
 # vocabulary of vproxy_dns_hold_flushes_total{why}
 DNS_FLUSH_WHYS = ("empty", "cap")
+# what answered an `Upstream.next_async` (components/upstream.py): the
+# closed label vocabulary of vproxy_upstream_selects_total{where}
+UPSTREAM_SELECT_WHERES = ("device_pick", "group_next", "fallback",
+                          "refused")
 
 
 def _fmt_labels(labels: Dict[str, str]) -> str:
@@ -539,7 +543,7 @@ class GlobalInspection:
         # as a nonzero drop count, not as mysteriously missing spans
         self.registry.gauge_f("vproxy_trace_spans_total",
                               self._trace_c_spans, plane="lane")
-        for pl in ("accept", "engine", "install", "cluster", "dns"):
+        for pl in ("accept", "engine", "install", "cluster", "dns", "l7"):
             self.registry.gauge_f("vproxy_trace_spans_total",
                                   lambda pl=pl: self._trace_py_spans(pl),
                                   plane=pl)
@@ -620,6 +624,12 @@ class GlobalInspection:
             self.get_counter("vproxy_dns_answers_total", rcode=rc)
         for why in DNS_FLUSH_WHYS:
             self.get_counter("vproxy_dns_hold_flushes_total", why=why)
+        # asynchronous backend selects by what answered
+        # (components/upstream.py next_async), and the L7 plane's frames
+        # held for a stream that waits for its backend (processors/h2.py)
+        for where in UPSTREAM_SELECT_WHERES:
+            self.get_counter("vproxy_upstream_selects_total", where=where)
+        self.get_counter("vproxy_l7_parked_frames_total")
         # maglev table-compiler accounting (rules/maglev.py): eager for
         # the same reason — a scrape shows the zeros before any build
         self.get_counter("vproxy_maglev_table_builds_total")
@@ -893,6 +903,10 @@ _ACCEPT_STAGE_HISTS: Dict[str, Histogram] = {}
 # at first GlobalInspection access so /metrics shows the zero.
 _UDP_DROP_CTR: Optional[Counter] = None
 
+# what answered each Upstream.next_async, by UPSTREAM_SELECT_WHERES;
+# memoized as the stage histograms are
+_UPSTREAM_SELECT_CTRS: Dict[str, Counter] = {}
+
 
 def udp_drop_incr(n: int = 1) -> None:
     global _UDP_DROP_CTR
@@ -900,6 +914,14 @@ def udp_drop_incr(n: int = 1) -> None:
         _UDP_DROP_CTR = GlobalInspection.get().get_counter(
             "vproxy_udp_drop_total")
     _UDP_DROP_CTR.incr(n)
+
+
+def upstream_select_incr(where: str) -> None:
+    c = _UPSTREAM_SELECT_CTRS.get(where)
+    if c is None:
+        c = _UPSTREAM_SELECT_CTRS[where] = GlobalInspection.get().get_counter(
+            "vproxy_upstream_selects_total", where=where)
+    c.incr()
 
 
 def accept_stage_observe(stage: str, seconds: float) -> None:

@@ -77,6 +77,11 @@ process-wide bounded buffer:
   submitted) and answer (one per classified query answered on the
   loop: the group's `next`, the encode and the sendto; `items`
   responses sent).
+* **l7** — an L7 session's loop (components/l7.py, processors/h2.py):
+  front_read (one per frontend read a session parses; `items` streams
+  it opened), select (one per stream whose backend is chosen through
+  `Upstream.next_async`: from its submit to its answer) and back_read
+  (one per backend read relayed; `items` streams it completed).
 * **runtime** — the interpreter: gc_pause (`gen`), from a `gc.callbacks`
   hook that lives exactly as long as tracing is on.
 
@@ -103,7 +108,8 @@ on some hosts), a `note_span()` each for inflight, swap_lock and,
 where they apply, kernel_wait, table_set and group_pick (a locked
 add) and the walk over the launch's arguments; a submit two clock
 reads; the DNS server a readable wakeup and a classified answer one
-span each (a query: two spans where the cache missed). Two deciders:
+span each (a query: two spans where the cache missed); an L7 session
+a read one span and a stream's select a `note_span()`. Two deciders:
 
 * `maybe_sample()` — deterministic counter-based 1-in-N (the accept
   paths; every Nth request).
@@ -140,7 +146,7 @@ SEED = os.environ.get("VPROXY_TPU_TRACE_SEED", "")
 MAX_TRACES = int(os.environ.get("VPROXY_TPU_TRACE_BUF", "512"))
 MAX_SPANS_PER_TRACE = 256
 
-PLANES = ("lane", "accept", "engine", "install", "cluster", "dns")
+PLANES = ("lane", "accept", "engine", "install", "cluster", "dns", "l7")
 
 _lock = threading.Lock()
 _traces: "OrderedDict[int, list]" = OrderedDict()
@@ -321,6 +327,7 @@ SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
          ("engine", "kernel_wait"), ("engine", "swap_lock"),
          ("engine", "begin"), ("engine", "release"),
          ("dns", "drain"), ("dns", "flush"), ("dns", "answer"),
+         ("l7", "front_read"), ("l7", "select"), ("l7", "back_read"),
          ("runtime", "gc_pause"))
 # bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
 TOTAL_BUCKETS = 27
