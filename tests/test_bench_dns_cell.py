@@ -1,9 +1,12 @@
 """Tier-1 copy of benchmark/tests/test_dns_cell.py: the DNS cell of the
 benchmark (`dns-100k.udp-w256`) at toy size through run.py's own path —
 its control and each planted fault turn `correct` false; and the traced
-line carries the drain's hold (`dns_lookups_per_flush`)."""
+line carries the drain's hold (`dns_lookups_per_flush`) and the
+service's hand-over to the server's loop (`loop_callbacks_per_handoff`)."""
 import importlib.util
 import os
+
+import pytest
 
 _path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "benchmark", "tests", "test_dns_cell.py")
@@ -22,9 +25,23 @@ test_an_address_no_group_owns_is_a_wrong_answer = \
     _mod.test_an_address_no_group_owns_is_a_wrong_answer
 
 
-def test_traced_line_reads_the_drains_hold():
+@pytest.fixture(scope="module")
+def traced():
+    """One traced toy run, read by the two cases below."""
+    return _mod.toy(45, trace=True)
+
+
+def test_traced_line_reads_the_drains_hold(traced):
     """`dns_lookups_per_flush` (the `dns/flush` span) is in the traced
     line, a flush handing over at least one lookup."""
-    t = _mod.toy(45, trace=True)
-    m = t["metrics"]
-    assert t["correct"] and m["dns_lookups_per_flush"]["value"] >= 1
+    m = traced["metrics"]
+    assert traced["correct"] and m["dns_lookups_per_flush"]["value"] >= 1
+
+
+def test_traced_line_reads_the_loop_handoff(traced):
+    """`loop_callbacks_per_handoff` (the `engine/handoff` span) is in the
+    traced line: the service hands the server's loop its callbacks, at
+    least one a hand-over."""
+    m = traced["metrics"]
+    assert traced["correct"] \
+        and m["loop_callbacks_per_handoff"]["value"] >= 1

@@ -1,7 +1,8 @@
 """Tier-1 copy of benchmark/tests/test_h2_cell.py: the h2 cell of the
 benchmark (`h2lb-groups256.h2-c16m48`) at toy size through run.py's own
 path — correct with its own checks and traced metrics, its control and
-each planted fault turn `correct` false."""
+each planted fault turn `correct` false; the traced line carries the
+service's hand-over to the h2 loop (`loop_callbacks_per_handoff`)."""
 import importlib.util
 import os
 
@@ -22,3 +23,12 @@ test_a_missing_forwarded_for_trips_its_check = \
     _mod.test_a_missing_forwarded_for_trips_its_check
 test_a_select_the_host_answers_trips_the_device_checks = \
     _mod.test_a_select_the_host_answers_trips_the_device_checks
+
+
+def test_traced_line_reads_the_loop_handoff():
+    """`loop_callbacks_per_handoff` (the `engine/handoff` span) is in the
+    traced line: the service hands the h2 loop its streams' answers, at
+    least one a hand-over."""
+    t = _mod.toy(2**31 + 61, trace=True)
+    m = t["metrics"]
+    assert t["correct"] and m["loop_callbacks_per_handoff"]["value"] >= 1

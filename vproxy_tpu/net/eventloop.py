@@ -4,7 +4,8 @@ Functional equivalent of the reference's selector/SelectorEventLoop.java
 (poll loop :265-322, timer queue :159-168, cross-thread task queue
 :370-389, wakeups, loop-thread confinement): a single thread polls the
 native epoll loop; all state mutation happens on that thread; other
-threads submit closures via run_on_loop() + eventfd wakeup. Timers are a
+threads submit closures via run_on_loop() + eventfd wakeup (a sequence
+of them via run_many_on_loop(): one lock hold, one wakeup). Timers are a
 heapq; the poll timeout is the nearest deadline (same single-clock
 design — one coarse timestamp per tick).
 
@@ -22,7 +23,7 @@ import threading
 import time
 import traceback
 from collections import deque
-from typing import Callable, Optional
+from typing import Callable, Optional, Sequence
 
 from . import vtl
 
@@ -299,20 +300,30 @@ class SelectorEventLoop:
         self._tick_q.append(fn)
 
     def run_on_loop(self, fn: Callable[[], None]) -> bool:
-        """Thread-safe submit + wakeup. Returns False when the loop is
-        gone and the task was dropped (callers owning resources must then
-        clean up themselves — e.g. ClassifyService delivery). True means
-        the task WILL run: enqueue and the closed-flag flip share one
-        lock, and close() drains tasks that raced the shutdown."""
+        """Thread-safe submit + wakeup of one task: run_many_on_loop."""
+        return self.run_many_on_loop((fn,))
+
+    def run_many_on_loop(self, fns: Sequence[Callable[[], None]]) -> bool:
+        """Thread-safe submit of tasks, run in order, with ONE wakeup:
+        off the loop thread they are queued under one `_xq_lock` hold
+        and the loop is woken once (ClassifyService hands a delivered
+        batch's callbacks for one loop over this way); on it they are
+        next_tick'ed. Returns False when the loop is gone and nothing
+        was queued (callers owning resources must then clean up
+        themselves — e.g. ClassifyService delivery). True means every
+        task WILL run: enqueue and the closed-flag flip share one lock,
+        and close() drains tasks that raced the shutdown. The loop runs
+        each through `_timed` alone, so each has its own guard and its
+        own duration against the stall rules."""
         if threading.current_thread() is self._thread:
             if not self._alive():
                 return False
-            self.next_tick(fn)
+            self._tick_q.extend(fns)
             return True
         with self._xq_lock:
             if not self._alive():
                 return False
-            self._xq.append(fn)
+            self._xq.extend(fns)
         if self._lp is not None:
             vtl.LIB.vtl_wakeup(self._lp)
         return True

@@ -87,9 +87,17 @@ submit synchronously (see _device_submit): their per-dispatch cost is
 fixed and high, so parking the dispatcher through the round trip —
 the "natural batching" window above — beats the overlap (A/B'd).
 
-Callbacks are delivered on the submitting event loop via run_on_loop()
-(loop-confinement discipline, SURVEY §5 race-detection row); submissions
-without a loop get the callback on the dispatcher thread.
+Callbacks are delivered on the submitting event loop (loop-confinement
+discipline, SURVEY §5 race-detection row): a delivered batch's callbacks
+for one loop are handed to it in ONE run_many_on_loop() — one lock hold
+and one wakeup for each batch and loop, not one a callback (a wakeup is
+a ctypes call that hands the interpreter to a busy loop thread);
+submissions without a loop get the callback on the dispatcher thread,
+inline, in batch order. Always on, on /metrics:
+vproxy_classify_loop_handoffs_total (one a (batch, loop) hand-over) and
+vproxy_classify_loop_callbacks_total (callbacks handed over); tracing
+on, the `engine/handoff` span, nested in `engine/deliver`, one a
+hand-over, items = callbacks handed.
 """
 from __future__ import annotations
 
@@ -152,6 +160,39 @@ def _guarded_cb(cb, *args) -> None:
         raise
     except Exception:
         _log.error("classify callback failed", exc=True)
+
+
+_handoff_counters: Optional[tuple] = None
+
+
+def _handoff_metrics() -> tuple:
+    """(hand-overs, callbacks handed over) — the process's counters,
+    looked up once (utils/metrics registers them eagerly, so a scrape
+    shows the zeros)."""
+    global _handoff_counters
+    if _handoff_counters is None:
+        from ..utils.metrics import GlobalInspection
+        gi = GlobalInspection.get()
+        _handoff_counters = (
+            gi.get_counter("vproxy_classify_loop_handoffs_total"),
+            gi.get_counter("vproxy_classify_loop_callbacks_total"))
+    return _handoff_counters
+
+
+def _hand_over(loop, fns: list, tid: int) -> None:
+    """One loop's callbacks of a delivered batch in one hand-over: one
+    lock hold and one wakeup (run_many_on_loop). Where the loop is gone
+    nothing was queued, and they run here, in order, so cleanup
+    (closing an accepted fd) still happens."""
+    with trace.span("engine", "handoff", tid=tid, items=len(fns)):
+        handed = loop.run_many_on_loop(fns)
+    if handed:
+        handoffs, callbacks = _handoff_metrics()
+        handoffs.incr()
+        callbacks.incr(len(fns))
+        return
+    for fn in fns:
+        fn()
 
 
 class _Req:
@@ -1043,9 +1084,10 @@ class ClassifyService:
         pair (a scalar row is an error fill: both -1). payload is the
         matcher-owner's object versioned with the table generation that
         served the batch (None when the owner didn't register one).
-        Callbacks run on the submitting loop; if that loop is gone,
-        inline on this thread so cleanup (closing an accepted fd)
-        still happens. tid: the batch's first sampled request."""
+        A request without a loop has its callback run here, in batch
+        order; the others' callbacks are collected per loop and each
+        loop gets its batch's in ONE hand-over (_hand_over), in batch
+        order. tid: the batch's first sampled request."""
         with trace.span("engine", "deliver", tid=tid, cpu=True,
                         items=len(reqs), kind=kind):
             self.stats.record_latencies(time.monotonic(), reqs)
@@ -1054,13 +1096,20 @@ class ClassifyService:
             cpick = kind == "cpick"
             if cpick and rows.ndim == 1:  # an error fill: both -1
                 rows = np.stack([rows, rows], axis=1)
+            by_loop: dict = {}      # loop -> its callbacks, batch order
             for r, row in zip(reqs, rows.tolist()):
                 args = (row[0], row[1], payload) if cpick \
                     else (row, payload)
-                # a closure is built only to be handed to a loop
-                if r.loop is None or not r.loop.run_on_loop(
-                        partial(_guarded_cb, r.cb, *args)):
+                if r.loop is None:
                     _guarded_cb(r.cb, *args)
+                    continue
+                # a closure is built only to be handed to a loop
+                fns = by_loop.get(r.loop)
+                if fns is None:
+                    fns = by_loop[r.loop] = []
+                fns.append(partial(_guarded_cb, r.cb, *args))
+            for loop, fns in by_loop.items():
+                _hand_over(loop, fns, tid)
 
     # ------------------------------------------------------------- control
 

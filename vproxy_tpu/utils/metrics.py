@@ -624,6 +624,11 @@ class GlobalInspection:
             self.get_counter("vproxy_dns_answers_total", rcode=rc)
         for why in DNS_FLUSH_WHYS:
             self.get_counter("vproxy_dns_hold_flushes_total", why=why)
+        # the classify service's deliver (rules/service.py _hand_over):
+        # one a (batch, loop) hand-over and the callbacks handed over —
+        # over the hand-overs, the burst one loop is woken for
+        self.get_counter("vproxy_classify_loop_handoffs_total")
+        self.get_counter("vproxy_classify_loop_callbacks_total")
         # asynchronous backend selects by what answered
         # (components/upstream.py next_async), and the L7 plane's frames
         # held for a stream that waits for its backend (processors/h2.py)

@@ -48,7 +48,9 @@ process-wide bounded buffer:
   after the launch), d2h_sync (the blocking `np.asarray` of the
   result; in a cycle or a drain), group_pick (a grouped pair's batch:
   counting its device picks), deliver (the callback loop: `items`,
-  `cpu_ns`), release (letting go of the finished batch, in a cycle or
+  `cpu_ns`; inside it handoff, one per (batch, event loop): the
+  loop's callbacks queued in one lock hold with one wakeup, `items`
+  callbacks handed), release (letting go of the finished batch, in a cycle or
   a drain: a batch of an earlier wake is freed there — its result,
   its `items` requests, their payloads and callbacks). NOT leaves —
   they lie over the others and are left out of the tiling: swap_lock
@@ -101,7 +103,8 @@ so the spans sit in a profiler trace on its own clock, beside the device.
 Sampling: `VPROXY_TPU_TRACE_SAMPLE` = N samples 1-in-N (0 = off, the
 default). Knob-off cost is one branch per site — per batch on the
 dispatcher, never per query. On, a batch costs its nine `span()`s
-(ten where a second encoder runs), a wake two more and a drain one
+(ten where a second encoder runs, one more for each event loop its
+callbacks are handed to), a wake two more and a drain one
 (two clock reads, a profiler annotation and one locked add each;
 encode and deliver two `thread_time_ns()` more — a syscall of ~6 us
 on some hosts), a `note_span()` each for inflight, swap_lock and,
@@ -325,7 +328,7 @@ SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
          ("engine", "swap"), ("engine", "drain"),
          ("engine", "readback_start"), ("engine", "inflight"),
          ("engine", "kernel_wait"), ("engine", "swap_lock"),
-         ("engine", "begin"), ("engine", "release"),
+         ("engine", "begin"), ("engine", "release"), ("engine", "handoff"),
          ("dns", "drain"), ("dns", "flush"), ("dns", "answer"),
          ("l7", "front_read"), ("l7", "select"), ("l7", "back_read"),
          ("runtime", "gc_pause"))
