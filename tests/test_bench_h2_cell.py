@@ -2,7 +2,8 @@
 benchmark (`h2lb-groups256.h2-c16m48`) at toy size through run.py's own
 path — correct with its own checks and traced metrics, its control and
 each planted fault turn `correct` false; the traced line carries the
-service's hand-over to the h2 loop (`loop_callbacks_per_handoff`)."""
+service's hand-over to the h2 loop (`loop_callbacks_per_handoff`) and
+the loop's work on each answer (`h2_selected_us_per_stream`)."""
 import importlib.util
 import os
 
@@ -28,7 +29,9 @@ test_a_select_the_host_answers_trips_the_device_checks = \
 def test_traced_line_reads_the_loop_handoff():
     """`loop_callbacks_per_handoff` (the `engine/handoff` span) is in the
     traced line: the service hands the h2 loop its streams' answers, at
-    least one a hand-over."""
+    least one a hand-over; and so is `h2_selected_us_per_stream` (the
+    `l7/selected` span), the loop's work on each answer."""
     t = _mod.toy(2**31 + 61, trace=True)
     m = t["metrics"]
     assert t["correct"] and m["loop_callbacks_per_handoff"]["value"] >= 1
+    assert m["h2_selected_us_per_stream"]["value"] > 0

@@ -341,4 +341,5 @@ def test_48_streams_reach_the_backends_the_reference_names(served):
         return tot[key][field] - before.get(key, {}).get(field, 0)
     assert delta("l7/front_read", "sum_items") == 48
     assert delta("l7/select", "n") == 48
+    assert delta("l7/selected", "sum_items") == 48
     assert delta("l7/back_read", "sum_items") == 48

@@ -50,8 +50,7 @@ def test_hpack_decoder_rejects_oversized_table_update():
 
 
 def test_hpack_static_only_encoder_decodes_everywhere():
-    from vproxy_tpu.processors.h2 import _StaticEncoder
-    e = _StaticEncoder()
+    e = hpack.StaticEncoder()
     d = hpack.Decoder()
     hs = [(b":status", b"200"), (b"content-type", b"text/plain"),
           (b"x-id", b"abc")]

@@ -108,7 +108,14 @@ class L7Engine(ProcessorEngine):
             if t0:
                 trace.note_span(tid, "l7", "select", t0,
                                 time.monotonic_ns() - t0)
-            cb(None if c is None else _Sel(c, hint))
+            sel = None if c is None else _Sel(c, hint)
+            if trace.SAMPLE:
+                # the session's work on the answer: the parked request
+                # encoded and written toward its backend
+                with trace.span("l7", "selected", tid=tid, items=1):
+                    cb(sel)
+                return
+            cb(sel)
         try:
             with trace.bind(tid):
                 self.lb.backend.next_async(self.client_ip, hint, done,

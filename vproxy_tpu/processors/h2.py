@@ -13,7 +13,9 @@ PING/SETTINGS/WINDOW_UPDATE stay hop-local.
 
 grpc and h2c (connection-preface cleartext, as used by h2load/grpc) work
 through this processor; our encoder is static-table-only (never adds
-dynamic entries), which keeps hop HPACK state trivially consistent.
+dynamic entries), which keeps hop HPACK state trivially consistent and
+lets each header field's bytes be encoded once a process
+(`hpack.StaticEncoder`).
 
 A new stream's backend is chosen asynchronously (engine.select_async:
 `Upstream.next_async`, one ClassifyService submit). Until the answer the
@@ -109,7 +111,7 @@ class _Side:
         self.buf = bytearray()
         self.preface_left = len(PREFACE) if server else 0
         self.dec = hpack.Decoder()
-        self.enc = _StaticEncoder()
+        self.enc = hpack.StaticEncoder()
         self.conn_window = DEFAULT_WINDOW  # our budget for sending to them
         self.stream_window: dict[int, int] = {}
         self.initial_window = DEFAULT_WINDOW  # their INITIAL_WINDOW_SIZE
@@ -208,15 +210,6 @@ class _Side:
                 pass
         self.got_settings = True
         self.send(frame(SETTINGS, F_ACK, 0))
-
-
-class _StaticEncoder(hpack.Encoder):
-    """HPACK encoder that never grows the dynamic table (always-legal
-    stateless hop encoding; peers still compress toward us and our
-    Decoder tracks their dynamic table)."""
-
-    def __init__(self):
-        super().__init__(max_table_size=0)
 
 
 def strip_padding(flags: int, payload: bytes, has_priority: bool) -> bytes:

@@ -352,3 +352,85 @@ class Encoder:
             self._write_string(out, value)
             self.table.add(name, value)
         return bytes(out)
+
+
+# ------------------------------------------------------- static-only encoder
+
+# Names whose fields are never memoised: a process-wide dict must not
+# keep credentials alive past their stream, and they rarely repeat.
+NO_MEMO_NAMES = frozenset((b"cookie", b"set-cookie", b"authorization",
+                           b"proxy-authorization"))
+# A field whose name + value is longer is encoded each time.
+MEMO_FIELD_MAX = 256
+# The memo holds at most this many fields and is cleared whole when full,
+# as the DNS answer cache is. One LB's distinct fields are a few constant
+# response fields, an identity a backend, an address a client and the
+# authorities its clients name (the benchmark's h2 cell, 1,028 backends
+# and 16,384 pool entries, stores ~9.5k in a run: PERF.md), and real
+# headers repeat a connection.
+MEMO_FIELDS = 16384
+
+_field_memo: dict[tuple[bytes, bytes], bytes] = {}
+_memo_ctrs: Optional[tuple] = None
+
+
+def _memo_counters() -> tuple:
+    """(hits, misses, clears) — the process's counters, looked up once
+    (utils/metrics registers them eagerly, so a scrape shows the zeros)."""
+    global _memo_ctrs
+    if _memo_ctrs is None:
+        from ..utils.metrics import GlobalInspection
+        gi = GlobalInspection.get()
+        _memo_ctrs = (
+            gi.get_counter("vproxy_h2_hpack_field_memo_total",
+                           result="hit"),
+            gi.get_counter("vproxy_h2_hpack_field_memo_total",
+                           result="miss"),
+            gi.get_counter("vproxy_h2_hpack_field_memo_clears_total"))
+    return _memo_ctrs
+
+
+class StaticEncoder(Encoder):
+    """The h2 processor's hop encoder: its dynamic table has size 0, so
+    it never indexes what it sends (always-legal stateless hop encoding;
+    peers still compress toward us, and our Decoder tracks their
+    dynamic table). A field's bytes are then a pure function of the
+    field, and a block is the concatenation of its fields' bytes. Each
+    field's bytes come from one process-wide memo (`_field_memo`) and
+    are encoded, by `Encoder.encode` of the field alone, only on a miss:
+    byte for byte the block `Encoder(max_table_size=0).encode` emits.
+    Sensitive fields, `NO_MEMO_NAMES` and fields over `MEMO_FIELD_MAX`
+    bytes are encoded each time and never stored. Dict access is atomic
+    under the GIL, so every event loop shares the memo without a
+    lock."""
+
+    def __init__(self):
+        super().__init__(max_table_size=0)
+
+    def encode(self, headers: list[tuple[bytes, bytes]],
+               sensitive: Optional[set[bytes]] = None) -> bytes:
+        memo = _field_memo
+        parts = []
+        misses = clears = 0
+        for field in headers:
+            name, value = field
+            keep = not (sensitive and name in sensitive)
+            got = memo.get(field) if keep else None
+            if got is None:
+                misses += 1
+                got = Encoder.encode(self, [field], sensitive)
+                if keep and name not in NO_MEMO_NAMES \
+                        and len(name) + len(value) <= MEMO_FIELD_MAX:
+                    if len(memo) >= MEMO_FIELDS:
+                        memo.clear()
+                        clears += 1
+                    memo[field] = got
+            parts.append(got)
+        hit, miss, clear = _memo_counters()
+        if len(parts) > misses:
+            hit.incr(len(parts) - misses)
+        if misses:
+            miss.incr(misses)
+            if clears:
+                clear.incr(clears)
+        return b"".join(parts)

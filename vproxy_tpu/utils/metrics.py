@@ -635,6 +635,12 @@ class GlobalInspection:
         for where in UPSTREAM_SELECT_WHERES:
             self.get_counter("vproxy_upstream_selects_total", where=where)
         self.get_counter("vproxy_l7_parked_frames_total")
+        # the h2 hop encoder's field memo (processors/hpack.py
+        # StaticEncoder), one increment a header block
+        for res in ("hit", "miss"):
+            self.get_counter("vproxy_h2_hpack_field_memo_total",
+                             result=res)
+        self.get_counter("vproxy_h2_hpack_field_memo_clears_total")
         # maglev table-compiler accounting (rules/maglev.py): eager for
         # the same reason — a scrape shows the zeros before any build
         self.get_counter("vproxy_maglev_table_builds_total")

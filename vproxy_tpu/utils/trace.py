@@ -330,8 +330,8 @@ SPANS = (("engine", "wait"), ("engine", "cycle"), ("engine", "turn_wait"),
          ("engine", "kernel_wait"), ("engine", "swap_lock"),
          ("engine", "begin"), ("engine", "release"), ("engine", "handoff"),
          ("dns", "drain"), ("dns", "flush"), ("dns", "answer"),
-         ("l7", "front_read"), ("l7", "select"), ("l7", "back_read"),
-         ("runtime", "gc_pause"))
+         ("l7", "front_read"), ("l7", "select"), ("l7", "selected"),
+         ("l7", "back_read"), ("runtime", "gc_pause"))
 # bucket upper bounds 1, 2, 4 ... 2**26 us, then +Inf: utils/metrics.Histogram's
 TOTAL_BUCKETS = 27
 
