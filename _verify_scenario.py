@@ -138,13 +138,12 @@ print(f"[6] micro-batch queue: 30 concurrent http-splice reqs -> "
 _lb.stop(); _g1.close(); _g2.close(); _elg.close()
 print("VERIFY SCENARIO PASSED (incl. classify queue)")
 
-# ---- 7. accept-path latency contract: lone queries under a blown device
-# budget are answered inline from the host index in microseconds, and the
-# EWMA is kept live by an off-path probe (no real query eats the probe)
+# ---- 7. accept-path latency contract: in auto mode every query is
+# answered inline from the host index in microseconds, however slow the
+# device is (no query reaches it)
 ClassifyService.reset()
 _svc7 = ClassifyService.get()
 assert _svc7.mode == "auto"
-_svc7.budget_us = 1000.0
 from vproxy_tpu.rules.engine import HintMatcher as _HM7
 _rules7 = [_HR(host=f"svc{i}.accept.example") for i in range(20000)]
 _m7 = _HM7(_rules7)
@@ -154,7 +153,6 @@ def _slow7(snap, hints):
     time.sleep(0.05)  # a slow (50ms) device round trip
     return _real7(snap, hints)
 _m7.dispatch_snap = _slow7
-_svc7._ewma["device"] = 50_000.0  # measured-over-budget device
 # calibrate the pass bound against THIS host's measured per-lookup cost
 # (the raw index_snap the inline path rides): an absolute 1000us bound
 # flakes on slow/contended hosts while hiding regressions on fast ones.

@@ -1,7 +1,7 @@
 """rules/index.py (O(probes) host-side matchers) vs the linear oracle.
 
-The indexes serve the accept-path latency contract (lone queries under
-the ClassifyService budget policy), so their winner must be bit-for-bit
+The indexes serve the accept-path latency contract (every query of the
+ClassifyService's auto mode), so their winner must be bit-for-bit
 the oracle's — including tie-breaks (earliest index), port gating, and
 the host/uri cross-coverage cases that justify the bucket pruning.
 """

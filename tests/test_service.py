@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from vproxy_tpu.rules.engine import CidrMatcher, HintMatcher
+from vproxy_tpu.rules.engine import SMALL_TABLE, CidrMatcher, HintMatcher
 from vproxy_tpu.rules import oracle
 from vproxy_tpu.rules.ir import AclRule, Hint, HintRule, Proto
 from vproxy_tpu.rules.service import ClassifyService
@@ -302,152 +302,102 @@ def test_mixed_port_and_portless_cidr_queries_keep_semantics():
         assert results[i] == m.oracle_one(a, p) == i % 20, (i, results[i])
 
 
-def test_latency_budget_reroutes_lone_big_table_queries():
-    """Weak #5: a lone accept against a big table must not eat an
-    over-budget device round trip forever — once the device EWMA blows
-    the budget and the oracle is faster, lone queries reroute (with
-    periodic re-probes of the device)."""
+def test_auto_mode_never_starts_the_dispatcher():
+    """auto answers every query inline on its submitting thread from the
+    host index: eight threads submitting at once against a big table
+    get the oracle's answers, and no query reaches the device — no
+    dispatch, no launch, no dispatcher thread."""
+    from vproxy_tpu.rules import engine as E
     svc = ClassifyService.get()
     assert svc.mode == "auto"
-    svc.inline_lone = False  # exercise the budget policy, not the lane
-    svc.budget_us = 1000.0  # 1ms budget
     m = HintMatcher(mk_rules(300))  # > SMALL_TABLE
-    # make the device path artificially slow (50ms)
-    real = m.dispatch_snap
+    def service_threads():  # the dispatcher, or any other of its own
+        return {t for t in threading.enumerate()
+                if t.name.startswith("classify-")}
 
-    def slow(snap, hints, **kw):
-        time.sleep(0.05)
-        return real(snap, hints, **kw)
+    before = service_threads()
+    launches = E.dispatch_launches_total()
+    n_threads, per = 8, 40
+    start = threading.Barrier(n_threads)
+    bad = []
 
-    m.dispatch_snap = slow
-    m.match([Hint.of_host("warm.example.com")] * 16)  # warm jit
+    def worker(w):
+        start.wait()
+        me = threading.get_ident()
+        for i in range(per):
+            h = Hint.of_host(f"svc{(w * per + i) % 320}.example.com")
+            fired = []
+            svc.submit_hint(m, h, lambda idx, _pl: fired.append(
+                (idx, threading.get_ident())))
+            if fired != [(oracle.search(m.rules, h), me)]:
+                bad.append((w, i, fired))
 
-    def lone(i):
-        cb, results, done = collect(1)
-        svc.submit_hint(m, Hint.of_host(f"svc{i}.example.com"),
-                        lambda idx, _pl: cb(0, idx))
-        assert done.wait(10)
-        return results[0]
-
-    # 1st lone query probes the device (EWMA unknown), then oracle probe,
-    # then steady-state reroutes to the oracle
-    for i in range(8):
-        assert lone(i) == i
-    assert svc.stats.budget_reroutes >= 4
-    assert svc.stats.oracle_queries >= 4
-    # correctness is unchanged either way
-    assert lone(123) == 123
-    # stats surface the latency contract
-    lat = svc.stats.latency_percentiles()
-    assert lat is not None and lat["n"] >= 9
-    assert lat["p50_us"] > 0
-    snap = svc.stats.snapshot()
-    assert "latency_p50_us" in snap and "budget_reroutes" in snap
+    threads = [threading.Thread(target=worker, args=(w,))
+               for w in range(n_threads)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30)
+    assert not bad, bad[:5]
+    assert svc.stats.queries == svc.stats.oracle_queries == n_threads * per
+    assert svc.stats.dispatches == svc.stats.device_queries == 0
+    assert E.dispatch_launches_total() == launches
+    assert svc._thread is None
+    assert service_threads() <= before, service_threads() - before
 
 
-def test_latency_budget_off_keeps_device_for_lone_big_queries():
-    svc = ClassifyService.get()
-    assert svc.mode == "auto"
-    svc.inline_lone = False  # fast lane off: budget knob governs
-    svc.budget_us = 0.0  # knob off -> previous behavior
-    m = HintMatcher(mk_rules(300))
-    m.match([Hint.of_host("warm.example.com")] * 16)
-    cb, results, done = collect(1)
-    svc.submit_hint(m, Hint.of_host("svc7.example.com"),
-                    lambda idx, _pl: cb(0, idx))
-    assert done.wait(10)
-    assert results[0] == 7
+@pytest.mark.parametrize("kind", ["hint", "cidr", "cpick"])
+def test_device_mode_lone_query_on_a_big_table_rides_the_device(kind):
+    """device mode sends a lone query to the device whatever the table's
+    size: one submit, one device answer, the oracle's."""
+    from vproxy_tpu.rules import maglev as MG
+    svc = ClassifyService(mode="device")
+    hm = HintMatcher(mk_rules(300))     # > SMALL_TABLE
+    h = Hint.of_host("svc7.example.com")
+    ents = [(f"b{i}", 1) for i in range(5)]
+    ip = bytes([172, 16, 0, 9])
+    if kind == "hint":
+        m, want = hm, (oracle.search(hm.rules, h),)
+    elif kind == "cidr":
+        m = CidrMatcher([Network(bytes([10, i // 256, i % 256, 0]),
+                                 mask_bytes(24)) for i in range(300)])
+        want = (m.oracle_one(bytes([10, 0, 7, 1]), None),)
+        assert want == (7,)
+    else:
+        m = MG.FusedPair(hm, MG.MaglevMatcher(ents, m=251))
+        want = (oracle.search(hm.rules, h),
+                MG.pick(MG.build_table(ents, 251), ip, 80))
+    assert m.size() > SMALL_TABLE
+    got, done = [], threading.Event()
+
+    def cb(*verdict):
+        got.append(verdict[:-1])
+        done.set()
+
+    try:
+        if kind == "hint":
+            svc.submit_hint(m, h, cb)
+        elif kind == "cidr":
+            svc.submit_cidr(m, bytes([10, 0, 7, 1]), None, cb)
+        else:
+            svc.submit_classify_pick(m, h, ip, 80, cb)
+        assert done.wait(60)
+    finally:
+        svc.close()
+    assert got == [want]
     assert svc.stats.device_queries == 1
     assert svc.stats.oracle_queries == 0
 
 
-def test_inline_host_path_is_synchronous_and_probes_off_path():
-    """Budget-rerouted lone queries are answered INLINE on the
-    submitting thread (no dispatcher hop — the accept-path latency
-    contract), and the device EWMA is refreshed by an off-path probe
-    thread, never by making a real query eat the device round trip."""
-    import threading as _t
-
-    svc = ClassifyService.get()
-    assert svc.mode == "auto"
-    svc.budget_us = 1000.0
-    svc._ewma["device"] = 50_000.0  # over budget -> host path
-    m = HintMatcher(mk_rules(300))
-    m.match([Hint.of_host("warm.example.com")] * 16)
-
-    probe_seen = _t.Event()
-    real = m.dispatch_snap
-
-    def slow(snap, hints, **kw):
-        probe_seen.set()          # only the probe thread gets here
-        time.sleep(0.02)
-        return real(snap, hints, **kw)
-
-    m.dispatch_snap = slow
-    caller = _t.get_ident()
-    hits = []
-    from vproxy_tpu.rules.service import PROBE_EVERY
-    for i in range(PROBE_EVERY + 2):
-        fired = []
-        svc.submit_hint(m, Hint.of_host(f"svc{i % 300}.example.com"),
-                        lambda idx, _pl: fired.append(
-                            (idx, _t.get_ident())))
-        # inline contract: the callback already ran, on THIS thread
-        assert fired and fired[0][1] == caller, i
-        hits.append(fired[0][0])
-    assert hits[:4] == [0, 1, 2, 3]
-    assert probe_seen.wait(5)     # the off-path probe fired...
-    for _ in range(100):          # ...and refreshed the device EWMA
-        if svc._ewma["device"] != 50_000.0:
-            break
-        time.sleep(0.05)
-    assert svc._ewma["device"] != 50_000.0
-    # every query was served by the host index, none by the device
-    assert svc.stats.oracle_queries >= PROBE_EVERY + 2
-
-
-def test_micro_batches_always_ride_device_despite_budget():
-    """n >= 2 is never rerouted by the budget policy: the policy only
-    gates LONE queries (which the inline fast path serves from the host
-    index); any batch that forms rides the device regardless of how bad
-    the device EWMA looks."""
-    svc = ClassifyService.get()
-    assert svc.mode == "auto"
-    svc.inline_lone = False  # decision-point asserts use the budget path
-    svc.budget_us = 1.0  # absurdly tight budget
-    svc._ewma["device"] = 1e6  # pretend the device is terrible
-    svc._ewma["oracle"] = 10.0
-    m = HintMatcher(mk_rules(300))
-    m.match([Hint.of_host("warm.example.com")] * 16)
-    # the routing contract, at the decision point the dispatcher uses
-    assert svc._use_device(m, 2)      # micro-batch: always the device
-    assert svc._use_device(m, 100)
-    assert not svc._lone_path_is_device()  # lone over budget: host
-    # and a burst stays correct end-to-end whichever path served it
-    n = 50
-    cb, results, done = collect(n)
-    for i in range(n):
-        svc.submit_hint(m, Hint.of_host(f"svc{i}.example.com"),
-                        lambda idx, _pl, i=i: cb(i, idx))
-    assert done.wait(30)
-    for i in range(n):
-        assert results[i] == i
-    # with the device over budget every lone submission was answered
-    # inline from the host index — no device round trip on the path
-    assert svc.stats.oracle_queries >= n - 10
-    assert svc.stats.budget_reroutes >= n - 10
-
-
 def test_inline_fast_lane_default_and_parity_vs_oracle():
-    """Round-6 fast lane: in auto mode EVERY lone query against a big
-    table is answered inline from the host index by default (no budget
-    gate, no device EWMA warm-up), and the winner is bit-for-bit the
+    """In auto mode EVERY query against a big table is answered inline
+    from the host index, and the winner is bit-for-bit the
     oracle's across exact hosts, dot-suffix matches, uri prefixes,
     port rules, wildcards and misses. Zero device dispatches."""
     import threading as _t
 
     svc = ClassifyService.get()
-    assert svc.mode == "auto" and svc.inline_lone
+    assert svc.mode == "auto"
 
     rules = []
     for i in range(200):

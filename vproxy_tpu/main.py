@@ -28,7 +28,6 @@ Env flags (the reference's -D system-property layer, Config.java):
   VPROXY_TPU_SWITCH_FASTPATH=0           force object-path switch
   VPROXY_TPU_FASTPATH_MIN=n              burst floor for the fast path
   VPROXY_TPU_CLASSIFY=auto|device|host   dispatch-path policy
-  VPROXY_TPU_CLASSIFY_BUDGET_US=n        lone-query latency budget
   VPROXY_TPU_DIST_COORD=host:port        jax.distributed coordinator
   VPROXY_TPU_DIST_NPROC=n                ... process count
   VPROXY_TPU_DIST_PROCID=i               ... this process's id

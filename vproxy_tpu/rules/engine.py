@@ -670,8 +670,8 @@ class HintMatcher:
         # `payload` is an opaque owner-supplied object versioned WITH the
         # rules (e.g. Upstream's GroupHandle list) so a matched index is
         # always interpreted against the same generation it was matched
-        # in; `index` is the O(probes) host-side HintIndex the latency
-        # budget policy answers lone queries from (rules/index.py);
+        # in; `index` is the O(probes) host-side HintIndex the service's
+        # auto mode answers every query from (rules/index.py);
         # then the packed fused tables, and the rule -> group column of
         # a maglev.GroupedPair's matcher (refs, device [r_cap, 2]) —
         # None unless set_rules was given `groups`

@@ -2,8 +2,8 @@
 
 The linear oracle (rules/oracle.py) replicates the reference's scan
 loops exactly but costs O(rules) per query (~9ms at 20k hint rules) —
-fine as a correctness baseline, unusable as the accept-path fallback the
-latency-budget policy routes lone queries to (BASELINE's p99 < 50us
+fine as a correctness baseline, unusable as the accept path the
+ClassifyService's auto mode answers every query on (BASELINE's p99 < 50us
 classify contract). These indexes answer a single query in O(probes)
 (~2-10us independent of table size) with EXACTLY the oracle's
 semantics, using the same probe/bucket/pruning structure the device

@@ -3,54 +3,39 @@
 THE north-star mechanism (BASELINE.json): data-plane code (TcpLB hint
 classify, SecurityGroup ACL gates, DNS qname lookup, switch routing)
 never dispatches the device per connection; it enqueues a query with a
-callback and the service coalesces everything that arrives while the
-previous device batch is in flight into ONE dispatch ("natural
-batching": the dispatch latency itself is the batch window, so the queue
-adapts from batch=1 at idle to hundreds under load with no timer).
+callback and, in device mode, the service coalesces everything that
+arrives while the previous device batch is in flight into ONE dispatch
+("natural batching": the dispatch latency itself is the batch window,
+so the queue adapts from batch=1 at idle to hundreds under load with no
+timer).
 
 This replaces the reference's per-connection linear scans
 (Upstream.searchForGroup Upstream.java:187-198, SecurityGroup.allow
 SecurityGroup.java:30-45, RouteTable.lookup RouteTable.java:44) with a
 shared per-process batching front to the compiled device tables.
 
-Dispatch-path policy (mode = VPROXY_TPU_CLASSIFY, default "auto"):
+Dispatch path (mode = VPROXY_TPU_CLASSIFY, default "auto"), one rule a
+mode:
 
-* "auto"   — a flushed batch goes to the device when it has >= 2 queries
-             (micro-batch) or the table is big (> SMALL_TABLE rules, the
-             same threshold match_one uses); lone queries against small
-             tables take the ~1us host oracle instead of a ~1ms device
-             round trip.
-* "device" — every flushed batch goes to the device (used by tests and
-             benchmarks to force the TPU path end-to-end).
-* "host"   — pure oracle (latency floor; also the correctness baseline).
+* "auto"   — every query is answered INLINE on the submitting thread
+             from the snapshot's O(probes) host index (rules/index.py —
+             exact, ~2-10us, winner bit-for-bit vs the oracle): no
+             dispatcher-thread hop, no device round trip. This is THE
+             accept path — what makes the BASELINE p99 < 50us
+             accept-path contract meetable. No query reaches the device,
+             and the dispatcher thread never starts.
+* "device" — every query goes through the dispatcher to the device,
+             micro-batched, with failover to the oracle while the device
+             is marked down (below). Tests and every benchmark cell run
+             this mode.
+* "host"   — the dispatcher answers from the host oracle (latency floor;
+             also the correctness baseline).
 
-Inline fast lane (VPROXY_TPU_INLINE_LONE, default on): in "auto" mode
-a LONE query with nothing pending for its matcher is answered INLINE
-on the submitting thread from the snapshot's O(probes) host index
-(rules/index.py — exact, ~2-10us, winner bit-for-bit vs the oracle):
-no dispatcher-thread hop, no device RTT. This is THE accept path —
-accepts consult the host index directly on the accept loop, which is
-what makes the BASELINE p99 < 50us accept-path contract meetable even
-when the device sits behind a slow transport. Micro-batches (n >= 2)
-always ride the device — batching is the whole point, and the device
-stays the bulk path.
-
-With the fast lane disabled (VPROXY_TPU_INLINE_LONE=0) the pre-round-6
-latency-budget policy applies (VPROXY_TPU_CLASSIFY_BUDGET_US, default
-5000; 0 = off): lone big-table queries ride the device while its EWMA
-stays within budget and reroute to the host index once it blows it.
-Either way the device EWMA is kept live by OFF-PATH probes: every
-PROBE_EVERY-th inline-served lone query (rate-limited to one per
-VPROXY_TPU_PROBE_MIN_S seconds) hands the persistent probe worker a
-synthetic device dispatch, so real accept-path queries never eat the
-probe cost (the round-4 policy rode probes on real queries, putting
-device RTT spikes straight into the reported p99). The probe worker is
-deliberately a bad GIL citizen's opposite: it yields between the
-phases of its dispatch and the service shrinks the interpreter's GIL
-slice (VPROXY_TPU_GIL_SLICE_MS, default 1ms vs CPython's 5ms) so a
-probe mid-dispatch can only delay an inline answer by ~one slice —
-this is what kills the ~3ms accept-path p999 spikes the round-5 bench
-saw whenever a probe held the GIL for a full default interval.
+The service shrinks the interpreter's GIL slice (VPROXY_TPU_GIL_SLICE_MS,
+default 1ms vs CPython's 5ms): a thread that holds the GIL — the
+dispatcher's encode and launch, a busy event loop — delays a submitter
+or a loop by ~one slice at most, and every benchmark cell is measured
+under it.
 
 Every delivered query also records submit->delivery latency into a
 fixed reservoir; stats.latency_percentiles() surfaces p50/p99 (the
@@ -121,10 +106,6 @@ _log = Logger("classify")
 
 RETRY_S = float(os.environ.get("VPROXY_TPU_DEVICE_RETRY_S", "5"))
 PAD_LO = int(os.environ.get("VPROXY_TPU_PAD_LO", "4"))
-BUDGET_US = float(os.environ.get("VPROXY_TPU_CLASSIFY_BUDGET_US", "5000"))
-INLINE_LONE = os.environ.get("VPROXY_TPU_INLINE_LONE", "1") != "0"
-PROBE_EVERY = 32     # re-probe the non-preferred lone-query path
-PROBE_MIN_S = float(os.environ.get("VPROXY_TPU_PROBE_MIN_S", "0.25"))
 GIL_SLICE_MS = float(os.environ.get("VPROXY_TPU_GIL_SLICE_MS", "1"))
 LAT_RESERVOIR = 4096  # submit->delivery latency samples kept
 # a batch this long records its latencies vectorised: the numpy pass
@@ -137,10 +118,10 @@ _gil_slice_applied = False
 
 def _apply_gil_slice() -> None:
     """Shrink the interpreter's thread-switch interval (once, process-
-    wide, never loosening an even smaller configured value): a GIL-
-    holding device probe can then only delay an inline accept-path
-    answer by ~one slice instead of CPython's default 5ms — the source
-    of the round-5 multi-ms p999 spikes."""
+    wide, never loosening an even smaller configured value): a thread
+    holding the GIL (the dispatcher's encode + launch, a busy loop) can
+    then delay another by ~one slice instead of CPython's default 5ms.
+    Every benchmark cell is measured under it."""
     global _gil_slice_applied
     if _gil_slice_applied or GIL_SLICE_MS <= 0:
         return
@@ -216,18 +197,16 @@ class _Inflight:
     """One async-submitted device batch awaiting its sync + delivery
     (the dispatcher's double buffer slot)."""
 
-    __slots__ = ("kind", "matcher", "reqs", "snap", "arr", "t0",
-                 "lone_big", "tid", "prefetched", "t_ret")
+    __slots__ = ("kind", "matcher", "reqs", "snap", "arr", "tid",
+                 "prefetched", "t_ret")
 
-    def __init__(self, kind, matcher, reqs, snap, arr, t0, lone_big, tid,
-                 prefetched, t_ret):
+    def __init__(self, kind, matcher, reqs, snap, arr, tid, prefetched,
+                 t_ret):
         self.kind = kind
         self.matcher = matcher
         self.reqs = reqs
         self.snap = snap
         self.arr = arr
-        self.t0 = t0
-        self.lone_big = lone_big
         self.tid = tid    # the batch's first sampled request, 0 = none
         self.prefetched = prefetched  # its readback started at launch
         # tracing on: monotonic ns at which its launch had returned (the
@@ -348,8 +327,8 @@ class ClassifyStats:
         self.failovers = 0        # device errors that degraded a batch
         self.last_failover = ""   # repr of the newest such error
         self.max_batch = 0
-        self.budget_reroutes = 0  # lone queries sent to oracle by budget
-        self.inline_fast = 0      # lone queries served by the fast lane
+        self.budget_reroutes = 0  # pinned at 0: benchmark/program.py reads it
+        self.inline_fast = 0      # inline answers a device could have given
         # latency samples recorded a batch at a time (observe_many); on
         # /metrics as vproxy_classify_latency_batched_total, beside the
         # histogram's own _count
@@ -419,7 +398,7 @@ class ClassifyStats:
     def snapshot(self) -> dict:
         d = {k: getattr(self, k) for k in (
             "queries", "dispatches", "device_queries", "oracle_queries",
-            "failovers", "max_batch", "budget_reroutes", "inline_fast",
+            "failovers", "max_batch", "inline_fast",
             "readback_prefetch", "readback_kernel_waits")}
         lat = self.latency_percentiles()
         if lat is not None:
@@ -450,20 +429,7 @@ class ClassifyService:
     def __init__(self, mode: Optional[str] = None):
         self.mode = mode or os.environ.get("VPROXY_TPU_CLASSIFY", "auto")
         self.retry_s = RETRY_S
-        self.budget_us = BUDGET_US
-        self.inline_lone = INLINE_LONE
         _apply_gil_slice()
-        # lone-query EWMA latency (us) per path, None until first sample
-        self._ewma = {"device": None, "oracle": None}
-        self._elock = threading.Lock()
-        self._lone_seen = 0
-        self._probe_last = 0.0  # monotonic ts of the last spawned probe
-        # persistent probe worker: the inline accept path only hands it
-        # a request + notify (~1us); spawning a Thread per probe costs
-        # ~200us and was visible in the accept-path p99
-        self._probe_req: Optional[tuple] = None
-        self._probe_cv = threading.Condition()
-        self._probe_thread: Optional[threading.Thread] = None
         self.stats = ClassifyStats()
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
@@ -505,10 +471,11 @@ class ClassifyService:
                              loop=None) -> None:
         """Queue one fused classify+pick against a maglev.FusedPair or
         GroupedPair: cb(verdict_idx, pick_idx, (hint_payload,
-        maglev_payload)). Micro-batches ride the pair's ONE-launch
-        program (rules/engine.fused_dispatch / grouped_dispatch); lone
-        queries take the inline host lane (hint index + O(1) maglev
-        read), same fast-lane policy as plain hint submits. A grouped
+        maglev_payload)). In device mode micro-batches ride the pair's
+        ONE-launch program (rules/engine.fused_dispatch /
+        grouped_dispatch); in auto mode every query takes the inline
+        host lane (hint index + O(1) maglev read), as plain hint
+        submits do. A grouped
         pair's pick is a slot of the table of the group the matched
         rule names (an index into that group's published member list),
         -1 where the verdict is -1 or its group holds no table.
@@ -560,50 +527,27 @@ class ClassifyService:
             self._answer_inline(kind, matcher, payload, cb, loop)
 
     def _inline_host(self, matcher) -> bool:
-        """Lone query, nothing pending for this matcher: answer it
-        synchronously on the submitting thread from the host index. With
-        the fast lane on (default) this is the first-class path for
-        EVERY lone query in auto mode — the O(probes) index gives the
-        same winner as the oracle at ~us cost, so there is nothing a
-        device round trip could add but latency. With the lane off, the
-        pre-round-6 gates apply: small table (the oracle crossover),
-        device marked down, or the budget policy preferring the host.
-        Called under the lock; must stay O(1)."""
+        """auto mode: the query is answered synchronously on the
+        submitting thread from the host index. `inline_fast` counts the
+        answers on a device backend, device up, table over SMALL_TABLE
+        (chip_smoke.py's auto leg and benchmark/program.py counters()
+        read it). Called under the lock; must stay O(1)."""
         if self.mode != "auto":
             return False
-        if getattr(matcher, "backend", "host") == "host":
-            return True
-        if time.monotonic() < self._device_down_until:
-            return True
-        if matcher.size() <= SMALL_TABLE:
-            return True
-        if self.inline_lone:
+        if getattr(matcher, "backend", "host") != "host" \
+                and time.monotonic() >= self._device_down_until \
+                and matcher.size() > SMALL_TABLE:
             self.stats.inline_fast += 1
-            return True
-        if self.budget_us <= 0:
-            return False
-        dev = self._ewma["device"]
-        if dev is None or dev <= self.budget_us:
-            return False          # ride the device (measures the EWMA)
-        self.stats.budget_reroutes += 1
         return True
 
     def _answer_inline(self, kind: str, matcher, payload, cb, loop) -> None:
-        """Serve one lone query from the snapshot's host index, inline.
-        Every PROBE_EVERY-th rerouted query also hands the off-path
-        probe worker a request so the device EWMA tracks current
-        conditions without any real query eating the probe cost.
+        """Serve one query from the snapshot's host index, inline.
         Delivery keeps the loop-confinement contract: run_on_loop runs
         the callback immediately when the submitter IS the loop thread
         (the accept path — fully synchronous), else queues it there."""
         t0 = time.monotonic()
         tid = trace.current_id()
         snap = matcher.snapshot()
-        # a host-backend matcher has no device to probe (and its
-        # dispatch_snap is the O(rules) oracle — exactly the GIL-holding
-        # scan the probe worker must never run)
-        big = (matcher.size() > SMALL_TABLE
-               and getattr(matcher, "backend", "host") != "host")
         try:
             if kind in ("hint", "cpick"):
                 # cpick: the FusedPair host lane -> (verdict, pick)
@@ -628,82 +572,12 @@ class ClassifyService:
                     and getattr(matcher, "grouped", False):
                 st.group_picks["host"] += 1     # a batch of one
         st.record_latency(dt)
-        if big:
-            self._note_lone_latency("oracle", dt)
-            with self._elock:
-                self._lone_seen += 1
-                now = time.monotonic()
-                probe = (self._lone_seen % PROBE_EVERY == 0
-                         and now - self._probe_last >= PROBE_MIN_S)
-                if probe:
-                    self._probe_last = now
-            if probe and self.device_ok():
-                self._spawn_probe(kind, matcher, payload)
         pl = matcher.snap_payload(snap)
         args = (int(i[0]), int(i[1]), pl) if kind == "cpick" \
             else (int(i), pl)
         if loop is None or not loop.run_on_loop(
                 partial(_guarded_cb, cb, *args)):
             _guarded_cb(cb, *args)
-
-    def _spawn_probe(self, kind: str, matcher, payload) -> None:
-        """Hand (kind, matcher, payload) to the persistent probe worker;
-        at most one probe in flight (a slow device must not queue up),
-        and the accept path pays only a notify."""
-        with self._probe_cv:
-            if self._probe_req is not None:
-                return
-            self._probe_req = (kind, matcher, payload)
-            if self._probe_thread is None:
-                self._probe_thread = threading.Thread(
-                    target=self._probe_run, name="classify-probe",
-                    daemon=True)
-                self._probe_thread.start()
-            self._probe_cv.notify()
-
-    def _probe_run(self) -> None:
-        while True:
-            with self._probe_cv:
-                while self._probe_req is None:
-                    if self._closed:
-                        return
-                    self._probe_cv.wait(1.0)
-                kind, matcher, payload = self._probe_req
-            try:
-                # chunked, deliberately-yielding dispatch: the probe is
-                # background work sharing the GIL with the inline accept
-                # path, so it gives the scheduler an explicit preemption
-                # point before each GIL-heavy phase (encode, dispatch)
-                time.sleep(0)
-                snap = matcher.snapshot()
-                time.sleep(0)
-                t0 = time.monotonic()
-                # pad exactly like _device_batch: the probe must time the
-                # SAME compiled program real dispatches run, not trigger
-                # a fresh batch-1 trace whose compile time poisons the
-                # EWMA for hundreds of queries
-                np.asarray(self._probe_dispatch(kind, matcher, snap,
-                                                payload))
-                self._note_lone_latency("device", time.monotonic() - t0)
-            except MemoryError:
-                raise
-            except Exception as e:
-                self.stats.bump("failovers")
-                self.stats.last_failover = repr(e)
-                self._device_down_until = time.monotonic() + self.retry_s
-                _log.alert(f"device probe failed ({e!r}); device marked "
-                           f"down for {self.retry_s:.0f}s")
-                from ..utils import events
-                events.record("classify_failover",
-                              f"device probe failed: {e!r}",
-                              retry_s=self.retry_s)
-            finally:
-                with self._probe_cv:
-                    self._probe_req = None
-
-    def _probe_dispatch(self, kind: str, matcher, snap, payload):
-        return self._device_batch(kind, matcher, snap,
-                                  [_Req(payload, None, None)])
 
     # ---------------------------------------------------------- dispatcher
 
@@ -808,37 +682,12 @@ class ClassifyService:
             if cycle is not None:
                 cycle.close()
 
-    def _use_device(self, matcher, n: int) -> bool:
-        if self.mode == "host" or getattr(matcher, "backend", "host") == "host":
-            return False
-        if time.monotonic() < self._device_down_until:
-            return False
-        if self.mode == "device":
-            return True
-        # auto: micro-batches always ride the device; lone queries only
-        # when the table is past the oracle's crossover size
-        if n >= 2:
-            return True
-        if matcher.size() <= SMALL_TABLE:
-            return False
-        return self._lone_path_is_device()
-
-    def _lone_path_is_device(self) -> bool:
-        """Budget policy for a lone query that reached the dispatcher
-        (the inline gate already served budget-rerouted ones): ride the
-        device while it is unmeasured or within budget."""
-        if self.budget_us <= 0:
-            return True
-        dev = self._ewma["device"]
-        return dev is None or dev <= self.budget_us
-
-    def _note_lone_latency(self, path: str, seconds: float) -> None:
-        # writers: inline submit threads, the probe worker, and the
-        # dispatcher — the EWMA read-modify-write needs the lock
-        us = seconds * 1e6
-        with self._elock:
-            cur = self._ewma[path]
-            self._ewma[path] = us if cur is None else 0.8 * cur + 0.2 * us
+    def _use_device(self, matcher) -> bool:
+        """device mode, a device backend, the device not marked down
+        (auto never reaches the dispatcher)."""
+        return (self.mode != "host"
+                and getattr(matcher, "backend", "host") != "host"
+                and time.monotonic() >= self._device_down_until)
 
     @staticmethod
     def _split_uniform(kind: str, reqs: list[_Req]) -> list[list[_Req]]:
@@ -864,7 +713,6 @@ class ClassifyService:
                 self.stats.max_batch = max(self.stats.max_batch, n)
             # ONE generation for device/oracle/payload
             snap = matcher.snapshot()
-            lone_big = n == 1 and matcher.size() > SMALL_TABLE
             if sketch.ON:
                 # device-plane attribution: which upstream's classify load
                 # is filling the batches (routes dim, `upstream:<alias>`
@@ -888,9 +736,8 @@ class ClassifyService:
                         t_sub = int(r.t0 * 1e9)
                         trace.note_span(r.tid, "engine", "queue_wait", t_sub,
                                         t_q - t_sub, kind=kind, batch=n)
-        if self._use_device(matcher, n):
+        if self._use_device(matcher):
             try:
-                t0 = time.monotonic()
                 # the bind hands the engine's encode and launch spans
                 # the sampled request's trace
                 with trace.bind(tid), trace.span("engine", "dispatch",
@@ -900,8 +747,8 @@ class ClassifyService:
                 with trace.span("engine", "readback_start", tid=tid,
                                 kind=kind):
                     prefetched = _start_readback(arr)
-                return _Inflight(kind, matcher, reqs, snap, arr, t0,
-                                 lone_big, tid, prefetched,
+                return _Inflight(kind, matcher, reqs, snap, arr, tid,
+                                 prefetched,
                                  time.monotonic_ns() if trace.SAMPLE else 0)
             except MemoryError:
                 raise
@@ -914,8 +761,6 @@ class ClassifyService:
                               int(t0 * 1e9),
                               int((time.monotonic() - t0) * 1e9),
                               kind=kind, batch=n)
-        if lone_big:
-            self._note_lone_latency("oracle", time.monotonic() - t0)
         self.stats.bump("oracle_queries", n)
         self._note_group_picks(matcher, idxs, "host")
         self._deliver(reqs, idxs, matcher.snap_payload(snap), kind=kind,
@@ -982,8 +827,6 @@ class ClassifyService:
                             also="kernel_wait" if kernel_wait else None,
                             kind=inf.kind, batch=n):
                 idxs = np.asarray(inf.arr)[:n]
-            if inf.lone_big:
-                self._note_lone_latency("device", time.monotonic() - inf.t0)
             st = self.stats
             with st.lock:
                 st.dispatches += 1
@@ -999,11 +842,8 @@ class ClassifyService:
         where = "host" if getattr(inf.arr, "picks_on_host", False) \
             else "device"
         if idxs is None:
-            t0 = time.monotonic()
             idxs = self._oracle_batch(inf.kind, inf.matcher, inf.snap,
                                       inf.reqs)
-            if inf.lone_big:
-                self._note_lone_latency("oracle", time.monotonic() - t0)
             self.stats.bump("oracle_queries", n)
             where = "host"
         self._note_group_picks(inf.matcher, idxs, where, inf.tid)
@@ -1063,11 +903,6 @@ class ClassifyService:
         return matcher.dispatch_snap(snap, addrs, ports, pad_to=cap,
                                      sync=sync)
 
-    def _device_batch(self, kind: str, matcher, snap, reqs: list[_Req]):
-        """Synchronous submit+pull (the probe worker's path)."""
-        return np.asarray(
-            self._device_submit(kind, matcher, snap, reqs))[: len(reqs)]
-
     def _oracle_batch(self, kind: str, matcher, snap,
                       reqs: list[_Req]) -> list[int]:
         """Host-served batch (device down / host path): rides the
@@ -1120,5 +955,3 @@ class ClassifyService:
         with self._cv:
             self._closed = True
             self._cv.notify()
-        with self._probe_cv:  # wake the probe worker so it exits
-            self._probe_cv.notify()
